@@ -105,7 +105,7 @@ def make_train_step(agent, optimizer, cfg, mesh):
         return params, opt_state, jnp.stack([*aux, gnorm, 1.0 - finite.astype(jnp.float32)]), hstats
 
     if distributed:
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def sharded(params, opt_state, data):
             return shard_map(

@@ -874,8 +874,8 @@ def build_agent(
         learnable_initial_recurrent_state=wm_cfg.learnable_initial_recurrent_state,
         decoupled_rssm=wm_cfg.decoupled_rssm,
         # Pallas fused LayerNorm-GRU: `algo.rssm_pallas` is the deploy-time
-        # lever (bench.py mfu_levers sweeps it); the older
-        # recurrent_model.fused_kernel spelling still works
+        # lever (raises at build time on a shape/backend the kernel cannot
+        # serve); the older recurrent_model.fused_kernel spelling still works
         fused_gru=bool(
             cfg.algo.get("rssm_pallas", False)
             or wm_cfg.recurrent_model.get("fused_kernel", False)

@@ -60,7 +60,7 @@ def chunked_dynamic_scan(
     """Run the T-step dynamic-learning scan, optionally split into ``chunks``
     independent chunks whose initial states come from replay-stored RSSM
     states — the chunk axis is folded into the batch axis, so the GRU GEMM
-    runs at ``B * chunks`` rows instead of ``B`` (PERF.md §4: MFU rises
+    runs at ``B * chunks`` rows instead of ``B`` (PERF.md §5: MFU rises
     exactly as the effective row count widens; the trade is strict recurrence
     across chunk boundaries for stored — possibly stale — states, the
     SEED-RL/R2D2 playbook).

@@ -166,7 +166,7 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, batch_siz
         return params, opt_state, metrics, health_out
 
     if distributed:
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def sharded_update(params, opt_state, data, key, coefs):
             # per-device independent permutation: fold the axis index into the key

@@ -103,7 +103,7 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch
         return params, opt_state, jnp.mean(losses.reshape(-1, 3), axis=0)
 
     if distributed:
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def sharded(params, opt_state, data, key, coefs):
             def body(params, opt_state, data, key, coefs):

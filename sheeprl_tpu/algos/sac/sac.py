@@ -213,7 +213,7 @@ def make_train_step(actor_def, critic_def, optimizers, cfg, mesh, target_entropy
         return params, opt_states, metrics, jax.tree_util.tree_map(jnp.mean, health_tree)
 
     if distributed:
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def sharded(params, opt_states, data, keys):
             return shard_map(

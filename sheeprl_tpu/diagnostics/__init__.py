@@ -142,7 +142,6 @@ class Diagnostics:
         self.enabled = bool(diag_cfg.get("enabled", False))
         self._journal_cfg = diag_cfg.get("journal") or {}
         self._trace_cfg = diag_cfg.get("trace") or {}
-        self.compilation_cache_dir = diag_cfg.get("compilation_cache_dir") or None
         self.role = str(diag_cfg.get("role") or "main")
         self.sentinel: SentinelSpec = sentinel_spec(cfg or {})
         div_cfg = (diag_cfg.get("sentinel") or {}).get("divergence") or {}
@@ -207,10 +206,14 @@ class Diagnostics:
         self._closed = False
 
     # -- lifecycle ---------------------------------------------------------
-    def open(self, log_dir: str, rank_zero: bool = True) -> "Diagnostics":
+    def open(
+        self, log_dir: str, rank_zero: bool = True, device: Optional[Mapping[str, Any]] = None
+    ) -> "Diagnostics":
         """Open journal/tracer/telemetry inside ``log_dir`` (idempotent;
         journal + endpoint are rank-0 only, the tracer — when
-        ``trace.all_ranks`` — and the telemetry accounting run everywhere)."""
+        ``trace.all_ranks`` — and the telemetry accounting run everywhere).
+        ``device`` is ``Runtime.device_info`` — the platform, device kind and
+        count the mesh resolved to, journaled with ``run_start``."""
         if not self.enabled or self.log_dir is not None:
             return self
         self.log_dir = str(log_dir)
@@ -254,13 +257,17 @@ class Diagnostics:
                 log_dir=self.log_dir,
                 run_id=self.run_id,
                 sentinel_policy=self.sentinel.policy if self.sentinel.enabled else None,
+                **(device or {}),
             )
-            if self.compilation_cache_dir:
-                # the cache itself was enabled at CLI startup (before any
-                # compile — cli._apply_global_flags); the journal records
-                # where it lives so restarts/post-mortems can account for
-                # compile time that never shows up
-                self.journal.write("compilation_cache", dir=str(self.compilation_cache_dir))
+            import jax
+
+            cache_dir = jax.config.jax_compilation_cache_dir
+            if cache_dir:
+                # the cache itself was placed at startup, before any compile
+                # (utils/compile_cache.py); the journal records the directory
+                # in force so restarts/post-mortems can account for compile
+                # time that never shows up
+                self.journal.write("compilation_cache", dir=str(cache_dir))
         if self.resilience is not None:
             # opened on every rank: each process of a decoupled topology must
             # honor its own preemption signal; journal writes (ckpt_begin/

@@ -33,7 +33,7 @@ METRIC_PREFIX = "sheeprl_"
 
 #: Every journal event kind -> one-line description (the howto table's text).
 EVENT_KINDS: Dict[str, str] = {
-    "run_start": "config hash, algo/env/seed, run identity, sentinel policy",
+    "run_start": "config hash, algo/env/seed, run identity, sentinel policy, resolved platform/device_kind/device_count",
     "metrics": "every aggregated metric interval, keyed by the policy-step counter",
     "checkpoint": "step + checkpoint path",
     "divergence": "structured sentinel/detector findings",
@@ -43,7 +43,7 @@ EVENT_KINDS: Dict[str, str] = {
     "telemetry_cost": "compiled-step cost_analysis FLOPs for one instrumented signature",
     "telemetry_fallback": "AOT compile/dispatch failed; the step reverted to native jit dispatch",
     "metrics_server": "the /metrics endpoint address (or its bind failure)",
-    "compilation_cache": "JAX on-disk compilation cache enabled (directory recorded)",
+    "compilation_cache": "JAX on-disk compilation cache directory in force (environment, config key or in-checkout default)",
     "aot_cache_hit": "persistent AOT executable cache: a serialized executable was loaded instead of compiling (fn, entry path, FLOPs)",
     "aot_cache_miss": "persistent AOT executable cache: no usable entry — reason absent/corrupt/fingerprint_mismatch/store_failed — so a fresh compile ran",
     "telemetry_summary": "closing perf totals (recompiles, compile time, FLOPs, phase seconds)",

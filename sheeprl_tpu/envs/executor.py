@@ -24,7 +24,7 @@ O(num_workers) — one command write and one ack drain per worker — plus one
 vectorized copy per observation key, instead of the one-process-per-env
 model's O(num_envs) pipe round-trips and per-env read loop.  That is what
 keeps 64-512 concurrent envs throughput-bound instead of Python-bound
-(PERF.md §11); ``envs_per_worker=1`` recovers the one-env-per-process layout
+(PERF.md §6); ``envs_per_worker=1`` recovers the one-env-per-process layout
 for expensive simulators that need a whole core each.
 
 Autoreset follows ``gym.vector.AutoresetMode.SAME_STEP`` bit-for-bit with

@@ -1,7 +1,7 @@
 """Split-phase (``step_async`` / ``step_wait``) facade over any vector env.
 
 The training loops' critical path used to be ``fetch actions -> envs.step ->
-train dispatch`` — a fully serialized sum (PERF.md §2).  This wrapper gives
+train dispatch`` — a fully serialized sum (PERF.md §5).  This wrapper gives
 every executor one uniform async surface so the hot loops can issue the env
 step the moment the action values land, keep dispatching device work (train
 step, replay writes) while the env workers are stepping, and only block in

@@ -183,12 +183,14 @@ class LayerNormGRUCell(nn.Module):
     Call as ``new_h = cell(h, x)`` — scan-ready: the concatenated
     ``[h, x] @ W`` projection is a single MXU matmul per step.
 
-    ``fused=True`` routes eligible shapes through the Pallas TPU kernel
+    ``fused=True`` runs the step through the Pallas TPU kernel
     (``sheeprl_tpu/ops/pallas_gru.py``): projection + LayerNorm + gates in one
     VMEM-resident ``pallas_call``, with the weight matrix pinned in VMEM
     across the batch grid.  The parameter tree is identical to the unfused
-    path, so the flag is a pure runtime choice.  ``fused_interpret`` runs the
-    kernel in interpreter mode (CPU tests).
+    path.  A shape or backend the kernel cannot serve raises
+    ``FusedGRUUnavailable`` with the reason when the module is built — the
+    unfused result is never substituted.  ``fused_interpret`` runs the kernel
+    in interpreter mode (CPU tests).
     """
 
     hidden_size: int
@@ -218,13 +220,22 @@ class LayerNormGRUCell(nn.Module):
             else None
         )
 
-        use_fused = self.fused and self.layer_norm and joint.ndim == 2
-        if use_fused and not self.is_initializing():
-            from sheeprl_tpu.ops.pallas_gru import fused_gru_supported, fused_layernorm_gru
+        if self.fused:
+            from sheeprl_tpu.ops.pallas_gru import (
+                FusedGRUUnavailable,
+                fused_gru_ineligible,
+                fused_layernorm_gru,
+            )
 
-            if fused_gru_supported(joint.shape[-1], self.hidden_size) and (
-                self.fused_interpret or jax.default_backend() == "tpu"
-            ):
+            if not self.layer_norm or joint.ndim != 2:
+                reason = "the kernel fuses the LayerNorm of a [batch, features] step"
+            elif not self.fused_interpret and jax.default_backend() != "tpu":
+                reason = f"it lowers through Mosaic and the backend is {jax.default_backend()!r}"
+            else:
+                reason = fused_gru_ineligible(joint.shape[-1], self.hidden_size, joint.dtype)
+            if reason is not None:
+                raise FusedGRUUnavailable(f"fused LayerNorm-GRU (algo.rssm_pallas) unavailable: {reason}")
+            if not self.is_initializing():
                 params = self.variables["params"]
                 w = params["Dense_0"]["kernel"]
                 b = (

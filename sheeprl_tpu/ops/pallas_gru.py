@@ -17,56 +17,89 @@ with plain jnp ops (rematerialization) and reuses XLA's autodiff — the
 backward is a standard fused XLA graph, the forward (the op run T times per
 scan in both dynamic learning and imagination) is the Pallas kernel.
 
-Eligibility (checked by ``fused_gru_supported``): TPU backend (or
-``interpret=True`` for CPU tests), ``3H`` a lane multiple (all DV3 size
-presets satisfy this), and the weight block fitting VMEM.  Ineligible shapes
-fall back to the flax path.
+Eligibility (``fused_gru_ineligible``): ``3H`` a lane multiple (all DV3 size
+presets satisfy this) and the whole weight block plus one batch tile fitting
+the VMEM the call may claim — DV3-S does in both precisions (compiled and
+trained on a v5e), XL (W ~126 MB in bf16) does not.  The flax cell raises ``FusedGRUUnavailable``
+with the reason when ``fused=True`` meets an ineligible shape or a non-TPU
+backend; it never substitutes the unfused result.
 
-Measured on a v5-lite chip (H=512, B=1024, 64-step scan): the XLA-compiled
-flax cell runs the scan in ~147 ms vs ~230 ms through this kernel — XLA's own
-matmul+LN+gate fusion is already sufficient at RSSM shapes (consistent with
-SURVEY §2.8's "Pallas only where XLA fusion is insufficient"), so the fused
-path ships **off by default** (``algo.world_model.recurrent_model.
-fused_kernel``) as a verified building block for shapes where the balance
-tips (e.g. much larger H where W residency dominates).
+Speed against the XLA-compiled flax cell: not measured on this chip (ROADMAP
+S3 owns the lever sweep), so the fused path ships **off by default**
+(``algo.rssm_pallas``).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 _LANE = 128
-_SUBLANE = 8
-# keep W + one batch tile comfortably inside ~16 MB of VMEM
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _BATCH_BLOCK = 256
+# Mosaic's default scoped-VMEM limit (16 MiB on a v5e) is below what the
+# resident f32 weight block of DV3-S needs, so the call asks for what its
+# blocks take.  48 MiB is the most it will ask for: under half of a v5e
+# core's 128 MiB (``pltpu.get_tpu_info().vmem_capacity_bytes``).
+_VMEM_CEILING_BYTES = 48 * 1024 * 1024
+
+
+class FusedGRUUnavailable(ValueError):
+    """``fused=True`` (``algo.rssm_pallas``) on a shape or backend the Pallas
+    kernel cannot serve; the message carries the reason."""
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def fused_gru_supported(joint_dim: int, hidden_size: int, use_bias: bool = True) -> bool:
-    """Shape/platform eligibility for the fused kernel."""
-    del use_bias
-    if (3 * hidden_size) % _LANE != 0:
-        return False
+def _sublane(dtype) -> int:
+    """Rows per VMEM tile: 8 for 4-byte types, 16 for bf16 (packed pairs)."""
+    return 8 * (4 // np.dtype(dtype).itemsize)
+
+
+def _vmem_bytes(joint_dim: int, hidden_size: int, dtype) -> int:
+    """VMEM one grid step of a full batch block holds: W and the three
+    [1, 3H] vectors once (their block index never changes, so they are
+    single-buffered), the joint/h/out batch tiles double-buffered, and the
+    f32 projection with its LayerNorm and gate temporaries."""
+    itemsize = np.dtype(dtype).itemsize
     d_pad = _round_up(joint_dim, _LANE)
-    w_bytes = d_pad * 3 * hidden_size * 4
-    tile_bytes = _BATCH_BLOCK * (d_pad + 6 * hidden_size) * 4
-    return w_bytes + tile_bytes <= _VMEM_BUDGET_BYTES
+    three_h = 3 * hidden_size
+    resident = (d_pad + 3 * _sublane(dtype)) * three_h * itemsize
+    tiles = 2 * _BATCH_BLOCK * (d_pad + 2 * hidden_size) * itemsize
+    temporaries = 4 * _BATCH_BLOCK * three_h * 4
+    return resident + tiles + temporaries
+
+
+def fused_gru_ineligible(joint_dim: int, hidden_size: int, dtype=jnp.float32) -> Optional[str]:
+    """Why the kernel cannot take this shape (``None`` when it can)."""
+    if (3 * hidden_size) % _LANE != 0:
+        return f"3*hidden_size = {3 * hidden_size} is not a multiple of the {_LANE}-lane tile"
+    need = _vmem_bytes(joint_dim, hidden_size, dtype)
+    if need > _VMEM_CEILING_BYTES:
+        return (
+            f"the [{_round_up(joint_dim, _LANE)}, {3 * hidden_size}] {np.dtype(dtype).name} weight block "
+            f"plus one batch tile needs {need / 2**20:.0f} MiB of VMEM, over the "
+            f"{_VMEM_CEILING_BYTES / 2**20:.0f} MiB the kernel may claim (W is not tiled)"
+        )
+    return None
 
 
 def _gru_kernel(joint_ref, w_ref, b_ref, g_ref, beta_ref, h_ref, out_ref, *, eps: float):
     """One batch tile: projection (MXU, native input dtype with fp32
-    accumulation) + LayerNorm + gates (VPU, fp32)."""
-    a = jnp.dot(joint_ref[:], w_ref[:], preferred_element_type=jnp.float32) + b_ref[:].astype(
-        jnp.float32
-    )
+    accumulation) + LayerNorm + gates (VPU, fp32).  f32 operands follow the
+    ambient ``jax.default_matmul_precision`` like the flax cell does (the TPU
+    default rounds them to bf16); bf16 operands pin DEFAULT — Mosaic rejects
+    an fp32 contraction over bf16 vectors ("Bad lhs type"), which is what
+    ``matmul_precision=highest`` would otherwise ask for."""
+    precision = jax.lax.Precision.DEFAULT if joint_ref.dtype == jnp.bfloat16 else None
+    a = jnp.dot(
+        joint_ref[:], w_ref[:], precision=precision, preferred_element_type=jnp.float32
+    ) + b_ref[:].astype(jnp.float32)
     # LayerNorm over the 3H projection
     mean = jnp.mean(a, axis=-1, keepdims=True)
     centered = a - mean
@@ -91,9 +124,10 @@ def _gru_pallas(joint: jax.Array, w: jax.Array, b: jax.Array, g: jax.Array, beta
     three_h = 3 * hidden
 
     # pad the contraction dim to lanes (zero rows of W contribute nothing) and
-    # the batch dim to the tile grid
+    # the batch dim to the tile grid (whole sublane tiles of the input dtype:
+    # the player's batch of 1 becomes 16 rows in bf16, 8 in f32)
     d_pad = _round_up(joint_dim, _LANE)
-    bm = min(_BATCH_BLOCK, _round_up(batch, _SUBLANE))
+    bm = min(_BATCH_BLOCK, _round_up(batch, max(_sublane(joint.dtype), _sublane(h.dtype))))
     b_pad = _round_up(batch, bm)
     if d_pad != joint_dim:
         joint = jnp.pad(joint, ((0, 0), (0, d_pad - joint_dim)))
@@ -102,19 +136,26 @@ def _gru_pallas(joint: jax.Array, w: jax.Array, b: jax.Array, g: jax.Array, beta
         joint = jnp.pad(joint, ((0, b_pad - batch), (0, 0)))
         h = jnp.pad(h, ((0, b_pad - batch), (0, 0)))
 
+    def resident(shape):
+        # same block at every grid step: one buffer, fetched once
+        return pl.BlockSpec(
+            shape, lambda i: (0, 0), memory_space=pltpu.VMEM, pipeline_mode=pl.Buffered(1)
+        )
+
     out = pl.pallas_call(
         functools.partial(_gru_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct((b_pad, hidden), h.dtype),
         grid=(b_pad // bm,),
         in_specs=[
             pl.BlockSpec((bm, d_pad), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((d_pad, three_h), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, three_h), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, three_h), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, three_h), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            resident((d_pad, three_h)),
+            resident((1, three_h)),
+            resident((1, three_h)),
+            resident((1, three_h)),
             pl.BlockSpec((bm, hidden), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((bm, hidden), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_CEILING_BYTES),
         interpret=interpret,
     )(joint, w, b.reshape(1, -1), g.reshape(1, -1), beta.reshape(1, -1), h)
     return out[:batch]
@@ -122,7 +163,7 @@ def _gru_pallas(joint: jax.Array, w: jax.Array, b: jax.Array, g: jax.Array, beta
 
 def _gru_reference(joint, w, b, g, beta, h, eps):
     """Plain-jnp step, numerically identical to the kernel — used for the
-    custom-VJP backward (remat) and as the fallback path."""
+    custom-VJP backward (remat)."""
     a = jnp.dot(joint, w, preferred_element_type=jnp.float32) + b.astype(jnp.float32)
     mean = jnp.mean(a, axis=-1, keepdims=True)
     centered = a - mean

@@ -121,7 +121,7 @@ def dp_jit(
         return jax.jit(constrained, donate_argnums=donate_argnums)
     if dp_axis(mesh) is None:
         return jax.jit(fn, donate_argnums=donate_argnums)
-    from sheeprl_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mapped = shard_map(fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=donate_argnums)

@@ -26,12 +26,14 @@ MODEL_AXIS = "model"
 
 
 def make_mesh(
-    n_devices: Optional[int] = None,
-    axis_names: Sequence[str] = ("data",),
     devices: Optional[Sequence[Any]] = None,
+    axis_names: Sequence[str] = ("data",),
     axis_sizes: Optional[Sequence[int]] = None,
+    n_devices: Optional[int] = None,
 ) -> Mesh:
-    """Build the device mesh.
+    """Build the device mesh over ``devices`` (the `Runtime` passes the ones
+    its accelerator setting selected); without them, over the first
+    ``n_devices`` of JAX's default backend.
 
     1-D (the default): all devices on one axis.  2-D (``("data", "model")``):
     ``axis_sizes`` gives the extent of every axis — the trailing (``model``)
@@ -39,9 +41,7 @@ def make_mesh(
     on the fastest links, exactly the GSPMD mesh-major convention.
     """
     if devices is None:
-        devices = jax.devices()
-    if n_devices is not None:
-        devices = devices[:n_devices]
+        devices = jax.devices()[:n_devices]
     arr = np.asarray(devices)
     if len(axis_names) == 1:
         return Mesh(arr.reshape(-1), axis_names)

@@ -14,7 +14,7 @@ a single-process XLA server on the repo's own building blocks:
 * :mod:`~sheeprl_tpu.serving.batcher` — the dynamic request batcher: requests
   queue for up to ``serving.max_delay_ms``, are padded to the nearest
   MXU-friendly bucket width (``serving.batch_buckets``, defaults derived from
-  the PERF.md §4 batch-width table) and dispatched as ONE device step; padded
+  the PERF.md §5 batch-width table) and dispatched as ONE device step; padded
   rows never leak into responses; beyond ``serving.max_queue`` load is shed
   with 503 + ``Retry-After``;
 * :mod:`~sheeprl_tpu.serving.sessions` — device-resident recurrent state for

@@ -1,7 +1,7 @@
 """Dynamic request batching: many concurrent ``/act`` requests, one device
 dispatch.
 
-The capacity model is PERF.md §4: a single-row policy apply leaves almost the
+The capacity model is PERF.md §5: a single-row policy apply leaves almost the
 whole MXU idle, and throughput rises with batch rows essentially for free
 until the systolic array saturates.  So requests queue for up to
 ``max_delay_ms`` (or until a full bucket is waiting), the group is padded to
@@ -32,7 +32,7 @@ import numpy as np
 
 from sheeprl_tpu.diagnostics.tracing import NullTracer
 
-#: MXU-friendly default widths (PERF.md §4: MFU rises monotonically with
+#: MXU-friendly default widths (PERF.md §5: MFU rises monotonically with
 #: batch rows; 8 is the smallest width worth a dispatch, 128 the systolic
 #: array's row count).  ``configs/serving/default.yaml`` mirrors this.
 DEFAULT_BUCKETS: Tuple[int, ...] = (8, 16, 32, 64, 128)

@@ -146,7 +146,7 @@ def get_log_dir(runtime, root_dir: str, run_name: str, share: bool = True) -> st
     # crash-safe journal under the CLI, which attaches the facade pre-launch.
     diagnostics = getattr(runtime, "diagnostics", None)
     if diagnostics is not None:
-        diagnostics.open(log_dir, rank_zero=runtime.is_global_zero)
+        diagnostics.open(log_dir, rank_zero=runtime.is_global_zero, device=runtime.device_info)
     return log_dir
 
 

@@ -182,7 +182,7 @@ def get_diagnostics(runtime, cfg: Mapping[str, Any], log_dir: str):
     if diag is None:
         diag = build_diagnostics(cfg)
         runtime.diagnostics = diag
-    diag.open(log_dir, rank_zero=runtime.is_global_zero)
+    diag.open(log_dir, rank_zero=runtime.is_global_zero, device=runtime.device_info)
     return diag
 
 

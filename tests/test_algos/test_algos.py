@@ -34,8 +34,7 @@ def devices(request):
 
 
 # The dreamer-family e2e runs compile multi-minute shard_mapped graphs at 2
-# virtual devices (unblocked by the parallel/compat.py shard_map shim — they
-# used to fail at import in seconds).  The tier-1 smoke (-m 'not slow') keeps
+# virtual devices.  The tier-1 smoke (-m 'not slow') keeps
 # the cheap 2-device proofs (ppo / a2c / sac / recurrent / decoupled / the
 # sharding-HLO checks) inside its wall-clock budget and defers these heavy
 # ones to the CI e2e suite: tests/run_tests.py runs tests/test_algos/ WITHOUT

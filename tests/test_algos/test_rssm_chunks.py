@@ -1,4 +1,4 @@
-"""Chunked sequence-parallel RSSM scan (PERF.md §4, ROADMAP item 2).
+"""Chunked sequence-parallel RSSM scan (PERF.md §5, ROADMAP item 2).
 
 The contract under test, layer by layer:
 
@@ -164,7 +164,7 @@ def test_chunks1_ignores_stored_state_and_matches_same_unroll():
     for unroll in (1, 4):
         # bit-identity is per unroll factor: an unrolled lax.scan is a
         # different XLA graph whose fusions may round differently (exactly
-        # why PERF.md §4 compares step_ms, not values, across unrolls) — so
+        # why PERF.md §5 compares step_ms, not values, across unrolls) — so
         # each arm is compared against the plain scan at the SAME unroll
         keys_t = jax.random.split(key, T)
         init = (jnp.zeros((B, Z)), jnp.zeros((B, H)))

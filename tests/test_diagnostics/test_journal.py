@@ -89,19 +89,36 @@ def test_facade_rank_gating(tmp_path):
 
 
 def test_facade_run_lifecycle_and_config_hash(tmp_path):
+    import jax
+
     diag = build_diagnostics(DIAG_CFG)
-    diag.open(str(tmp_path), rank_zero=True)
+    device = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
+    diag.open(str(tmp_path), rank_zero=True, device=device)
     diag.log_metrics(16, {"Rewards/rew_avg": 0.5})
     diag.on_checkpoint(16, "ckpt_16.ckpt")
     diag.close("completed")
     events = read_journal(str(tmp_path / "journal.jsonl"))
     kinds = [e["event"] for e in events]
-    # telemetry (default-on since ISSUE 3) and memory (default-on since
-    # ISSUE 4) each close with a cumulative summary right before run_end
-    assert kinds == ["run_start", "metrics", "checkpoint", "telemetry_summary", "memory_summary", "run_end"]
+    # the compile-cache directory in force (the suite's, tests/conftest.py) is
+    # journaled right after run_start; telemetry (default-on since ISSUE 3)
+    # and memory (default-on since ISSUE 4) each close with a cumulative
+    # summary right before run_end
+    assert kinds == [
+        "run_start",
+        "compilation_cache",
+        "metrics",
+        "checkpoint",
+        "telemetry_summary",
+        "memory_summary",
+        "run_end",
+    ]
     start = events[0]
     assert start["algo"] == "ppo" and start["env"] == "discrete_dummy"
     assert len(start["config_hash"]) == 16
+    # what the mesh resolved to rides run_start, so a CPU run is never
+    # mistaken for a chip run
+    assert {k: start[k] for k in device} == device
+    assert events[1]["dir"] == jax.config.jax_compilation_cache_dir
     assert events[-1]["status"] == "completed"
     # close is idempotent and open-once: no duplicate run_end
     diag.close("again")

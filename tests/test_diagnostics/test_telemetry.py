@@ -117,7 +117,7 @@ def test_instrumented_train_step_captures_cost_and_stays_correct(tmp_path):
 
 
 def test_cost_note_caveat_rides_the_telemetry_cost_event(tmp_path):
-    """Callers with inflated cost_analysis FLOPs (unrolled scans — PERF.md §4)
+    """Callers with inflated cost_analysis FLOPs (unrolled scans — PERF.md §5)
     declare it via instrument(cost_note=...); the caveat must land on the
     journaled telemetry_cost event so MFU is never silently over-read."""
     import jax

@@ -349,16 +349,23 @@ _COMMON_CLI = [
 ]
 
 
-def test_cli_smoke_ppo_shared_memory(run_cli):
+def test_cli_smoke_ppo_shared_memory(run_cli, tmp_path, monkeypatch):
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
+    # absolute: the cache is process-global and outlives this test's cwd.
+    # Without the suite's environment variable the config key places it.
+    cache_dir = str(tmp_path / "jit_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    cache_knobs = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs")
+    saved = {knob: getattr(jax.config, knob) for knob in cache_knobs}
     try:
         run_cli(
             "exp=ppo",
             *_COMMON_CLI,
             "env.envs_per_worker=2",  # one 2-env slab worker
             "diagnostics.trace.enabled=True",
-            "diagnostics.compilation_cache_dir=logs/jit_cache",
+            f"diagnostics.compilation_cache_dir={cache_dir}",
             "algo.rollout_steps=8",
             "algo.per_rank_batch_size=4",
             "algo.update_epochs=1",
@@ -366,8 +373,10 @@ def test_cli_smoke_ppo_shared_memory(run_cli):
             "algo.cnn_keys.encoder=[]",
         )
     finally:
-        # jax config is process-global: don't leave the suite writing caches
-        jax.config.update("jax_compilation_cache_dir", None)
+        # jax config is process-global: hand the suite its own cache back
+        for knob, value in saved.items():
+            jax.config.update(knob, value)
+        compilation_cache.reset_cache()
     assert sorted(Path("logs").rglob("*.ckpt")), "no checkpoint written"
 
     # env-throughput telemetry (ISSUE 7): the batched-inference loop must
@@ -378,8 +387,8 @@ def test_cli_smoke_ppo_shared_memory(run_cli):
     journal = sorted(Path("logs").rglob("journal.jsonl"))[-1]
     events = [_json.loads(line) for line in journal.read_text().splitlines() if line.strip()]
     cache_events = [e for e in events if e.get("event") == "compilation_cache"]
-    assert cache_events and cache_events[0]["dir"] == "logs/jit_cache"
-    assert Path("logs/jit_cache").is_dir()
+    assert cache_events and cache_events[0]["dir"] == cache_dir
+    assert any(Path(cache_dir).iterdir()), "nothing was cached where the journal says"
     metric_rows = [e["metrics"] for e in events if e.get("event") == "metrics"]
     env_sps = [m["Telemetry/env_steps_per_sec"] for m in metric_rows if "Telemetry/env_steps_per_sec" in m]
     amort = [m["Telemetry/fetch_amortization"] for m in metric_rows if "Telemetry/fetch_amortization" in m]

@@ -70,7 +70,7 @@ def test_dv3_moments_quantile_is_global():
     from sheeprl_tpu.algos.dreamer_v3.utils import update_moments
 
     mesh = make_mesh(n_devices=N_DEV)
-    from sheeprl_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     def body(state, x):
         _, _, new_state = update_moments(state, x, decay=0.0, axis_name="data")

@@ -1,7 +1,8 @@
 """Stateful serving acceptance e2e (ISSUE 16): recurrent and model-based
 policies trained through the REAL CLI, served over HTTP sessions, and proven
-**bit-identical** to the training-side player loop — including ``is_first``
-resets, LRU eviction + re-init, multi-model routing with independent
+to reproduce the training-side player loop (identical actions; state bit for
+bit at equal dispatch width, to a few ulp across widths) — including
+``is_first`` resets, LRU eviction + re-init, multi-model routing with independent
 promotion gates, and the request-log -> offline-training flywheel.
 """
 
@@ -155,8 +156,11 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
     N interleaved sessions, against a host-side mirror of the TRAINING
     player's state handling (keep-mask resets, one-hot prev-action feed,
     ``ppo_recurrent.py``'s env loop) running the same agent apply: every
-    action bit-identical, including the ``reset`` flag mid-episode and the
-    re-initialized state after an LRU eviction."""
+    action identical, including the ``reset`` flag mid-episode and the
+    re-initialized state after an LRU eviction; the recurrent state equal to
+    a few float32 ulp (the served width-2 dispatch and the player's width-1
+    apply are different XLA executables — bit equality across dispatch widths
+    is not something the backend promises)."""
     run([*RECURRENT_TINY, "dry_run=True", "checkpoint.save_last=True"])
     (ckpt,) = sorted(Path("logs").rglob("*.ckpt"))
 
@@ -254,15 +258,15 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
         store = app.service.sessions
         assert store.sessions() == ["b", "a"]
         assert store.created_total == 5 and store.evictions_total == 3
-        # the device-resident slab state itself is bit-identical to the
-        # player mirror (a far stronger parity than the argmax'd actions)
+        # the device-resident slab state itself matches the player mirror
+        # to a few ulp (a far stronger parity than the argmax'd actions)
         for sid in ("b", "a"):
             slot = store._lru[sid]
-            np.testing.assert_array_equal(
-                np.asarray(store.slab["hx"])[slot], mirror[sid]["hx"]
+            np.testing.assert_allclose(
+                np.asarray(store.slab["hx"])[slot], mirror[sid]["hx"], rtol=1e-5, atol=1e-7
             )
-            np.testing.assert_array_equal(
-                np.asarray(store.slab["cx"])[slot], mirror[sid]["cx"]
+            np.testing.assert_allclose(
+                np.asarray(store.slab["cx"])[slot], mirror[sid]["cx"], rtol=1e-5, atol=1e-7
             )
             np.testing.assert_array_equal(
                 np.asarray(store.slab["prev_actions"])[slot], mirror[sid]["prev"]

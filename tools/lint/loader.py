@@ -34,6 +34,7 @@ PY_ROOT_FILES = (
     "sheeprl_eval.py",
     "sheeprl_model_manager.py",
     "bench.py",
+    "chip_smoke.py",
     "__graft_entry__.py",
 )
 CONFIGS_DIR = "sheeprl_tpu/configs/"

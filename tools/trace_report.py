@@ -14,7 +14,7 @@ the report's instant-markers section).  This tool uses those anchors to:
   pair, or the per-rank ``trace_rank{N}.json`` files of a multihost run — into
   ONE Chrome/Perfetto-loadable timeline (``--out merged.json``),
 * print the per-phase wall-clock table (count / total / mean / share per
-  role) that PERF.md §3 used to hand-compute from isolated runs, and
+  role) that PERF.md used to hand-compute from isolated runs, and
 * overlay the run-state machine (ISSUE 8) as its own track: when a *run dir*
   argument also contains a ``journal.jsonl``, its ``state_change`` /
   ``stall`` / ``stall_end`` events and per-interval ``Telemetry/run_state``
