@@ -1,0 +1,8 @@
+"""Device milliseconds of one ``jit_train_step`` run under
+``decoder_heads``: decoder, reward and continue heads and the world-model loss, forward and backward."""
+
+from benchmarks.chip.span_reduce import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "decoder_heads")

@@ -1,0 +1,8 @@
+"""Device milliseconds of one ``jit_train_step`` run under
+``optim``: clipping, the three optimizer updates and the target critic's."""
+
+from benchmarks.chip.span_reduce import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "optim")

@@ -1,0 +1,8 @@
+"""Device-busy milliseconds inside one execution of the player's forward pass (``jit_player_step``),
+from the device trace."""
+
+from benchmarks.chip.span_reduce import module_ms
+
+
+def read(run):
+    return module_ms(run, "player_step")

@@ -129,10 +129,17 @@ def make_train_step(
             copy = lambda tree: jax.tree_util.tree_map(lambda leaf: leaf, tree)  # noqa: E731
             prev_state = (copy(params), copy(opt_states), moments_state)
 
+        def apply_optimizer(module, grads):
+            with jax.named_scope("optim"):  # clip + Adam + apply, the same for the three modules
+                updates, opt_states[module] = optimizers[module].update(grads, opt_states[module], params[module])
+                params[module] = optax.apply_updates(params[module], updates)
+            return updates
+
         # --- target critic Polyak update (reference dreamer_v3.py:713-720) --
-        params["target_critic"] = jax.tree_util.tree_map(
-            lambda c, t: tau * c + (1 - tau) * t, params["critic"], params["target_critic"]
-        )
+        with jax.named_scope("optim"):
+            params["target_critic"] = jax.tree_util.tree_map(
+                lambda c, t: tau * c + (1 - tau) * t, params["critic"], params["target_critic"]
+            )
 
         # loss-side targets stay fp32; the compute path runs in `cdt` via the
         # JMP-style casts at each loss entry (params + inputs -> cdt, flax
@@ -148,7 +155,8 @@ def make_train_step(
         # ---------------- DYNAMIC LEARNING ---------------------------------
         def wm_loss_fn(wm_params):
             wm_params = cast_floating(wm_params, cdt)
-            embedded = world_model_def.apply(wm_params, batch_obs, method="encode")
+            with jax.named_scope("encoder"):
+                embedded = world_model_def.apply(wm_params, batch_obs, method="encode")
 
             def scan_body(carry, x):
                 posterior, recurrent = carry
@@ -158,52 +166,54 @@ def make_train_step(
                 )
                 return (posterior, recurrent), (recurrent, posterior, post_logits, prior_logits)
 
-            recurrents, posteriors, post_logits, prior_logits = chunked_dynamic_scan(
-                scan_body,
-                batch_actions,
-                embedded,
-                is_first,
-                k_wm,
-                stoch_flat=stoch_flat,
-                recurrent_size=recurrent_size,
-                cdt=cdt,
-                chunks=rssm_chunks,
-                burn_in=rssm_burn_in,
-                stored_recurrent=batch.get("rssm_recurrent"),
-                stored_posterior=batch.get("rssm_posterior"),
-                stored_valid=batch.get("rssm_valid"),
-                unroll=scan_unroll,
-            )
-            latents = jnp.concatenate([posteriors, recurrents], axis=-1)
-            recon = world_model_def.apply(wm_params, latents, method="decode")
-            po = {k: MSEDistribution(recon[k], dims=len(recon[k].shape[2:])) for k in cnn_dec_keys}
-            po.update(
-                {k: SymlogDistribution(recon[k], dims=len(recon[k].shape[2:])) for k in mlp_dec_keys}
-            )
-            pr = TwoHotEncodingDistribution(
-                world_model_def.apply(wm_params, latents, method="reward_logits"), dims=1
-            )
-            pc = Bernoulli(
-                world_model_def.apply(wm_params, latents, method="continue_logits"), event_dims=1
-            )
-            continues_targets = 1 - batch["terminated"]
-            pl = prior_logits.reshape(T, B, wm_cfg.stochastic_size, wm_cfg.discrete_size)
-            ql = post_logits.reshape(T, B, wm_cfg.stochastic_size, wm_cfg.discrete_size)
-            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po,
-                target_obs,
-                pr,
-                batch["rewards"],
-                pl,
-                ql,
-                wm_cfg.kl_dynamic,
-                wm_cfg.kl_representation,
-                wm_cfg.kl_free_nats,
-                wm_cfg.kl_regularizer,
-                pc,
-                continues_targets,
-                wm_cfg.continue_scale_factor,
-            )
+            with jax.named_scope("rssm_scan"):
+                recurrents, posteriors, post_logits, prior_logits = chunked_dynamic_scan(
+                    scan_body,
+                    batch_actions,
+                    embedded,
+                    is_first,
+                    k_wm,
+                    stoch_flat=stoch_flat,
+                    recurrent_size=recurrent_size,
+                    cdt=cdt,
+                    chunks=rssm_chunks,
+                    burn_in=rssm_burn_in,
+                    stored_recurrent=batch.get("rssm_recurrent"),
+                    stored_posterior=batch.get("rssm_posterior"),
+                    stored_valid=batch.get("rssm_valid"),
+                    unroll=scan_unroll,
+                )
+            with jax.named_scope("decoder_heads"):
+                latents = jnp.concatenate([posteriors, recurrents], axis=-1)
+                recon = world_model_def.apply(wm_params, latents, method="decode")
+                po = {k: MSEDistribution(recon[k], dims=len(recon[k].shape[2:])) for k in cnn_dec_keys}
+                po.update(
+                    {k: SymlogDistribution(recon[k], dims=len(recon[k].shape[2:])) for k in mlp_dec_keys}
+                )
+                pr = TwoHotEncodingDistribution(
+                    world_model_def.apply(wm_params, latents, method="reward_logits"), dims=1
+                )
+                pc = Bernoulli(
+                    world_model_def.apply(wm_params, latents, method="continue_logits"), event_dims=1
+                )
+                continues_targets = 1 - batch["terminated"]
+                pl = prior_logits.reshape(T, B, wm_cfg.stochastic_size, wm_cfg.discrete_size)
+                ql = post_logits.reshape(T, B, wm_cfg.stochastic_size, wm_cfg.discrete_size)
+                rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                    po,
+                    target_obs,
+                    pr,
+                    batch["rewards"],
+                    pl,
+                    ql,
+                    wm_cfg.kl_dynamic,
+                    wm_cfg.kl_representation,
+                    wm_cfg.kl_free_nats,
+                    wm_cfg.kl_regularizer,
+                    pc,
+                    continues_targets,
+                    wm_cfg.continue_scale_factor,
+                )
             aux = {
                 "posteriors": posteriors,
                 "recurrents": recurrents,
@@ -219,10 +229,7 @@ def make_train_step(
 
         (rec_loss, aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(params["world_model"])
         wm_grads = pmean_tree(wm_grads, axis)
-        wm_updates, opt_states["world_model"] = optimizers["world_model"].update(
-            wm_grads, opt_states["world_model"], params["world_model"]
-        )
-        params["world_model"] = optax.apply_updates(params["world_model"], wm_updates)
+        wm_updates = apply_optimizer("world_model", wm_grads)
 
         # ---------------- BEHAVIOUR LEARNING -------------------------------
         # (uses the freshly updated world model, like the reference)
@@ -233,69 +240,71 @@ def make_train_step(
 
         def actor_loss_fn(actor_params, moments_state):
             actor_params = cast_floating(actor_params, cdt)
-            latent0 = jnp.concatenate([posteriors, recurrents], axis=-1)
-            a0 = actor_def.apply(actor_params, jax.lax.stop_gradient(latent0), k_img_actions, False, method="act")
+            with jax.named_scope("imagination"):
+                latent0 = jnp.concatenate([posteriors, recurrents], axis=-1)
+                a0 = actor_def.apply(actor_params, jax.lax.stop_gradient(latent0), k_img_actions, False, method="act")
 
-            def img_body(carry, key_t):
-                prior, recurrent, actions = carry
-                k_dyn, k_act = jax.random.split(key_t)
-                prior, recurrent = world_model_def.apply(
-                    wm_params, prior, recurrent, actions, k_dyn, method="imagination"
+                def img_body(carry, key_t):
+                    prior, recurrent, actions = carry
+                    k_dyn, k_act = jax.random.split(key_t)
+                    prior, recurrent = world_model_def.apply(
+                        wm_params, prior, recurrent, actions, k_dyn, method="imagination"
+                    )
+                    latent = jnp.concatenate([prior, recurrent], axis=-1)
+                    actions = actor_def.apply(
+                        actor_params, jax.lax.stop_gradient(latent), k_act, False, method="act"
+                    )
+                    return (prior, recurrent, actions), (latent, actions)
+
+                keys_h = jax.random.split(k_img, horizon)
+                _, (latents_h, actions_h) = jax.lax.scan(img_body, (posteriors, recurrents, a0), keys_h, unroll=scan_unroll)
+                imagined_trajectories = jnp.concatenate([latent0[None], latents_h], axis=0)  # [H+1, TB, L]
+                imagined_actions = jnp.concatenate([a0[None], actions_h], axis=0)
+
+            with jax.named_scope("behaviour_losses"):
+                predicted_values = TwoHotEncodingDistribution(
+                    critic_def.apply(cast_floating(params["critic"], cdt), imagined_trajectories), dims=1
+                ).mean
+                predicted_rewards = TwoHotEncodingDistribution(
+                    world_model_def.apply(wm_params, imagined_trajectories, method="reward_logits"), dims=1
+                ).mean
+                continues = Bernoulli(
+                    world_model_def.apply(wm_params, imagined_trajectories, method="continue_logits"),
+                    event_dims=1,
+                ).mode
+                continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
+
+                lambda_values = compute_lambda_values(
+                    predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda=cfg.algo.lmbda
                 )
-                latent = jnp.concatenate([prior, recurrent], axis=-1)
-                actions = actor_def.apply(
-                    actor_params, jax.lax.stop_gradient(latent), k_act, False, method="act"
+                discount = jnp.cumprod(continues * gamma, axis=0) / gamma
+                discount = jax.lax.stop_gradient(discount)
+
+                baseline = predicted_values[:-1]
+                offset, invscale, new_moments = update_moments(
+                    moments_state,
+                    lambda_values,
+                    cfg.algo.actor.moments.decay,
+                    cfg.algo.actor.moments.max,
+                    cfg.algo.actor.moments.percentile.low,
+                    cfg.algo.actor.moments.percentile.high,
+                    axis_name=axis,
                 )
-                return (prior, recurrent, actions), (latent, actions)
-
-            keys_h = jax.random.split(k_img, horizon)
-            _, (latents_h, actions_h) = jax.lax.scan(img_body, (posteriors, recurrents, a0), keys_h, unroll=scan_unroll)
-            imagined_trajectories = jnp.concatenate([latent0[None], latents_h], axis=0)  # [H+1, TB, L]
-            imagined_actions = jnp.concatenate([a0[None], actions_h], axis=0)
-
-            predicted_values = TwoHotEncodingDistribution(
-                critic_def.apply(cast_floating(params["critic"], cdt), imagined_trajectories), dims=1
-            ).mean
-            predicted_rewards = TwoHotEncodingDistribution(
-                world_model_def.apply(wm_params, imagined_trajectories, method="reward_logits"), dims=1
-            ).mean
-            continues = Bernoulli(
-                world_model_def.apply(wm_params, imagined_trajectories, method="continue_logits"),
-                event_dims=1,
-            ).mode
-            continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
-
-            lambda_values = compute_lambda_values(
-                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda=cfg.algo.lmbda
-            )
-            discount = jnp.cumprod(continues * gamma, axis=0) / gamma
-            discount = jax.lax.stop_gradient(discount)
-
-            baseline = predicted_values[:-1]
-            offset, invscale, new_moments = update_moments(
-                moments_state,
-                lambda_values,
-                cfg.algo.actor.moments.decay,
-                cfg.algo.actor.moments.max,
-                cfg.algo.actor.moments.percentile.low,
-                cfg.algo.actor.moments.percentile.high,
-                axis_name=axis,
-            )
-            normed_lambda_values = (lambda_values - offset) / invscale
-            normed_baseline = (baseline - offset) / invscale
-            advantage = normed_lambda_values - normed_baseline
-            log_probs, entropies = actor_def.apply(
-                actor_params,
-                jax.lax.stop_gradient(imagined_trajectories),
-                jax.lax.stop_gradient(imagined_actions),
-                method="log_prob_entropy",
-            )
-            if is_continuous:
-                objective = advantage
-            else:
-                objective = log_probs[:-1] * jax.lax.stop_gradient(advantage)
-            entropy = cfg.algo.actor.ent_coef * entropies
-            policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[:-1]))
+                normed_lambda_values = (lambda_values - offset) / invscale
+                normed_baseline = (baseline - offset) / invscale
+                advantage = normed_lambda_values - normed_baseline
+                log_probs, entropies = actor_def.apply(
+                    actor_params,
+                    jax.lax.stop_gradient(imagined_trajectories),
+                    jax.lax.stop_gradient(imagined_actions),
+                    method="log_prob_entropy",
+                )
+                if is_continuous:
+                    objective = advantage
+                else:
+                    objective = log_probs[:-1] * jax.lax.stop_gradient(advantage)
+                entropy = cfg.algo.actor.ent_coef * entropies
+                policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[:-1]))
             aux2 = {
                 "imagined_trajectories": jax.lax.stop_gradient(imagined_trajectories),
                 "lambda_values": jax.lax.stop_gradient(lambda_values),
@@ -308,10 +317,7 @@ def make_train_step(
             params["actor"], moments_state
         )
         actor_grads = pmean_tree(actor_grads, axis)
-        actor_updates, opt_states["actor"] = optimizers["actor"].update(
-            actor_grads, opt_states["actor"], params["actor"]
-        )
-        params["actor"] = optax.apply_updates(params["actor"], actor_updates)
+        actor_updates = apply_optimizer("actor", actor_grads)
         moments_state = aux2["moments"]
 
         # ---------------- CRITIC LEARNING ----------------------------------
@@ -320,23 +326,21 @@ def make_train_step(
         discount = aux2["discount"]
 
         def critic_loss_fn(critic_params):
-            qv = TwoHotEncodingDistribution(
-                critic_def.apply(cast_floating(critic_params, cdt), imagined_trajectories[:-1]), dims=1
-            )
-            predicted_target_values = TwoHotEncodingDistribution(
-                critic_def.apply(cast_floating(params["target_critic"], cdt), imagined_trajectories[:-1]),
-                dims=1,
-            ).mean
-            value_loss = -qv.log_prob(lambda_values)
-            value_loss = value_loss - qv.log_prob(jax.lax.stop_gradient(predicted_target_values))
-            return jnp.mean(value_loss * discount[:-1, ..., 0])
+            with jax.named_scope("behaviour_losses"):
+                qv = TwoHotEncodingDistribution(
+                    critic_def.apply(cast_floating(critic_params, cdt), imagined_trajectories[:-1]), dims=1
+                )
+                predicted_target_values = TwoHotEncodingDistribution(
+                    critic_def.apply(cast_floating(params["target_critic"], cdt), imagined_trajectories[:-1]),
+                    dims=1,
+                ).mean
+                value_loss = -qv.log_prob(lambda_values)
+                value_loss = value_loss - qv.log_prob(jax.lax.stop_gradient(predicted_target_values))
+                return jnp.mean(value_loss * discount[:-1, ..., 0])
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
         critic_grads = pmean_tree(critic_grads, axis)
-        critic_updates, opt_states["critic"] = optimizers["critic"].update(
-            critic_grads, opt_states["critic"], params["critic"]
-        )
-        params["critic"] = optax.apply_updates(params["critic"], critic_updates)
+        critic_updates = apply_optimizer("critic", critic_grads)
 
         metrics = jnp.stack(
             [
@@ -763,16 +767,18 @@ def _dreamer_main(
                     )
             else:
                 rng_key, step_key = jax.random.split(rng_key)
-                torch_obs = prepare_obs(
-                    obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs, sharding=stage_sharding
-                )
+                with diag.span("rollout/obs-stage"):
+                    torch_obs = prepare_obs(
+                        obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs, sharding=stage_sharding
+                    )
                 # mask_* observation keys feed MinedojoActor's hierarchical
                 # action masking (reference dreamer_v3.py:614-617)
                 mask = {k: v for k, v in torch_obs.items() if k.startswith("mask")} or None
-                actions_jnp = player.get_actions(
-                    params["world_model"], player_actor_fn(params, has_trained), torch_obs, step_key,
-                    mask=mask,
-                )
+                with diag.span("rollout/player-forward"):
+                    actions_jnp = player.get_actions(
+                        params["world_model"], player_actor_fn(params, has_trained), torch_obs, step_key,
+                        mask=mask,
+                    )
                 if use_device_buffer:
                     # device-resident actions go straight into the HBM ring
                     # (no fetch needed for the write); the chunked-scan state
@@ -787,19 +793,23 @@ def _dreamer_main(
                                 valid=True,
                             )
                         )
-                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
+                    with diag.span("rollout/replay-add"):
+                        rb.add(step_data, validate_args=cfg.buffer.validate_args)
                 diag.note_fetch()  # the iteration's ONE blocking d2h
-                if store_rssm_state and not use_device_buffer:
-                    # the stored states ride the SAME blocking fetch as the
-                    # action values — still one d2h round trip per vector step
-                    actions, host_recurrent, host_stochastic = fetch_values(
-                        actions_jnp, player.state["recurrent"], player.state["stochastic"]
-                    )
-                    step_data.update(
-                        rssm_state_slab(num_envs, host_recurrent, host_stochastic, valid=True)
-                    )
-                else:
-                    actions = np.asarray(actions_jnp)  # blocking value fetch
+                # the host waits here for the device: the player forward and
+                # whatever was queued before it
+                with diag.span("rollout/action-fetch"):
+                    if store_rssm_state and not use_device_buffer:
+                        # the stored states ride the SAME blocking fetch as the
+                        # action values — still one d2h round trip per vector step
+                        actions, host_recurrent, host_stochastic = fetch_values(
+                            actions_jnp, player.state["recurrent"], player.state["stochastic"]
+                        )
+                        step_data.update(
+                            rssm_state_slab(num_envs, host_recurrent, host_stochastic, valid=True)
+                        )
+                    else:
+                        actions = np.asarray(actions_jnp)  # blocking value fetch
                 real_actions = split_real_actions(actions)
                 if not use_device_buffer:
                     step_data["actions"] = actions.reshape(1, num_envs, -1)
@@ -807,7 +817,8 @@ def _dreamer_main(
                 envs.step_async(real_actions.reshape(envs.action_space.shape))
             if actions_jnp is None or not use_device_buffer:
                 # prefill / host-buffer write — overlaps the env workers
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
+                with diag.span("rollout/replay-add"):
+                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
         # ---- dispatch this iteration's gradient steps ---------------------
         # Runs while the env workers are stepping.  The sample includes
@@ -868,111 +879,114 @@ def _dreamer_main(
             next_obs, rewards, terminated, truncated, infos = envs.step_wait()
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            for i, agent_roe in enumerate(infos["restart_on_exception"]):
-                if agent_roe and not dones[i]:
-                    if use_device_buffer:
-                        rb.mark_last_truncated(i)
-                    else:
-                        sub = rb.buffer[i]
-                        last_idx = (sub._pos - 1) % sub.buffer_size
-                        sub["terminated"][last_idx] = np.zeros_like(sub["terminated"][last_idx])
-                        sub["truncated"][last_idx] = np.ones_like(sub["truncated"][last_idx])
-                        sub["is_first"][last_idx] = np.zeros_like(sub["is_first"][last_idx])
-                    step_data["is_first"][0, i] = np.ones_like(step_data["is_first"][0, i])
+        # ---- bookkeeping: host work between the env's results and the
+        # checkpoint test (obs copies, reset rows, aggregator, metric drain)
+        with diag.span("bookkeeping"):
+            step_data["is_first"] = np.zeros_like(step_data["terminated"])
+            if "restart_on_exception" in infos:
+                for i, agent_roe in enumerate(infos["restart_on_exception"]):
+                    if agent_roe and not dones[i]:
+                        if use_device_buffer:
+                            rb.mark_last_truncated(i)
+                        else:
+                            sub = rb.buffer[i]
+                            last_idx = (sub._pos - 1) % sub.buffer_size
+                            sub["terminated"][last_idx] = np.zeros_like(sub["terminated"][last_idx])
+                            sub["truncated"][last_idx] = np.ones_like(sub["truncated"][last_idx])
+                            sub["is_first"][last_idx] = np.zeros_like(sub["is_first"][last_idx])
+                        step_data["is_first"][0, i] = np.ones_like(step_data["is_first"][0, i])
 
-        if "final_info" in infos and "episode" in infos["final_info"]:
-            ep = infos["final_info"]["episode"]
-            mask = ep.get("_r", infos["final_info"].get("_episode"))
-            if mask is not None and np.any(mask):
-                for r, l in zip(ep["r"][mask], ep["l"][mask]):
-                    aggregator.update("Rewards/rew_avg", float(r))
-                    aggregator.update("Game/ep_len_avg", float(l))
+            if "final_info" in infos and "episode" in infos["final_info"]:
+                ep = infos["final_info"]["episode"]
+                mask = ep.get("_r", infos["final_info"].get("_episode"))
+                if mask is not None and np.any(mask):
+                    for r, l in zip(ep["r"][mask], ep["l"][mask]):
+                        aggregator.update("Rewards/rew_avg", float(r))
+                        aggregator.update("Game/ep_len_avg", float(l))
 
-        real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
-        if "final_obs" in infos:
-            for idx, final_obs in enumerate(infos["final_obs"]):
-                if final_obs is not None:
-                    for k in obs_keys:
-                        real_next_obs[k][idx] = np.asarray(final_obs[k])
+            real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
+            if "final_obs" in infos:
+                for idx, final_obs in enumerate(infos["final_obs"]):
+                    if final_obs is not None:
+                        for k in obs_keys:
+                            real_next_obs[k][idx] = np.asarray(final_obs[k])
 
-        step_data.update(
-            step_slab(
-                num_envs,
-                {
-                    **{k: next_obs[k] for k in obs_keys},
-                    "terminated": terminated,
-                    "truncated": truncated,
-                    "rewards": rewards,
-                },
-                dtypes={"terminated": np.float32, "truncated": np.float32, "rewards": np.float32},
-            )
-        )
-        obs = next_obs
-        if cfg.env.clip_rewards:
-            step_data["rewards"] = np.tanh(step_data["rewards"])
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        if dones_idxes:
-            reset_data = {}
-            for k in obs_keys:
-                reset_data[k] = real_next_obs[k][dones_idxes][np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, len(dones_idxes), int(sum(actions_dim))), np.float32)
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            if store_rssm_state:
-                # episode-end bookkeeping rows carry no player state (the env
-                # just reset); valid=0 keeps chunk starts off them
-                reset_data.update(
-                    rssm_state_slab(
-                        len(dones_idxes),
-                        rssm_zero_recurrent[: len(dones_idxes)],
-                        rssm_zero_stochastic[: len(dones_idxes)],
-                        valid=False,
-                    )
+            step_data.update(
+                step_slab(
+                    num_envs,
+                    {
+                        **{k: next_obs[k] for k in obs_keys},
+                        "terminated": terminated,
+                        "truncated": truncated,
+                        "rewards": rewards,
+                    },
+                    dtypes={"terminated": np.float32, "truncated": np.float32, "rewards": np.float32},
                 )
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-
-            step_data["rewards"][:, dones_idxes] = 0
-            step_data["terminated"][:, dones_idxes] = 0
-            step_data["truncated"][:, dones_idxes] = 0
-            step_data["is_first"][:, dones_idxes] = 1
-            reset_mask = np.zeros((num_envs, 1), np.float32)
-            reset_mask[dones_idxes] = 1.0
-            player.init_states(params["world_model"], reset_mask)
-
-        # ---- log (reference dreamer_v3.py:747-793) ------------------------
-        if policy_step_count - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run:
-            # the sentinel sees the raw per-gradient-step rows before the
-            # aggregator's NaN filtering drops them (warn/halt policies; the
-            # skip_update selection already happened in-graph)
-            metrics_drain.flush_into(
-                aggregator,
-                metric_order,
-                observer=lambda rows: diag.observe_rows(policy_step_count, metric_order, rows),
-                extra_observer=lambda extras: diag.on_health(
-                    policy_step_count, mean_stats(extras)
-                ),
             )
-            metrics_dict = aggregator.compute()
-            timers = timer.compute()
-            if timers.get("Time/train_time", 0) > 0:
-                metrics_dict["Time/sps_train"] = (train_step_count - last_train) / timers["Time/train_time"]
-            if timers.get("Time/env_interaction_time", 0) > 0:
-                metrics_dict["Time/sps_env_interaction"] = (
-                    (policy_step_count - last_log) * cfg.env.action_repeat
-                ) / timers["Time/env_interaction_time"]
-            if policy_step_count > 0:
-                metrics_dict["Params/replay_ratio"] = cumulative_grad_steps / policy_step_count
-            if runtime.is_global_zero:
-                logger.log_metrics(metrics_dict, policy_step_count)
-            aggregator.reset()
-            timer.reset()
-            last_log = policy_step_count
-            last_train = train_step_count
+            obs = next_obs
+            if cfg.env.clip_rewards:
+                step_data["rewards"] = np.tanh(step_data["rewards"])
+
+            dones_idxes = dones.nonzero()[0].tolist()
+            if dones_idxes:
+                reset_data = {}
+                for k in obs_keys:
+                    reset_data[k] = real_next_obs[k][dones_idxes][np.newaxis]
+                reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
+                reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
+                reset_data["actions"] = np.zeros((1, len(dones_idxes), int(sum(actions_dim))), np.float32)
+                reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
+                reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
+                if store_rssm_state:
+                    # episode-end bookkeeping rows carry no player state (the env
+                    # just reset); valid=0 keeps chunk starts off them
+                    reset_data.update(
+                        rssm_state_slab(
+                            len(dones_idxes),
+                            rssm_zero_recurrent[: len(dones_idxes)],
+                            rssm_zero_stochastic[: len(dones_idxes)],
+                            valid=False,
+                        )
+                    )
+                rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+
+                step_data["rewards"][:, dones_idxes] = 0
+                step_data["terminated"][:, dones_idxes] = 0
+                step_data["truncated"][:, dones_idxes] = 0
+                step_data["is_first"][:, dones_idxes] = 1
+                reset_mask = np.zeros((num_envs, 1), np.float32)
+                reset_mask[dones_idxes] = 1.0
+                player.init_states(params["world_model"], reset_mask)
+
+            # ---- log (reference dreamer_v3.py:747-793) ------------------------
+            if policy_step_count - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run:
+                # the sentinel sees the raw per-gradient-step rows before the
+                # aggregator's NaN filtering drops them (warn/halt policies; the
+                # skip_update selection already happened in-graph)
+                metrics_drain.flush_into(
+                    aggregator,
+                    metric_order,
+                    observer=lambda rows: diag.observe_rows(policy_step_count, metric_order, rows),
+                    extra_observer=lambda extras: diag.on_health(
+                        policy_step_count, mean_stats(extras)
+                    ),
+                )
+                metrics_dict = aggregator.compute()
+                timers = timer.compute()
+                if timers.get("Time/train_time", 0) > 0:
+                    metrics_dict["Time/sps_train"] = (train_step_count - last_train) / timers["Time/train_time"]
+                if timers.get("Time/env_interaction_time", 0) > 0:
+                    metrics_dict["Time/sps_env_interaction"] = (
+                        (policy_step_count - last_log) * cfg.env.action_repeat
+                    ) / timers["Time/env_interaction_time"]
+                if policy_step_count > 0:
+                    metrics_dict["Params/replay_ratio"] = cumulative_grad_steps / policy_step_count
+                if runtime.is_global_zero:
+                    logger.log_metrics(metrics_dict, policy_step_count)
+                aggregator.reset()
+                timer.reset()
+                last_log = policy_step_count
+                last_train = train_step_count
 
         # ---- checkpoint (reference dreamer_v3.py:795-826) -----------------
         # a pending preemption (signal or drill) forces the branch: the save

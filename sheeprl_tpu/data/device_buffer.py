@@ -38,8 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# The two jitted functions' names are their executables' names in a profile
+# (``jit_replay_add`` / ``jit_replay_gather`` on the ``XLA Modules`` line):
+# a reduction finds them by name, so a rename is a change to what is measured.
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter_all(buf: Dict[str, jax.Array], step: Dict[str, jax.Array], rows: jax.Array, envs: jax.Array) -> Dict[str, jax.Array]:
+def replay_add(buf: Dict[str, jax.Array], step: Dict[str, jax.Array], rows: jax.Array, envs: jax.Array) -> Dict[str, jax.Array]:
     """Whole-dict ring write in ONE dispatched program: ``step[k]`` is
     ``[n_sel, ...]`` written at ``(rows[i], envs[i])`` of ``buf[k]``.  One
     device call per policy step instead of one per key — each dispatch is
@@ -52,7 +55,7 @@ def _scatter_all(buf: Dict[str, jax.Array], step: Dict[str, jax.Array], rows: ja
 
 
 @partial(jax.jit, static_argnums=(3,))
-def _gather_all(buf: Dict[str, jax.Array], starts: jax.Array, env_idx: jax.Array, seq_len: int) -> Dict[str, jax.Array]:
+def replay_gather(buf: Dict[str, jax.Array], starts: jax.Array, env_idx: jax.Array, seq_len: int) -> Dict[str, jax.Array]:
     """Whole-dict sequence gather in ONE dispatched program:
     ``[cap, n_envs, ...] -> [seq_len, B, ...]`` per key; window ``b`` is rows
     ``(starts[b] + t) % cap`` of env ``env_idx[b]``."""
@@ -75,7 +78,7 @@ def _make_sharded_gather(mesh, seq_len: int):
     from sheeprl_tpu.parallel.dp import dp_jit
 
     def local_gather(storage, starts, env_local):
-        return _gather_all(storage, starts, env_local, seq_len)
+        return replay_gather(storage, starts, env_local, seq_len)
 
     return dp_jit(
         local_gather,
@@ -217,7 +220,7 @@ class DeviceSequentialReplayBuffer:
         # (see dreamer_v3.py's pipelined iteration).  Host leaves ride along
         # as KB-sized transfer operands of the same single dispatch.
         step = {k: v[0] for k, v in data.items()}
-        self._buf = _scatter_all(self._buf, step, rows, envs_dev)
+        self._buf = replay_add(self._buf, step, rows, envs_dev)
         self._pos[envs] = (self._pos[envs] + 1) % self._buffer_size
         self._filled[envs] = np.minimum(self._filled[envs] + 1, self._buffer_size)
         self._added[envs] += 1
@@ -307,7 +310,7 @@ class DeviceSequentialReplayBuffer:
                 out.append(gather(self._buf, starts_dev, env_local))
             else:
                 out.append(
-                    _gather_all(
+                    replay_gather(
                         self._buf,
                         jnp.asarray(starts, jnp.int32),
                         jnp.asarray(env_idx, jnp.int32),

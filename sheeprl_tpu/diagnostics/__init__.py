@@ -77,7 +77,7 @@ from sheeprl_tpu.diagnostics.sentinel import (
     sentinel_spec,
 )
 from sheeprl_tpu.diagnostics.telemetry import TELEMETRY_PREFIX, Telemetry, monitoring_available
-from sheeprl_tpu.diagnostics.tracing import TRACE_NAME, NullTracer, PhaseTracer
+from sheeprl_tpu.diagnostics.tracing import TRACE_NAME, NullTracer, PhaseTracer, profiler_annotation
 
 __all__ = [
     "Diagnostics",
@@ -448,8 +448,10 @@ class Diagnostics:
     # -- tracing + phase accounting ----------------------------------------
     def span(self, name: str, **args: Any):
         """Phase span context manager: feeds the telemetry phase-attribution
-        accumulator, the run-state machine and (when tracing is open) the
-        Chrome trace."""
+        accumulator, the run-state machine, the ``jax.profiler`` session if
+        one is running (``sheeprl/<name>`` on its host plane) and (when
+        tracing is open) the Chrome trace.  A slash name is a part of the
+        phase before the slash (``tracing.KNOWN_PHASES``)."""
         tracing = not isinstance(self.tracer, NullTracer)
         # `_opened` (not just `is not None`): goodput is rank-0 only, and
         # telemetry-off workers must not pay a generator per span for a no-op
@@ -464,11 +466,12 @@ class Diagnostics:
             goodput.note_span(name)
         token = self.telemetry.span_enter(name) if self.telemetry is not None else None
         try:
-            if tracing:
-                with self.tracer.span(name, **args):
+            with profiler_annotation(name, **args):
+                if tracing:
+                    with self.tracer.span(name, **args):
+                        yield
+                else:
                     yield
-            else:
-                yield
         finally:
             if token is not None:
                 self.telemetry.span_exit(token)

@@ -124,17 +124,24 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
     for key, value in sorted((snapshot.get("counters") or {}).items()):
         emit(key, "counter", value)
 
-    phase_seconds = snapshot.get("phase_seconds_total") or {}
-    if phase_seconds:
+    def emit_family(name: str, label: str, values: Mapping[str, Any], fmt: str = "g") -> None:
         # one TYPE line for the whole label family — a second TYPE line for
         # the same metric name is a Prometheus parse error
-        lines.append("# TYPE sheeprl_phase_seconds_total counter")
-        for phase, secs in sorted(phase_seconds.items()):
+        if not values:
+            return
+        lines.append(f"# TYPE {name} counter")
+        for key, value in sorted(values.items()):
             try:
-                num = float(secs)
+                num = float(value)
             except (TypeError, ValueError):
                 num = 0.0
-            lines.append(f'sheeprl_phase_seconds_total{{phase="{_escape_label(phase)}"}} {num:g}')
+            lines.append(f'{name}{{{label}="{_escape_label(key)}"}} {num:{fmt}}')
+
+    # a slash phase is a part of the phase before the slash, counted
+    # inclusive (tracing.KNOWN_PHASES); call counts are rendered exact
+    emit_family("sheeprl_phase_seconds_total", "phase", snapshot.get("phase_seconds_total") or {})
+    emit_family("sheeprl_phase_calls_total", "phase", snapshot.get("phase_calls_total") or {}, fmt=".0f")
+    emit_family("sheeprl_instrumented_calls_total", "fn", snapshot.get("calls_total") or {}, fmt=".0f")
 
     lag = snapshot.get("journal_lag_seconds")
     if lag is not None:

@@ -171,7 +171,7 @@ def _phase_summary(metrics: Dict[str, Any]) -> Optional[str]:
     }
     if not phases:
         return None
-    order = ("train", "env", "fetch", "other", "idle")
+    order = ("train", "env", "fetch", "other", "unspanned")
     keys = [k for k in order if k in phases] + sorted(set(phases) - set(order))
     return " ".join(f"{k}:{phases[k]:.0f}%" for k in keys)
 

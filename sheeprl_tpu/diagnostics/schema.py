@@ -100,7 +100,9 @@ METRICS: Dict[str, str] = {
     "sheeprl_up": "1 while the training process serves the endpoint",
     "sheeprl_run_info": "run identity as labels (value is always 1)",
     "sheeprl_policy_steps_total": "policy steps taken (env frames / action_repeat)",
-    "sheeprl_phase_seconds_total": "cumulative wall-clock per host phase (label: phase)",
+    "sheeprl_phase_seconds_total": "cumulative wall-clock per host phase (label: phase; self time, a slash part inclusive)",
+    "sheeprl_phase_calls_total": "cumulative spans closed per host phase or part (label: phase)",
+    "sheeprl_instrumented_calls_total": "cumulative dispatches through each instrumented jitted step (label: fn)",
     "sheeprl_journal_lag_seconds": "seconds since the last journal write",
     # telemetry counters (Telemetry.snapshot()["counters"])
     "sheeprl_recompiles_total": "watchdog: new dispatch signatures seen",
@@ -143,7 +145,7 @@ METRICS: Dict[str, str] = {
     "sheeprl_phase_pct_env": "interval wall-clock share: env stepping",
     "sheeprl_phase_pct_fetch": "interval wall-clock share: metric/buffer fetch",
     "sheeprl_phase_pct_other": "interval wall-clock share: other instrumented spans",
-    "sheeprl_phase_pct_idle": "interval wall-clock share: un-instrumented host time",
+    "sheeprl_phase_pct_unspanned": "interval wall-clock share: host time under no span (not device idleness)",
     # resilience gauges (checkpoint freshness; run_monitor --url keys its
     # !! NO-RECENT-CKPT banner off these)
     "sheeprl_ckpt_last_step": "policy step of the newest verified checkpoint written by this run",

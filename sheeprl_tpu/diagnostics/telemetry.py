@@ -48,7 +48,7 @@ inside the run itself, instead of from offline ``bench.py`` snapshots
   env_step_async / env_wait / buffer-sample / train / checkpoint) feed a
   nesting-aware self-time accumulator (a child span's time is subtracted from
   its parent), so each interval also reports where the wall-clock went:
-  ``Telemetry/phase_pct/{train,env,fetch,other,idle}``.
+  ``Telemetry/phase_pct/{train,env,fetch,other,unspanned}``.
 
 Emission rides the rank-0 logger proxy: ``JournalingLogger`` asks the facade
 to augment each aggregated-metrics interval with the ``Telemetry/*`` gauges
@@ -64,6 +64,8 @@ import time
 import warnings
 from collections import deque
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
+
+from sheeprl_tpu.diagnostics.tracing import is_part, profiler_annotation
 
 TELEMETRY_PREFIX = "Telemetry/"
 
@@ -273,7 +275,10 @@ class _Instrumented:
         if self._use_aot:
             compiled = self._compiled.get(sig)
             if compiled is None:
-                compiled = self._aot_compile(sig, args, kwargs)
+                # a compile (or a cache load) inside a profiled window is
+                # named on the profile's host plane
+                with profiler_annotation(f"compile/{self.name}"):
+                    compiled = self._aot_compile(sig, args, kwargs)
             if compiled is not None:
                 self._signature = sig
                 try:
@@ -640,9 +645,12 @@ class Telemetry:
         self._journal_fn: Optional[Callable[..., None]] = None
         self._span_stack = threading.local()
 
-        # phase self-times (seconds): cumulative + current interval
+        # phase self-times (seconds): cumulative + current interval; a part
+        # (slash name, tracing.KNOWN_PHASES) is counted inclusive, under its
+        # full name, in the cumulative totals only
         self._phase_total: Dict[str, float] = {}
         self._phase_interval: Dict[str, float] = {}
+        self._phase_calls_total: Dict[str, int] = {}
         # instrumented-call accounting
         self._instrumented: Dict[str, _Instrumented] = {}
         self._calls_total: Dict[str, int] = {}
@@ -838,32 +846,39 @@ class Telemetry:
         return _span()
 
     def span_enter(self, name: str) -> List:
+        rec = [name, self._clock(), 0.0]  # [name, t0, child seconds]
+        if is_part(name):  # off the self-time stack: its phase reads as without it
+            return rec
         stack = getattr(self._span_stack, "stack", None)
         if stack is None:
             stack = self._span_stack.stack = []
-        rec = [name, self._clock(), 0.0]  # [name, t0, child seconds]
         stack.append(rec)
         return rec
 
     def span_exit(self, rec: List) -> None:
-        stack = getattr(self._span_stack, "stack", None)
-        dur = self._clock() - rec[1]
-        if stack and stack[-1] is rec:
-            stack.pop()
-        if stack:
-            stack[-1][2] += dur
-        self_time = max(0.0, dur - rec[2])
+        name = rec[0]
+        seconds = self._clock() - rec[1]
+        part = is_part(name)  # inclusive, and not in the interval's buckets
+        if not part:
+            stack = getattr(self._span_stack, "stack", None)
+            if stack and stack[-1] is rec:
+                stack.pop()
+            if stack:
+                stack[-1][2] += seconds
+            seconds = max(0.0, seconds - rec[2])  # self time
         with self._lock:
-            name = rec[0]
-            self._phase_total[name] = self._phase_total.get(name, 0.0) + self_time
-            self._phase_interval[name] = self._phase_interval.get(name, 0.0) + self_time
+            self._phase_total[name] = self._phase_total.get(name, 0.0) + seconds
+            self._phase_calls_total[name] = self._phase_calls_total.get(name, 0) + 1
+            if not part:
+                self._phase_interval[name] = self._phase_interval.get(name, 0.0) + seconds
 
     # -- interval math -----------------------------------------------------
     # The phase -> bucket map behind Telemetry/phase_pct/*: `env` is host
     # work spent driving the envs/policy (rollout bookkeeping + async issue),
     # `fetch` is blocking waits on env results and batch staging, `train` is
     # the train-step dispatch+fetch, everything else (checkpoint, custom
-    # spans) lands in `other`, and `idle` is wall-clock no span accounted for.
+    # spans) lands in `other`, and `unspanned` is host wall-clock under no
+    # span (not device idleness: a compute-bound loop reads 98% here).
     _PHASE_BUCKETS = {
         "rollout": "env",
         "env_step_async": "env",
@@ -905,7 +920,7 @@ class Telemetry:
                         bucket = self._PHASE_BUCKETS.get(name, "other")
                         buckets[bucket] = buckets.get(bucket, 0.0) + secs
                     accounted = sum(buckets.values())
-                    buckets["idle"] = max(0.0, dt - accounted)
+                    buckets["unspanned"] = max(0.0, dt - accounted)
                     for bucket, secs in sorted(buckets.items()):
                         out[TELEMETRY_PREFIX + f"phase_pct/{bucket}"] = 100.0 * secs / dt
             if self._dataset_epoch is not None:
@@ -944,6 +959,7 @@ class Telemetry:
                 },
                 "policy_steps": self._tick_step,
                 "phase_seconds_total": dict(self._phase_total),
+                "phase_calls_total": dict(self._phase_calls_total),
                 "calls_total": dict(self._calls_total),
                 "flops_per_call": {
                     name: inst.flops_per_call

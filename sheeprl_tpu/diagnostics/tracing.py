@@ -53,10 +53,23 @@ TRACE_SERVE_NAME = "trace_serve.json"
 # inside) AOT dispatch → result scatter → response serialization, plus the
 # request-log writer thread's shard flush.  tools/lint TRC501 pins every
 # span-name literal in serving/ and the loops to this tuple.
+#
+# A name with a slash is a *part* of the phase before the slash
+# (``rollout/action-fetch`` is the blocking value fetch inside ``rollout``).
+# A part is annotated and counted under its full name, inclusive seconds and
+# calls, and takes no part in the self-time stack: the phase around it, and
+# every other phase, reads what it would read without the part, and parts are
+# left out of the ``Telemetry/phase_pct/*`` buckets.  For the run-state
+# machine a part is progress and maps to no state.
 KNOWN_PHASES = (
     "rollout",
+    "rollout/obs-stage",
+    "rollout/player-forward",
+    "rollout/replay-add",
+    "rollout/action-fetch",
     "env_step_async",
     "env_wait",
+    "bookkeeping",
     "buffer-sample",
     "train",
     "checkpoint",
@@ -68,6 +81,25 @@ KNOWN_PHASES = (
     "serve-serialize",
     "serve-request-log",
 )
+
+# Every facade span is also a ``jax.profiler.TraceAnnotation`` under this
+# prefix: inside a profiler session (``metric.profiler``, the ``/profile``
+# endpoint, a benchmark's own trace) the loop's phases lie on the host plane
+# of the same ``.xplane.pb`` as the device's ``XLA Ops``, on one clock.
+PROFILER_PREFIX = "sheeprl/"
+
+
+def is_part(name: str) -> bool:
+    """True for a slash name: a part of the phase before the slash."""
+    return "/" in name
+
+
+def profiler_annotation(name: str, **args: Any):
+    """The span ``name`` on the profiler's clock.  With no profiler session
+    entering it is a flag test."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(PROFILER_PREFIX + name, **args)
 
 
 class PhaseTracer:

@@ -21,7 +21,23 @@ its first compile.  The rule:
    hit; CPU compiles are what the tests and dry runs pay, not what a chip
    run waits for.
 
-This is JAX's own cache (keyed on the HLO, so a source edit misses).  The AOT
+This is JAX's own cache (keyed on the HLO, so a source edit misses).  Wherever
+it lies, a process that may use an accelerator keys it on the HLO *with* its
+metadata (``jax_compilation_cache_include_metadata_in_key``): JAX leaves the
+``op_name`` and source line of every operation out of the key by default, so
+an executable loaded from the cache carries the names of whoever compiled it
+first, and a ``jax.named_scope`` added since does not show in a profile (seen
+on the v5e, PERF.md §6 PR 27: the train step of a checkout with scopes ran
+with the scopeless names of the checkout before it).  The locations then hold
+one frame, the operation's own source line (``jax_traceback_in_locations_limit``
+1): with the ten frames JAX writes by default the key would hold the entry
+script and whatever wraps the loop, and two entry points of one checkout
+would share no executable (seen too: the benchmark's command and a script
+around the same harness each compiled everything).  Not
+``jax_include_full_tracebacks_in_locations=False``, which in this JAX also
+drops the scope path from every ``op_name`` (seen on the v5e as well).  The
+price is a compile where an edit only moved the lines of traced code, and a
+profile's ``source_stack`` of one frame.  The AOT
 *executable* cache (``diagnostics/telemetry.py``) skips lowering and stays
 opt-in through ``diagnostics.compilation_cache_dir`` alone.
 """
@@ -51,9 +67,15 @@ def enable_compile_cache(configured: Optional[str] = None) -> Optional[str]:
     import jax
 
     target = compile_cache_dir_to_set(configured)
+    cpu_only = jax.config.jax_platforms == "cpu"
+    if not cpu_only:
+        # a profile of the device must show this checkout's names, not a cached
+        # build's; the key then holds each operation's own line, not its callers
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        jax.config.update("jax_traceback_in_locations_limit", 1)
     if target is None:
         return os.environ[ENV_VAR]
-    if not configured and jax.config.jax_platforms == "cpu":
+    if not configured and cpu_only:
         return None
     os.makedirs(target, exist_ok=True)
     if jax.config.jax_compilation_cache_dir != target:

@@ -189,7 +189,7 @@ def test_interval_math_mfu_sps_and_phase_breakdown():
     assert out[TELEMETRY_PREFIX + "phase_pct/train"] == pytest.approx(30.0)
     # buffer-sample + env_wait both land in the `fetch` bucket
     assert out[TELEMETRY_PREFIX + "phase_pct/fetch"] == pytest.approx(30.0)
-    assert out[TELEMETRY_PREFIX + "phase_pct/idle"] == pytest.approx(40.0)
+    assert out[TELEMETRY_PREFIX + "phase_pct/unspanned"] == pytest.approx(40.0)
     # interval accumulators reset: an empty follow-up interval has no rates
     clock.t += 1.0
     again = tele.interval_metrics(200)
@@ -438,7 +438,7 @@ def test_cli_ppo_journals_telemetry_serves_metrics_and_catches_recompile(run_cli
     assert last["Telemetry/tflops_per_sec"] > 0
     assert last["Telemetry/sps"] > 0  # needs a previous interval as baseline
     phase_keys = [k for k in last if k.startswith("Telemetry/phase_pct/")]
-    assert {"Telemetry/phase_pct/train", "Telemetry/phase_pct/idle"} <= set(phase_keys)
+    assert {"Telemetry/phase_pct/train", "Telemetry/phase_pct/unspanned"} <= set(phase_keys)
     shares = sum(last[k] for k in phase_keys)
     assert shares == pytest.approx(100.0, abs=1.0)
 

@@ -32,6 +32,17 @@ def test_environment_variable_wins_and_nothing_is_set(monkeypatch, recorded_upda
     assert compile_cache.compile_cache_dir_to_set("configured/dir") is None
     assert compile_cache.enable_compile_cache("configured/dir") == "/somewhere/else"
     assert recorded_updates == []
+    # a process that may use an accelerator keys the cache on the metadata too
+    # (a profile must show this checkout's scopes), and still places nothing
+    import jax
+
+    with monkeypatch.context() as m:
+        m.setattr(type(jax.config), "jax_platforms", "tpu,cpu", raising=False)
+        assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert recorded_updates == [
+        ("jax_compilation_cache_include_metadata_in_key", True),
+        ("jax_traceback_in_locations_limit", 1),
+    ]
 
 
 def test_default_is_one_absolute_in_checkout_path_from_any_cwd(monkeypatch, tmp_path):
