@@ -7,20 +7,48 @@ sample).  On TPU that transfer is the end-to-end bottleneck: a DV3-S batch
 data is only ~12 KB per policy step.  This buffer therefore keeps the whole
 replay ring in HBM:
 
-- ``add`` scatters one policy step into the ring in place (jitted, donated)
+- ``add`` writes one policy step into the ring in place (jitted, donated)
   — the only host->device traffic is the newest frame;
 - per-env write heads: envs advance independently (episode-end rows are
   appended only to done envs), replacing the host path's one-sub-buffer-per-
   env ``EnvIndependentReplayBuffer`` + ``SequentialReplayBuffer`` pair;
 - ``sample`` draws sequence windows with the host ``SequentialReplayBuffer``'s
   age-space semantics (windows never span an env's write head; starts uniform
-  over each env's valid range) but the gather runs on device and the returned
+  over each env's valid range) but the read runs on device and the returned
   ``[T, B, ...]`` batch never touches the host.  Env choice is uniform on a
   single device; in multi-device mode it is *block-stratified* — each device's
-  batch block draws only from its own env shard (see ``_draw_env_idx``);
-- capacity math: DV3 Atari-100K (1e5 steps x 64x64x3 uint8) is ~1.2 GB — it
-  fits v5e HBM next to the S model.  For bigger buffers keep the host path
-  (``buffer.device=False``).
+  batch block draws only from its own env shard (see ``_draw_env_idx``).
+
+Storage form.  Every key is held as ``[cap, n_envs, width]``, ``width`` the
+product of its trailing dims (``rgb`` ``[cap, n, 3, 64, 64]`` is held as
+``[cap, n, 12288]``); the logical trailing shape is put back on the
+``[T, B, ...]`` batch inside the sampling executable, and ``state_dict`` /
+``load_state_dict`` / ``footprint`` speak logical shapes and the same bytes.
+Why: the TPU runtime picks an array's device layout from its shape, and for
+``u8[cap, n, 3, 64, 64]`` it puts the *ring* axis in the lanes (64x64 in the
+lanes would pad twofold).  XLA's gather wants the indexed axis major, so it
+first re-laid the whole ring (PERF.md section 6, PR 28: 38.85 of a 62.5 ms
+iteration at 250,000 rows, a 6.1 GB temporary, and no ring over ~400,000
+rows).  The read now follows what the layout allows, by the key's width:
+
+- a width that is a multiple of the 128-lane tile (frames 3x64x64, the RSSM
+  slabs) gets a row-major layout: row ``(t, e)`` is contiguous, and a gather
+  of the ``T x B`` rows reads exactly them;
+- any other width (scalars, actions, small vectors, 84x84 frames) gets the
+  ring axis in the lanes: a sample is read as ``B`` windows of ``T``
+  consecutive ring rows (``dynamic_slice`` along the axis the layout has
+  minor), the wrap at the ring's end mended inside the executable.
+
+The write is one ``dynamic_update_slice`` per written env in either layout.
+The invariant, held from the compiler by ``tests/test_data/
+test_device_buffer.py``: neither executable holds an operand, a result or a
+temporary that grows with ``buffer_size`` other than the ring itself — the
+cost of a sample is a function of ``batch x sequence_length x row bytes``.
+
+Capacity: the ring is what it stores and nothing more (a 1,000,000-row
+64x64x3 uint8 ring is 12.3 GB of a v5e's 16 GB; DV3 Atari-100K is 1.2 GB).
+For a ring that does not fit beside the model keep the host path
+(``buffer.device=False``).
 
 Head bookkeeping (per-env ``pos``/``full``) stays on the host: it's a few
 ints per policy step, and host-side index math keeps sampling logic in cheap
@@ -29,13 +57,73 @@ numpy while every array byte stays in HBM.
 
 from __future__ import annotations
 
+import math
 import warnings
 from functools import partial
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+# the minor tile of a TPU layout: a key whose width is a multiple of it is
+# laid out row-major, any other with the ring axis in the lanes
+_LANES = 128
+# windows read by one trip of their loop.  Up to here a batch (DV3 16, DV1/DV2
+# 50, per device) is spelled out whole, which is the form the compiler leaves
+# every layout alone for; a larger one becomes a ``while`` whose ring operand
+# XLA may re-lay (seen for widths of 64 and over)
+_UNROLL = 64
+
+Shapes = Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+
+def _write_rows(ring: jax.Array, new: jax.Array, rows: jax.Array, envs: jax.Array, mine: Optional[jax.Array] = None) -> jax.Array:
+    """``new[i]`` into ``ring[rows[i], envs[i]]``, one ``dynamic_update_slice``
+    each: in place under donation whatever the ring's layout (a scatter
+    re-lays a narrow key whole at ``n_envs`` > 1).  Where ``mine[i]`` is false
+    the row is written back as it was found; such rows come first in the
+    order, so that none undoes a write that counts."""
+    n = new.shape[0]
+    new = list(new.reshape(n, 1, 1, *ring.shape[2:]).astype(ring.dtype))
+    at = [(rows[i], envs[i], *(0,) * (ring.ndim - 2)) for i in range(n)]
+    if mine is not None:
+        # all read before the first write: read and written in turn, XLA
+        # fuses each pair and re-lays the ring for the fusion
+        found = [lax.dynamic_slice(ring, at[i], new[i].shape) for i in range(n)]
+        new = [jnp.where(mine[i], new[i], found[i]) for i in range(n)]
+    for i in range(n):
+        ring = lax.dynamic_update_slice(ring, new[i], at[i])
+    return ring
+
+
+def _read_rows(ring: jax.Array, starts: jax.Array, env_idx: jax.Array, seq_len: int) -> jax.Array:
+    """``[T, B, ...]`` by a gather of rows ``(starts[b] + t) % cap``: for a
+    ring whose rows are contiguous."""
+    rows = (starts[None, :] + jnp.arange(seq_len)[:, None]) % ring.shape[0]
+    return ring[rows, env_idx[None, :]]
+
+
+def _read_windows(ring: jax.Array, starts: jax.Array, env_idx: jax.Array, seq_len: int) -> jax.Array:
+    """``[T, B, ...]`` as ``B`` slices of ``T`` consecutive ring rows: for a
+    ring whose layout has the ring axis minor.  A window that runs past the
+    ring's end is cut from its last ``T`` rows followed by its first ``T``.
+    The slices are taken one by one (a loop, unrolled up to ``_UNROLL``): a
+    batched ``dynamic_slice`` is a gather to XLA, which re-lays the ring for
+    it at ``n_envs`` > 1."""
+    cap, trailing = ring.shape[0], ring.shape[2:]
+    size, zeros = (seq_len, 1, *trailing), (0,) * len(trailing)
+    first = jnp.minimum(starts, cap - seq_len)  # of the last T rows at most
+
+    def window(_, at):
+        row, shift, env = at
+        tail = lax.dynamic_slice(ring, (row, env, *zeros), size)
+        head = lax.dynamic_slice(ring, (0, env, *zeros), size)
+        return None, lax.dynamic_slice(jnp.concatenate([tail, head]), (shift, 0, *zeros), size)[:, 0]
+
+    _, windows = lax.scan(window, None, (first, starts - first, env_idx), unroll=_UNROLL)
+    return jnp.swapaxes(windows, 0, 1)
 
 
 # The two jitted functions' names are their executables' names in a profile
@@ -46,26 +134,29 @@ def replay_add(buf: Dict[str, jax.Array], step: Dict[str, jax.Array], rows: jax.
     """Whole-dict ring write in ONE dispatched program: ``step[k]`` is
     ``[n_sel, ...]`` written at ``(rows[i], envs[i])`` of ``buf[k]``.  One
     device call per policy step instead of one per key — each dispatch is
-    host work on the hot thread, and at 7 buffer keys that is 7 of them per
-    step (per-dispatch cost on an attached host: not measured; the chip
-    benchmark should re-decide whether the fusion still pays).  Works for
-    sharded storage too: the updates are tiny and the SPMD partitioner
-    applies each to the owning shard."""
-    return {k: buf[k].at[rows, envs].set(step[k]) for k in buf}
+    host work on the hot thread (``loop.replay_add_host_ms``, PERF.md)."""
+    return {k: _write_rows(buf[k], step[k], rows, envs) for k in buf}
 
 
-@partial(jax.jit, static_argnums=(3,))
-def replay_gather(buf: Dict[str, jax.Array], starts: jax.Array, env_idx: jax.Array, seq_len: int) -> Dict[str, jax.Array]:
-    """Whole-dict sequence gather in ONE dispatched program:
+@partial(jax.jit, static_argnums=(3, 4))
+def replay_gather(
+    buf: Dict[str, jax.Array], starts: jax.Array, env_idx: jax.Array, seq_len: int, shapes: Optional[Shapes] = None
+) -> Dict[str, jax.Array]:
+    """Whole-dict sequence read in ONE dispatched program:
     ``[cap, n_envs, ...] -> [seq_len, B, ...]`` per key; window ``b`` is rows
-    ``(starts[b] + t) % cap`` of env ``env_idx[b]``."""
-    cap = next(iter(buf.values())).shape[0]
-    rows = (starts[None, :] + jnp.arange(seq_len)[:, None]) % cap  # [T, B]
-    return {k: v[rows, env_idx[None, :]] for k, v in buf.items()}
+    ``(starts[b] + t) % cap`` of env ``env_idx[b]``.  ``shapes`` names the
+    logical trailing shape of each key held flat (none: as stored)."""
+    out = {}
+    for k, ring in buf.items():
+        read = _read_rows if math.prod(ring.shape[2:]) % _LANES == 0 else _read_windows
+        out[k] = read(ring, starts, env_idx, seq_len)
+    for k, trailing in shapes or ():
+        out[k] = out[k].reshape(*out[k].shape[:2], *trailing)
+    return out
 
 
-def _make_sharded_gather(mesh, seq_len: int):
-    """Per-device local gather over an env-sharded ring (multi-device mode).
+def _make_sharded_gather(mesh, seq_len: int, shapes: Shapes):
+    """Per-device local read over an env-sharded ring (multi-device mode).
 
     Inside ``shard_map`` every device sees only its env block; ``env_idx`` is
     drawn block-stratified on the host so each device's indices are local.
@@ -78,13 +169,43 @@ def _make_sharded_gather(mesh, seq_len: int):
     from sheeprl_tpu.parallel.dp import dp_jit
 
     def local_gather(storage, starts, env_local):
-        return replay_gather(storage, starts, env_local, seq_len)
+        return replay_gather(storage, starts, env_local, seq_len, shapes)
 
     return dp_jit(
         local_gather,
         mesh,
         in_specs=(P(None, "data"), P("data"), P("data")),
         out_specs=P(None, "data"),
+    )
+
+
+def _make_sharded_add(mesh):
+    """The ring write over an env-sharded ring: every device gets the whole
+    (KB-sized) step and writes the rows of its own env block, so each local
+    program is the single-device one (left to the SPMD partitioner, a narrow
+    key is re-laid whole on the way in and out)."""
+    from jax.sharding import PartitionSpec as P
+
+    from sheeprl_tpu.parallel.dp import dp_axis, dp_jit
+
+    axis = dp_axis(mesh)
+    if axis is None:  # a mesh with a model axis runs global-view programs
+        return replay_add
+
+    def local_add(storage, step, rows, envs):
+        n_local = next(iter(storage.values())).shape[1]
+        local = envs - lax.axis_index(axis) * n_local
+        mine = (local >= 0) & (local < n_local)
+        order = jnp.argsort(mine)  # another block's envs first: _write_rows
+        rows, local, mine = rows[order], jnp.clip(local, 0, n_local - 1)[order], mine[order]
+        return {k: _write_rows(storage[k], step[k][order], rows, local, mine) for k in storage}
+
+    return dp_jit(
+        local_add,
+        mesh,
+        in_specs=(P(None, "data"), P(), P(), P()),
+        out_specs=P(None, "data"),
+        donate_argnums=(0,),
     )
 
 
@@ -113,7 +234,8 @@ class DeviceSequentialReplayBuffer:
         self._buffer_size = int(buffer_size)
         self._n_envs = int(n_envs)
         self._obs_keys = tuple(obs_keys)
-        self._buf: Dict[str, jax.Array] = {}
+        self._buf: Dict[str, jax.Array] = {}  # [cap, n_envs, width] per key
+        self._shapes: Shapes = ()  # each key's logical trailing shape, as replay_gather takes it
         self._pos = np.zeros(self._n_envs, dtype=np.int64)
         self._filled = np.zeros(self._n_envs, dtype=np.int64)  # rows ever written, capped at size
         self._added = np.zeros(self._n_envs, dtype=np.int64)  # monotone (dataset-export cursor)
@@ -128,6 +250,7 @@ class DeviceSequentialReplayBuffer:
                 f"n_envs ({self._n_envs}) must be divisible by the mesh size ({self._world}) "
                 "for the env-sharded device buffer"
             )
+        self._add = _make_sharded_add(self._mesh) if self._mesh else replay_add
         self._gather_cache: Dict[int, Any] = {}
 
     # -- properties mirrored from the host buffer ---------------------------
@@ -210,8 +333,9 @@ class DeviceSequentialReplayBuffer:
                     )
                     dtype = narrowed
                 self._buf[k] = self._to_storage(
-                    jnp.zeros((self._buffer_size, self._n_envs, *v.shape[2:]), dtype=dtype)
+                    jnp.zeros((self._buffer_size, self._n_envs, math.prod(v.shape[2:])), dtype=dtype)
                 )
+                self._shapes += ((k, tuple(v.shape[2:])),)
         rows = jnp.asarray(self._pos[envs] % self._buffer_size, jnp.int32)
         envs_dev = jnp.asarray(envs, jnp.int32)
         # device leaves (e.g. the player's actions) stay on device: the slice
@@ -220,7 +344,7 @@ class DeviceSequentialReplayBuffer:
         # (see dreamer_v3.py's pipelined iteration).  Host leaves ride along
         # as KB-sized transfer operands of the same single dispatch.
         step = {k: v[0] for k, v in data.items()}
-        self._buf = replay_add(self._buf, step, rows, envs_dev)
+        self._buf = self._add(self._buf, step, rows, envs_dev)
         self._pos[envs] = (self._pos[envs] + 1) % self._buffer_size
         self._filled[envs] = np.minimum(self._filled[envs] + 1, self._buffer_size)
         self._added[envs] += 1
@@ -293,7 +417,7 @@ class DeviceSequentialReplayBuffer:
         gather = None
         if self._mesh is not None:
             if sequence_length not in self._gather_cache:
-                self._gather_cache[sequence_length] = _make_sharded_gather(self._mesh, sequence_length)
+                self._gather_cache[sequence_length] = _make_sharded_gather(self._mesh, sequence_length, self._shapes)
             gather = self._gather_cache[sequence_length]
         out = []
         for _ in range(n_samples):
@@ -315,6 +439,7 @@ class DeviceSequentialReplayBuffer:
                         jnp.asarray(starts, jnp.int32),
                         jnp.asarray(env_idx, jnp.int32),
                         sequence_length,
+                        self._shapes,
                     )
                 )
         return out
@@ -334,7 +459,7 @@ class DeviceSequentialReplayBuffer:
         # np.asarray over a jax.Array is a read-only view; copy so checkpoint
         # surgery (truncated-flag patching) can write into the snapshot
         return {
-            "buffer": {k: np.array(v) for k, v in self._buf.items()},
+            "buffer": {k: np.array(self._buf[k]).reshape(*self._buf[k].shape[:2], *shape) for k, shape in self._shapes},
             "pos": self._pos.copy(),
             "filled": self._filled.copy(),
             "added": self._added.copy(),
@@ -349,6 +474,13 @@ class DeviceSequentialReplayBuffer:
             storage = jax.device_put(storage, NamedSharding(self._mesh, P(None, "data")))
         return storage
 
+    def _load_rings(self, rings: Dict[str, Any]) -> None:
+        """Logical ``[cap, n_envs, ...]`` arrays (a checkpoint's) into the
+        flat storage; the reshape is the host's, a view."""
+        self._shapes = tuple((k, tuple(np.shape(v)[2:])) for k, v in rings.items())
+        self._buf = {k: self._to_storage(np.asarray(v).reshape(*np.shape(v)[:2], -1)) for k, v in rings.items()}
+        self._gather_cache.clear()
+
     def load_state_dict(self, state: Dict[str, Any]) -> "DeviceSequentialReplayBuffer":
         if "buffers" in state:
             # host EnvIndependentReplayBuffer format (one sub-state per env):
@@ -356,10 +488,7 @@ class DeviceSequentialReplayBuffer:
             # checkpoints survive toggling buffer.device between runs
             subs = state["buffers"]
             keys = subs[0]["buffer"].keys()
-            self._buf = {
-                k: self._to_storage(np.concatenate([np.asarray(s["buffer"][k]) for s in subs], axis=1))
-                for k in keys
-            }
+            self._load_rings({k: np.concatenate([np.asarray(s["buffer"][k]) for s in subs], axis=1) for k in keys})
             self._pos = np.asarray([s["pos"] for s in subs], dtype=np.int64)
             self._filled = np.asarray(
                 [self._buffer_size if s["full"] else s["pos"] for s in subs], dtype=np.int64
@@ -369,7 +498,7 @@ class DeviceSequentialReplayBuffer:
                 dtype=np.int64,
             )
             return self
-        self._buf = {k: self._to_storage(v) for k, v in state["buffer"].items()}
+        self._load_rings(state["buffer"])
         self._pos = np.asarray(state["pos"], dtype=np.int64).copy()
         self._filled = np.asarray(state["filled"], dtype=np.int64).copy()
         # checkpoints predating the export subsystem: the stored window is

@@ -4,6 +4,9 @@ pair: per-env heads, windows never spanning a head, age-uniform starts."""
 
 from __future__ import annotations
 
+import functools
+import math
+
 import numpy as np
 import pytest
 
@@ -449,3 +452,335 @@ def test_pipelined_write_trace_parity_host_vs_device():
     rgb = np.asarray(dev_state["buffer"]["rgb"])[:n_rows, 0]
     flat = rgb.reshape(n_rows, -1)
     assert (flat == flat[:, :1]).all(), "obs rows are not step-constant"
+
+
+# ---------------------------------------------------------------------------
+# The storage form per key (PR 28): same bytes as a plain ring, logical shapes
+# in checkpoints, and, from the TPU compiler itself, nothing of capacity size
+# moved by a sample or an add.
+# ---------------------------------------------------------------------------
+# a key set with both read forms in it: widths that are multiples of the
+# 128-lane tile (gathered rows) and widths that are not (windows)
+_MIXED_KEYS = {
+    "rgb": ((2, 8, 8), np.uint8),  # 128 wide
+    "frame": ((1, 5, 5), np.uint8),  # 25 wide: an 84x84-like frame
+    "slab": ((512,), np.float32),
+    "state": ((7,), np.float32),
+    "actions": ((6,), np.float32),
+    "rewards": ((1,), np.float32),
+    "terminated": ((1,), np.float32),
+    "truncated": ((1,), np.float32),
+    "is_first": ((1,), np.float32),
+}
+
+
+def _mixed_step(rng, n_sel):
+    return {
+        k: rng.integers(0, 255, size=(1, n_sel, *shape)).astype(dtype)
+        if dtype == np.uint8
+        else rng.standard_normal((1, n_sel, *shape)).astype(dtype)
+        for k, (shape, dtype) in _MIXED_KEYS.items()
+    }
+
+
+class _PlainRing:
+    """The reference: numpy arrays in logical shapes, per-env heads, rows
+    indexed ``(start + t) % cap``."""
+
+    def __init__(self, cap, n_envs):
+        self.cap = cap
+        self.pos = np.zeros(n_envs, np.int64)
+        self.buf = {k: np.zeros((cap, n_envs, *shape), dtype) for k, (shape, dtype) in _MIXED_KEYS.items()}
+
+    def add(self, data, envs):
+        for i, e in enumerate(envs):
+            for k in self.buf:
+                self.buf[k][self.pos[e] % self.cap, e] = data[k][0, i]
+            self.pos[e] += 1
+
+    def read(self, starts, env_idx, seq_len):
+        rows = (starts[None, :] + np.arange(seq_len)[:, None]) % self.cap
+        return {k: v[rows, env_idx[None, :]] for k, v in self.buf.items()}
+
+
+def _filled_pair(cap, n_envs, steps, mesh=None, seed=0):
+    rng = np.random.default_rng(seed)
+    rb = DeviceSequentialReplayBuffer(cap, n_envs=n_envs, mesh=mesh)
+    ref = _PlainRing(cap, n_envs)
+    for t in range(steps):
+        # every third step goes to a subset of the envs, as episode ends do
+        envs = list(range(n_envs)) if t % 3 or n_envs == 1 else list(range(t % n_envs, n_envs, 2))
+        data = _mixed_step(rng, len(envs))
+        rb.add(data, indices=None if len(envs) == n_envs else envs)
+        ref.add(data, envs)
+    np.testing.assert_array_equal(rb._pos, ref.pos % cap)
+    return rb, ref
+
+
+def _sample_at(rb, monkeypatch, starts, env_idx, seq_len):
+    """``rb.sample`` with the draw replaced: the windows are the test's."""
+    starts, env_idx = np.asarray(starts, np.int64), np.asarray(env_idx, np.int64)
+    monkeypatch.setattr(rb, "_draw", lambda n, t: (starts, env_idx))
+    (batch,) = rb.sample(len(starts), sequence_length=seq_len)
+    return batch
+
+
+def _assert_same_bytes(batch, expected):
+    assert batch.keys() == expected.keys()
+    for k, want in expected.items():
+        got = np.asarray(batch[k])
+        assert got.shape == want.shape and got.dtype == want.dtype, (k, got.shape, got.dtype)
+        np.testing.assert_array_equal(got, want, err_msg=k)
+
+
+@pytest.mark.parametrize("n_envs", [1, 4])
+def test_sample_returns_the_bytes_of_a_plain_ring(n_envs, monkeypatch):
+    cap, seq_len = 24, 5
+    rb, ref = _filled_pair(cap, n_envs, steps=61)  # wrapped twice and a half
+    head = int(rb._pos[0])
+    starts = [
+        cap - 1,  # all but one row past the ring's end
+        cap - seq_len + 1,  # one row past it
+        cap - seq_len,  # ends on the last row
+        0,
+        (head - seq_len) % cap,  # ends on the newest row, next to the write head
+        head,  # starts on the oldest row, the other side of the head
+        (head + 3) % cap,
+        7,
+    ]
+    env_idx = [i % n_envs for i in range(len(starts))]
+    batch = _sample_at(rb, monkeypatch, starts, env_idx, seq_len)
+    _assert_same_bytes(batch, ref.read(np.asarray(starts), np.asarray(env_idx), seq_len))
+
+
+def test_sample_of_more_windows_than_one_loop_trip_holds(monkeypatch):
+    # past _UNROLL windows the window form runs as a loop: same bytes
+    from sheeprl_tpu.data import device_buffer
+
+    cap, seq_len, n = 16, 4, 2 * device_buffer._UNROLL + 3
+    rb, ref = _filled_pair(cap, 2, steps=37)
+    rng = np.random.default_rng(1)
+    starts, env_idx = rng.integers(0, cap, n), rng.integers(0, 2, n)
+    batch = _sample_at(rb, monkeypatch, starts, env_idx, seq_len)
+    _assert_same_bytes(batch, ref.read(starts, env_idx, seq_len))
+
+
+def test_sharded_sample_returns_the_bytes_of_a_plain_ring(monkeypatch):
+    from sheeprl_tpu.parallel.mesh import make_mesh
+
+    cap, seq_len, n_envs = 12, 4, 8
+    mesh = make_mesh(n_devices=4, axis_names=("data",))
+    rb, ref = _filled_pair(cap, n_envs, steps=31, mesh=mesh)
+    # two windows a device, each from the device's own block of two envs
+    env_idx = np.asarray([0, 1, 3, 2, 4, 4, 7, 6])
+    starts = np.asarray([cap - 1, 0, cap - 2, 5, cap - seq_len, int(rb._pos[4]), cap - 3, 1])
+    batch = _sample_at(rb, monkeypatch, starts, env_idx, seq_len)
+    _assert_same_bytes(batch, ref.read(starts, env_idx, seq_len))
+    # the sharded add wrote every env's rows where the plain ring has them
+    state = rb.state_dict()
+    for k, want in ref.buf.items():
+        np.testing.assert_array_equal(state["buffer"][k], want, err_msg=k)
+
+
+def test_state_dict_has_logical_shapes_and_todays_format_loads(monkeypatch):
+    cap, n_envs = 10, 2
+    rb, ref = _filled_pair(cap, n_envs, steps=23)
+    state = rb.state_dict()
+    assert set(state) == {"buffer", "pos", "filled", "added"}
+    for k, (shape, dtype) in _MIXED_KEYS.items():
+        assert state["buffer"][k].shape == (cap, n_envs, *shape) and state["buffer"][k].dtype == dtype
+        np.testing.assert_array_equal(state["buffer"][k], ref.buf[k])
+    assert rb.footprint()["device_bytes"] == sum(v.nbytes for v in ref.buf.values())
+
+    # a state dict as the buffer wrote it before it held keys flat, by hand
+    old = {
+        "buffer": {k: v.copy() for k, v in ref.buf.items()},
+        "pos": ref.pos % cap,
+        "filled": np.minimum(ref.pos, cap),
+    }
+    rb2 = DeviceSequentialReplayBuffer(cap, n_envs=n_envs).load_state_dict(old)
+    starts, env_idx = np.asarray([cap - 2, 3, 0, cap - 1]), np.asarray([0, 1, 1, 0])
+    _assert_same_bytes(_sample_at(rb2, monkeypatch, starts, env_idx, 4), ref.read(starts, env_idx, 4))
+    # and it goes on where the other left off
+    data = _mixed_step(np.random.default_rng(5), n_envs)
+    rb2.add(data)
+    ref.add(data, range(n_envs))
+    for k, want in ref.buf.items():
+        np.testing.assert_array_equal(rb2.state_dict()["buffer"][k], want, err_msg=k)
+
+
+def test_cross_format_roundtrip_keeps_image_keys_logical():
+    from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
+
+    cap, n_envs = 8, 2
+    rng = np.random.default_rng(2)
+    host = EnvIndependentReplayBuffer(cap, n_envs=n_envs, buffer_cls=SequentialReplayBuffer)
+    for _ in range(11):
+        host.add(_mixed_step(rng, n_envs))
+    dev = DeviceSequentialReplayBuffer(cap, n_envs=n_envs).load_state_dict(host.state_dict())
+    state = dev.state_dict()
+    for e, sub in enumerate(host.state_dict()["buffers"]):
+        for k, (shape, _) in _MIXED_KEYS.items():
+            assert state["buffer"][k].shape == (cap, n_envs, *shape)
+            np.testing.assert_array_equal(state["buffer"][k][:, e], np.asarray(sub["buffer"][k])[:, 0], err_msg=k)
+    host2 = EnvIndependentReplayBuffer(cap, n_envs=n_envs, buffer_cls=SequentialReplayBuffer)
+    host2.load_state_dict(state)
+    for k in _MIXED_KEYS:
+        np.testing.assert_array_equal(np.asarray(host2.buffer[1][k]), np.asarray(host.buffer[1][k]), err_msg=k)
+
+
+@pytest.mark.parametrize("steps", [3, 8, 13])  # head mid-ring, on row 0 after a wrap, past it
+def test_mark_last_truncated_lands_on_the_newest_row(steps):
+    cap = 8
+    rb, ref = _filled_pair(cap, 2, steps=steps)
+    rb.mark_last_truncated(1)
+    last = int((ref.pos[1] - 1) % cap)
+    want = {k: ref.buf[k].copy() for k in ("terminated", "truncated", "is_first")}
+    want["terminated"][last, 1], want["truncated"][last, 1], want["is_first"][last, 1] = 0.0, 1.0, 0.0
+    state = rb.state_dict()["buffer"]
+    for k, v in want.items():
+        np.testing.assert_array_equal(state[k], v, err_msg=k)
+    np.testing.assert_array_equal(state["rgb"], ref.buf["rgb"])
+
+
+def test_host_replay_path_never_loads_the_ring():
+    """``buffer.device=False`` shares no code with the HBM ring: the factory
+    hands out the host buffers without importing ``device_buffer``."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    code = (
+        "import sys, numpy as np\n"
+        "from types import SimpleNamespace as NS\n"
+        "from sheeprl_tpu.data.factory import make_dreamer_replay_buffer\n"
+        "cfg = NS(buffer=dict(device=False, memmap=False))\n"
+        "cfg.buffer = type('B', (dict,), {'__getattr__': dict.__getitem__})(cfg.buffer)\n"
+        "rb, on_device = make_dreamer_replay_buffer(cfg, 1, 2, ('rgb',), '.', 8)\n"
+        "assert not on_device and type(rb).__name__ == 'EnvIndependentReplayBuffer'\n"
+        "for t in range(5): rb.add({'rgb': np.full((1, 2, 3, 4, 4), t, np.uint8)})\n"
+        "s = rb.sample(4, sequence_length=3)\n"
+        "assert s['rgb'].shape == (1, 3, 4, 3, 4, 4) and (np.diff(s['rgb'][0, :, :, 0, 0, 0].astype(int), axis=0) == 1).all()\n"
+        "assert 'sheeprl_tpu.data.device_buffer' not in sys.modules\n"
+    )
+    root = Path(__file__).resolve().parents[2]
+    done = subprocess.run([sys.executable, "-c", code], cwd=root, capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+# -- the invariant, from the compiler ----------------------------------------
+_CELL_KEYS = {  # the DV3 loop's ring at the benchmark cell's shapes
+    "rewards": ((1,), np.float32),
+    "terminated": ((1,), np.float32),
+    "truncated": ((1,), np.float32),
+    "is_first": ((1,), np.float32),
+    "actions": ((6,), np.float32),
+}
+_BATCH, _SEQ = 16, 64
+_TEMP_LIMIT = 16 * 1024 * 1024
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e (no chip): ``topologies(name)`` gives its devices."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def describe(name, **kwargs):
+        try:
+            return topologies.get_topology_desc(platform="tpu", topology_name=name, **kwargs).devices
+        except Exception as e:  # no libtpu here, or another process holds its lock
+            pytest.skip(f"no {name} topology can be described here: {e}")
+
+    describe("v5e:2x2")
+    # such a compile can be written to the persistent cache and not read back
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield describe
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _ring_as_the_buffer_holds_it(cap, n_envs, frame, sharding):
+    """Shapes of the storage, the step and the logical trailing shapes, taken
+    from a real buffer of 4 rows after its first add and stretched to ``cap``."""
+    import jax
+
+    keys = {"rgb": (frame, np.uint8), **_CELL_KEYS}
+    rb = DeviceSequentialReplayBuffer(4, n_envs=n_envs)
+    rb.add({k: np.zeros((1, n_envs, *shape), dtype) for k, (shape, dtype) in keys.items()})
+    buf = {k: jax.ShapeDtypeStruct((cap, *v.shape[1:]), v.dtype, sharding=sharding(2)) for k, v in rb._buf.items()}
+    step = {k: jax.ShapeDtypeStruct((n_envs, *shape), dtype, sharding=sharding(0)) for k, (shape, dtype) in keys.items()}
+    return buf, step, rb._shapes
+
+
+def _capacity_sized_copies(compiled, cap):
+    import re
+
+    found = []
+    for line in compiled.as_text().splitlines():
+        match = re.search(r"= \w+\[([\d,]*)\]\S* copy\(", line)
+        if match and str(cap) in match.group(1).split(","):
+            found.append(line.strip()[:160])
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_ring(describe, cap, n_envs, frame):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from sheeprl_tpu.data.device_buffer import replay_add, replay_gather
+
+    one_chip = SingleDeviceSharding(describe("v5e:1x1", chips_per_host_bounds=(1, 1, 1), num_slices=1)[0])
+    buf, step, shapes = _ring_as_the_buffer_holds_it(cap, n_envs, frame, lambda ndim: one_chip)
+    windows = jax.ShapeDtypeStruct((_BATCH,), np.int32, sharding=one_chip)
+    written = jax.ShapeDtypeStruct((n_envs,), np.int32, sharding=one_chip)
+    gather = replay_gather.lower(buf, windows, windows, _SEQ, shapes).compile()
+    add = replay_add.lower(buf, step, written, written).compile()
+    return gather, add, sum(math.prod(v.shape) * v.dtype.itemsize for v in buf.values())
+
+
+@pytest.mark.parametrize("frame", [(3, 64, 64), (1, 84, 84)], ids=["64x64x3", "84x84"])
+@pytest.mark.parametrize("cap,n_envs", [(250_000, 1), (500_000, 1), (62_500, 4)])
+def test_compiled_for_a_v5e_the_ring_moves_nothing_of_capacity_size(v5e, cap, n_envs, frame):
+    gather, add, ring_bytes = _compiled_ring(v5e, cap, n_envs, frame)
+    for name, compiled in (("replay_gather", gather), ("replay_add", add)):
+        assert compiled.memory_analysis().temp_size_in_bytes < _TEMP_LIMIT, name
+        assert not _capacity_sized_copies(compiled, cap), name
+    # the sample leaves in logical shape; the add is in place over the whole ring
+    assert tuple(gather.out_info["rgb"].shape) == (_SEQ, _BATCH, *frame)
+    assert add.memory_analysis().alias_size_in_bytes >= ring_bytes
+
+
+@pytest.mark.parametrize("frame", [(3, 64, 64), (1, 84, 84)], ids=["64x64x3", "84x84"])
+def test_compiled_temporaries_do_not_grow_with_the_ring(v5e, frame):
+    small, large = _compiled_ring(v5e, 250_000, 1, frame), _compiled_ring(v5e, 500_000, 1, frame)
+    for a, b in zip(small[:2], large[:2]):
+        assert a.memory_analysis().temp_size_in_bytes == b.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("frame", [(3, 64, 64), (1, 84, 84)], ids=["64x64x3", "84x84"])
+def test_compiled_for_four_chips_the_env_sharded_ring_moves_nothing_of_capacity_size(v5e, frame):
+    import jax
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from sheeprl_tpu.data.device_buffer import _make_sharded_add, _make_sharded_gather
+
+    cap, n_envs = 62_500, 8
+    mesh = Mesh(np.array(v5e("v5e:2x2")).reshape(4), ("data",))
+    spec = {2: P(None, "data"), 1: P("data"), 0: P()}
+    buf, step, shapes = _ring_as_the_buffer_holds_it(cap, n_envs, frame, lambda k: NamedSharding(mesh, spec[k]))
+    windows = jax.ShapeDtypeStruct((_BATCH,), np.int32, sharding=NamedSharding(mesh, spec[1]))
+    written = jax.ShapeDtypeStruct((n_envs,), np.int32, sharding=NamedSharding(mesh, spec[0]))
+    gather = _make_sharded_gather(mesh, _SEQ, shapes).lower(buf, windows, windows).compile()
+    add = _make_sharded_add(mesh).lower(buf, step, written, written).compile()
+    for name, compiled in (("gather", gather), ("add", add)):
+        assert compiled.memory_analysis().temp_size_in_bytes < _TEMP_LIMIT, name
+        assert not _capacity_sized_copies(compiled, cap), name
+        for collective in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
+            assert collective not in compiled.as_text(), (name, collective)
+    ring_bytes = sum(math.prod(v.shape) * v.dtype.itemsize for v in buf.values())
+    assert add.memory_analysis().alias_size_in_bytes >= ring_bytes // 4  # a device's share
