@@ -11,27 +11,27 @@ frames the env has emitted (resets included): :func:`frame_of`,
 :func:`reward_of` and :class:`EpisodeSchedule`.  Every frame carries ``k`` in
 its first eight bytes, so a replayed row says which frame it claims to be.
 
-The env also keeps the client's side of the measurement: ``time.time()`` at
-every ``step`` call and the action it was given, in preallocated arrays that
-are written to ``log_path`` every ``flush_every`` steps and at ``close``.
-The program reaches this module through ``hydra/env/chipbench.yaml``.
+The env also keeps the client's side of the measurement (``steplog.py``):
+``time.time()`` at every ``step`` call, the action it was given and the index
+of the newest frame.  The program reaches this module through
+``hydra/env/chipbench.yaml``.
 """
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import gymnasium as gym
 import numpy as np
 
+from benchmarks.chip.steplog import StepLog
+
 FRAME_SHAPE = (3, 64, 64)
 BANK = 251  # seeded base frames; prime, so the xor pattern below never lines up with it
 LEVEL_BLOCK = 128  # frames that share a brightness level
 LEVELS = 4093  # prime
 REWARD_TABLE = 65521  # prime
-_LOG_CAPACITY = 1 << 20
 
 
 class FrameBank:
@@ -136,12 +136,7 @@ class BenchEnv(gym.Env):
         self._step_s = max(0.0, float(step_ms)) / 1000.0
         self._k = -1  # index of the newest frame emitted
         self._left = 0  # steps left in the episode
-        self._log_path = log_path
-        self._flush_every = int(flush_every)
-        self._times = np.zeros(_LOG_CAPACITY, np.float64)
-        self._actions = np.full(_LOG_CAPACITY, -1, np.int16)
-        self._frames_at = np.zeros(_LOG_CAPACITY, np.int64)
-        self._n = 0
+        self.log = StepLog(log_path, flush_every=flush_every)
 
     def _emit(self) -> Dict[str, np.ndarray]:
         self._k += 1
@@ -154,43 +149,17 @@ class BenchEnv(gym.Env):
         return self._emit(), {}
 
     def step(self, action) -> Tuple[Dict[str, np.ndarray], float, bool, bool, dict]:
-        now = time.time()
-        if self._n < _LOG_CAPACITY:
-            self._times[self._n] = now
-            self._actions[self._n] = int(np.asarray(action).reshape(-1)[0])
-            self._frames_at[self._n] = self._k
-            self._n += 1
-            if self._log_path and self._n % self._flush_every == 0:
-                self.flush()
+        self.log.stamp(action, self._k)
         if self._step_s > 0.0:
             time.sleep(self._step_s)
         obs = self._emit()
         self._left -= 1
         return obs, reward_of(self._rewards, self._k), self._left <= 0, False, {}
 
-    def flush(self) -> None:
-        """Write the log so far; replace, never append, so a reader sees a whole file."""
-        if not self._log_path:
-            return
-        tmp = self._log_path + ".part"
-        with open(tmp, "wb") as fh:
-            np.savez(
-                fh,
-                times=self._times[: self._n],
-                actions=self._actions[: self._n],
-                frames_at=self._frames_at[: self._n],
-            )
-        os.replace(tmp, self._log_path)
-
     def close(self) -> None:
-        self.flush()
+        self.log.flush()
 
 
 def make_bench_env(seed: int = 0, **params: Any) -> BenchEnv:
     """The ``_target_`` of ``hydra/env/chipbench.yaml``."""
     return BenchEnv(seed=seed, **params)
-
-
-def read_step_log(path: str) -> Dict[str, np.ndarray]:
-    with np.load(path) as data:
-        return {k: np.array(data[k]) for k in ("times", "actions", "frames_at")}

@@ -4,10 +4,14 @@
 thread, exactly as ``python sheeprl.py`` does, with overrides taken from the
 cell's files.  Nothing of the program is edited; the harness stands around it:
 
-- the env is the benchmark's own (``envs.py``), which keeps the client's clock;
-- the weights are the benchmark's own (``weights.py``), handed to the program
-  through the argument ``build_agent`` takes saved weights in;
-- the compiled train step the loop built is wrapped where the loop gets it
+- everything that knows which algorithm runs comes from the family module the
+  configuration's file names (``families/<family>.py``): where the
+  benchmark's own weights go in and the player's forward pass is copied, how
+  the train step's arguments and result are read, which env the cells run
+  against, the comparison that decides ``correct``, and the faults that
+  comparison has to catch;
+- the env is the benchmark's own, which keeps the client's clock (``steplog.py``);
+- the compiled train step the loop built is wrapped where every loop gets it
   (``Diagnostics.instrument``): the :class:`Recorder` copies what goes into
   and comes out of its first steps, stamps every later call, and is otherwise
   a pass-through.  The object the window drives is the one those first steps
@@ -38,7 +42,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from benchmarks.chip.manifest import Manifest
+from benchmarks.chip.manifest import Manifest, ManifestError
+from benchmarks.chip.steplog import read_step_log
 from benchmarks.chip.window import window_metrics
 
 RECORDED_STEPS = 3
@@ -53,42 +58,39 @@ class BenchFailure(RuntimeError):
 # --------------------------------------------------------------------------
 # the train step, wrapped where the loop receives it
 # --------------------------------------------------------------------------
-def _to_host(tree: Any) -> Any:
+def to_host(tree: Any) -> Any:
+    """Host copies of a tree's leaves."""
     import jax
 
     return jax.tree_util.tree_map(np.asarray, tree)
 
 
-def find_adam_mu(state: Any) -> Any:
-    """The first-moment tree inside an optax chain's state."""
-    if hasattr(state, "mu"):
-        return state.mu
-    if isinstance(state, (tuple, list)):
-        for sub in state:
-            found = find_adam_mu(sub)
-            if found is not None:
-                return found
-    return None
-
-
 class Recorder:
-    """Pass-through around the loop's train step that keeps its first steps."""
+    """Pass-through around the loop's train step that keeps its first steps.
 
-    def __init__(self, step: Callable, annotate: bool = False):
-        self._step = step
-        self._annotation = None
-        if annotate:  # the harness's own host span, on the profiler's clock
-            import jax
+    Made before the loop starts, with the family's ``split_step`` as its only
+    knowledge of the step's signature; :meth:`wrap` takes the compiled step
+    when the loop hands it over.  Of the first ``RECORDED_STEPS`` calls it
+    keeps host copies (the arguments are donated, so copied before the call):
+    ``params_before`` the first, of each ``steps[n]`` the ``batch``, ``key``
+    and ``aux`` that went in and the ``metrics`` that came out,
+    ``opt_state_after_first``, and ``params_after`` the last.  ``player`` is
+    the family's to fill (``install``)."""
 
-            self._annotation = jax.profiler.TraceAnnotation
+    def __init__(self, split_step: Callable[[tuple, Optional[tuple]], Dict[str, Any]]):
+        self._split = split_step
+        self._step: Optional[Callable] = None
         self.calls = 0
         self.call_times = np.zeros(_CALL_CAPACITY, np.float64)
         self.params_before: Any = None
-        self.moments_before: Any = None
-        self.inputs: List[Dict[str, Any]] = []  # batch, key, tau of each recorded step
-        self.metrics: List[np.ndarray] = []  # the step's own metric vector
-        self.mu_after_first: Any = None
+        self.steps: List[Dict[str, Any]] = []
+        self.opt_state_after_first: Any = None
         self.params_after: Any = None
+        self.player: Any = None
+
+    def wrap(self, step: Callable) -> "Recorder":
+        self._step = step
+        return self
 
     def __getattr__(self, name: str) -> Any:  # whatever else the loop asks of the step
         return getattr(self._step, name)
@@ -97,27 +99,25 @@ class Recorder:
         """Let go of the compiled step once the loop has ended, so that the check has the device to itself."""
         self._step = None
 
-    def __call__(self, params, opt_states, moments_state, batch, key, tau):
+    def __call__(self, *args):
         n = self.calls
         if n < _CALL_CAPACITY:
             self.call_times[n] = time.time()
         self.calls = n + 1
         if n >= RECORDED_STEPS:
-            if self._annotation is not None:
-                with self._annotation("bench/train_dispatch"):
-                    return self._step(params, opt_states, moments_state, batch, key, tau)
-            return self._step(params, opt_states, moments_state, batch, key, tau)
-        # the first steps: the arguments are donated, so copy before the call
+            return self._step(*args)
+        went_in = self._split(args, None)
         if n == 0:
-            self.params_before = _to_host(params)
-            self.moments_before = _to_host(moments_state)
-        self.inputs.append({"batch": _to_host(batch), "key": np.asarray(key), "tau": float(tau)})
-        out = self._step(params, opt_states, moments_state, batch, key, tau)
-        self.metrics.append(np.asarray(out[3]))
+            self.params_before = to_host(went_in["params"])
+        record = {k: to_host(went_in[k]) for k in ("batch", "key", "aux")}
+        out = self._step(*args)
+        came_out = self._split(args, out)
+        record["metrics"] = to_host(came_out["metrics"])
+        self.steps.append(record)
         if n == 0:
-            self.mu_after_first = {k: _to_host(find_adam_mu(v)) for k, v in out[1].items()}
+            self.opt_state_after_first = to_host(came_out["opt_state"])
         if n == RECORDED_STEPS - 1:
-            self.params_after = _to_host(out[0])
+            self.params_after = to_host(came_out["params"])
         return out
 
 
@@ -163,11 +163,11 @@ def scrape(port: int) -> Dict[str, float]:
 class Watcher(threading.Thread):
     """Bounds the window from outside the loop."""
 
-    def __init__(self, journal_glob: str, recorder_of: Callable[[], Optional[Recorder]], seconds: float,
+    def __init__(self, journal_glob: str, recorder: Recorder, seconds: float,
                  warmup_steps: int, trace_dir: Optional[str], trace_seconds: float, startup_limit_s: float):
         super().__init__(name="bench-watcher", daemon=True)
         self._journal_glob = journal_glob
-        self._recorder_of = recorder_of
+        self._recorder = recorder
         self.seconds = float(seconds)
         self._warmup_steps = int(warmup_steps)
         self._trace_dir = trace_dir
@@ -212,11 +212,7 @@ class Watcher(threading.Thread):
     def _run(self) -> None:
         deadline = time.time() + self._startup_limit_s
 
-        def warm() -> bool:
-            recorder = self._recorder_of()
-            return recorder is not None and recorder.calls >= self._warmup_steps
-
-        if not self._wait_for(warm, deadline):
+        if not self._wait_for(lambda: self._recorder.calls >= self._warmup_steps, deadline):
             self.error = f"the loop did not reach {self._warmup_steps} train steps in {self._startup_limit_s:.0f} s"
             return
         port = self._port()
@@ -261,13 +257,12 @@ def end_run() -> None:
 # --------------------------------------------------------------------------
 # one run
 # --------------------------------------------------------------------------
-def compose_overrides(config: Dict[str, Any], cell: Dict[str, Any], seed: int, log_path: str,
+def compose_overrides(family: Any, config: Dict[str, Any], cell: Dict[str, Any], seed: int, log_path: str,
                       extra: Optional[List[str]] = None) -> List[str]:
-    overrides = list(config["overrides"]) + ["env=chipbench"]
-    overrides += [f"env.wrapper.{k}={v}" for k, v in cell["env"].items()]
+    overrides = list(config["overrides"]) + [f"env={family.env_group}"]
+    overrides += list(family.env_overrides(cell, log_path))
     overrides += list(cell.get("overrides", []))
     overrides += [
-        f"env.wrapper.log_path={log_path}",
         f"seed={seed}",
         f"algo.total_steps={TOTAL_STEPS}",
         "diagnostics.telemetry.http.enabled=True",
@@ -290,17 +285,23 @@ def run_cell(
     trace: bool,
     t_start: float,
     work_dir: str,
-    break_step: Optional[Callable[[Callable], Callable]] = None,
+    fault: Optional[str] = None,
     extra_overrides: Optional[List[str]] = None,
     controls: Optional[List[str]] = None,
     precision: Optional[str] = None,
 ) -> Dict[str, Any]:
     """Run one cell once; returns the result object of the contract plus, under
-    ``_run``, what the readers saw.  The caller has already looked for the chip."""
+    ``_run``, what the readers saw.  ``fault`` names one of the family's
+    ``faults``, planted under the loop's train step.  The caller has already
+    looked for the chip."""
     import jax
 
     cell = manifest.workload(workload)
     config = manifest.config(cell["config"])
+    family = manifest.family(config)
+    if fault and fault not in family.faults:
+        raise ManifestError(f"family {config['family']!r} has no fault {fault!r}: {sorted(family.faults)}")
+    break_step = family.faults[fault] if fault else None
     if precision:  # the control: the program's own path in another precision
         config["precision"] = precision
         extra_overrides = list(extra_overrides or []) + [f"fabric.precision={precision}"]
@@ -317,15 +318,10 @@ def run_cell(
     os.environ["SHEEPRL_TPU_SEARCH_PATH"] = os.path.join(manifest.bench_dir, "hydra")
 
     from sheeprl_tpu import diagnostics as diag_module
-    from sheeprl_tpu.algos.dreamer_v3.agent import PlayerDV3
-    from sheeprl_tpu.algos.dreamer_v3 import dreamer_v3 as dv3
     from sheeprl_tpu.cli import run as cli_run
 
-    from benchmarks.chip.weights import make_weights
-
-    recorders: List[Recorder] = []
+    recorder = Recorder(family.split_step)
     original_instrument = diag_module.Diagnostics.instrument
-    original_build = dv3._build_agent_from_state
 
     def instrument(self, name, fn, **kwargs):
         wrapped = original_instrument(self, name, fn, **kwargs)
@@ -333,45 +329,24 @@ def run_cell(
             return wrapped
         if break_step is not None:
             wrapped = break_step(wrapped)
-        recorders.append(Recorder(wrapped, annotate=trace))
-        return recorders[-1]
-
-    original_get_actions = PlayerDV3.get_actions
-    player: Dict[str, Any] = {}
-
-    def get_actions(self, wm_params, actor_params, obs, key, greedy=False, mask=None):
-        """The player's forward pass, the first call after the last recorded
-        step copied on the way through: it acts with the weights that step left."""
-        if "record" in player or not recorders or recorders[-1].params_after is None:
-            return original_get_actions(self, wm_params, actor_params, obs, key, greedy, mask)
-        record = {"before": _to_host(self.state), "obs": _to_host(obs), "key": np.asarray(key)}
-        actions = original_get_actions(self, wm_params, actor_params, obs, key, greedy, mask)
-        record.update(after=_to_host(self.state), actions=np.asarray(actions))
-        player["record"] = record
-        return actions
-
-    def build_agent(runtime, actions_dim, is_continuous, cfg, obs_space, state):
-        wm_def, actor_def, critic_def, params = original_build(
-            runtime, actions_dim, is_continuous, cfg, obs_space, state
-        )
-        return wm_def, actor_def, critic_def, make_weights(params, seed)
+        return recorder.wrap(wrapped)
 
     watcher = Watcher(
         journal_glob=os.path.join(run_dir, "logs", "runs", "bench", cell["name"], "version_*", "journal.jsonl"),
-        recorder_of=lambda: recorders[-1] if recorders else None,
+        recorder=recorder,
         seconds=seconds,
         warmup_steps=int(cell["warmup_steps"]),
         trace_dir=trace_dir,
         trace_seconds=float(cell.get("trace_seconds", 3.0)),
         startup_limit_s=float(cell.get("startup_limit_s", 1000.0)),
     )
-    overrides = compose_overrides(config, cell, seed, log_path, extra_overrides)
+    overrides = compose_overrides(family, config, cell, seed, log_path, extra_overrides)
     cwd = os.getcwd()
     exit_code: Optional[int] = None
-    diag_module.Diagnostics.instrument = instrument
-    dv3._build_agent_from_state = build_agent
-    PlayerDV3.get_actions = get_actions
+    restore_program: Callable[[], None] = lambda: None  # noqa: E731
     try:
+        diag_module.Diagnostics.instrument = instrument
+        restore_program = family.install(seed, recorder)
         os.chdir(run_dir)
         watcher.start()
         try:
@@ -387,8 +362,7 @@ def run_cell(
         else:
             os.environ["SHEEPRL_TPU_SEARCH_PATH"] = search_path
         diag_module.Diagnostics.instrument = original_instrument
-        dv3._build_agent_from_state = original_build
-        PlayerDV3.get_actions = original_get_actions
+        restore_program()
     watcher.join(timeout=30)
     if watcher.tracer is not None:
         watcher.tracer.join(timeout=300)
@@ -399,16 +373,13 @@ def run_cell(
 
     if watcher.error or watcher.t0 is None or len(watcher.scrapes) < 2:
         raise BenchFailure(f"the window was never bounded: {watcher.error}")
-    recorder = recorders[-1]
     recorder.release()
     gc.collect()
     journal = read_journal(watcher.journal_path)
     t0, t1 = watcher.t0, watcher.t0 + watcher.seconds
 
-    from benchmarks.chip.envs import read_step_log
-
     step_log = read_step_log(log_path)
-    window = window_metrics(step_log["times"], t0, watcher.seconds)
+    window = window_metrics(step_log["times"], t0, watcher.seconds, step_log["env"])
     call_times = recorder.call_times[: min(recorder.calls, _CALL_CAPACITY)]
     window["gradient_steps"] = int(((call_times >= t0) & (call_times <= t1)).sum())
     window["seconds"] = watcher.seconds
@@ -422,6 +393,7 @@ def run_cell(
         "t_start": t_start,
         "t0": t0,
         "config": config,
+        "family": family,
         "cell": cell,
         "device": device,
         "journal": journal,
@@ -439,11 +411,9 @@ def run_cell(
     if trace:
         from benchmarks.chip.trace_reduce import find_xplane, load_xplane, reduce_trace
 
-        run["trace"] = reduce_trace(load_xplane(find_xplane(trace_dir)))
+        run["trace"] = reduce_trace(load_xplane(find_xplane(trace_dir)), module_match=family.executables["train_step"])
         run["trace"]["host_window"] = watcher.trace_window
-    from benchmarks.chip.check import compare_run
-
-    checks.update(compare_run(recorder, step_log, config, cell, seed, controls=controls, player=player.get("record")))
+    checks.update(family.compare(recorder, recorder.player, step_log, config, cell, seed, controls))
 
     run["checks"] = checks
     result = assemble_result(manifest, workload, run, checks, trace)

@@ -4,11 +4,15 @@
 belongs to one of them sits in a file of its own that is found by that name:
 
 - ``workloads/<cell>.json``: the traffic (env parameters, warm-up, extra overrides);
-- the configuration's ``file`` (``configs/<config>.json``): source, overrides, shapes;
+- the configuration's ``file`` (``configs/<config>.json``): source, overrides,
+  shapes, the ``family`` of its algorithm and its plain ``reference``;
+- ``families/<family>.py``: everything that knows which algorithm runs
+  (:data:`FAMILY_ANSWERS`; PERF.md section 3 says what each is);
 - ``metrics/<metric>.py``: one reader, ``read(run) -> float | None``.
 
-Adding a cell, a configuration or a per-layer metric adds files and entries
-in ``BENCHMARK.json``; nothing here or in ``run.py`` names any of them.
+Adding a cell, a configuration, a family or a per-layer metric adds files and
+entries in ``BENCHMARK.json``; nothing here, in ``run.py``, ``harness.py`` or
+``check.py`` names any of them.
 """
 
 from __future__ import annotations
@@ -23,6 +27,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# what a family module answers: the whole of what a new algorithm family brings
+FAMILY_ANSWERS = ("install", "split_step", "compare", "faults", "train_step_flops", "executables", "train_step_scopes",
+                  "env_group", "env_overrides")
 
 
 class ManifestError(RuntimeError):
@@ -59,8 +66,26 @@ class Manifest:
         return {**cell, **entry}
 
     def config(self, name: str) -> Dict[str, Any]:
+        """The configuration's file, its ``reference`` made a path from the root."""
         entry = self._entry("configs", name)
-        return {**load_json(os.path.join(self.root, entry["file"])), "name": name}
+        config = {**load_json(os.path.join(self.root, entry["file"])), "name": name}
+        if "reference" in config:
+            config["reference"] = os.path.join(self.root, config["reference"])
+        return config
+
+    def family(self, config: Dict[str, Any]) -> Any:
+        """The module ``families/<family>.py`` that the configuration's file names."""
+        name = config.get("family")
+        if not name or not NAME_RE.match(str(name)):
+            raise ManifestError(f"configuration {config.get('name')!r} names no family in its file")
+        path = os.path.join(self.bench_dir, "families", f"{name}.py")
+        if not os.path.isfile(path):
+            raise ManifestError(f"family {name!r} of configuration {config.get('name')!r} has no file at {path}")
+        module = load_file(path, "bench_family_" + name)
+        missing = [answer for answer in FAMILY_ANSWERS if not hasattr(module, answer)]
+        if missing:
+            raise ManifestError(f"family file {path} does not answer {missing}")
+        return module
 
     def metrics_for(self, workload: str, section: str) -> List[Dict[str, Any]]:
         """The metrics of ``end_to_end`` or ``per_layer`` that this cell reports."""
@@ -72,10 +97,15 @@ class Manifest:
         path = os.path.join(self.bench_dir, "metrics", f"{metric}.py")
         if not os.path.isfile(path):
             raise ManifestError(f"per-layer metric {metric!r} has no reader at {path}")
-        spec = importlib.util.spec_from_file_location("bench_metric_" + re.sub(r"\W", "_", metric), path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return load_file(path, "bench_metric_" + metric).read
+
+
+def load_file(path: str, name: str) -> Any:
+    """The module in the file at ``path``: found by where it is, not by a name on ``sys.path``."""
+    spec = importlib.util.spec_from_file_location(re.sub(r"\W", "_", name), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def check_names(data: Dict[str, Any]) -> List[str]:

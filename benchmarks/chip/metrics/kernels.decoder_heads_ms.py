@@ -1,4 +1,4 @@
-"""Device milliseconds of one ``jit_train_step`` run under
+"""Device milliseconds of one run of the train step under
 ``decoder_heads``: decoder, reward and continue heads and the world-model loss, forward and backward."""
 
 from benchmarks.chip.span_reduce import scope_ms

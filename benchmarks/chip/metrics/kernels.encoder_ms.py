@@ -1,4 +1,4 @@
-"""Device milliseconds of one ``jit_train_step`` run under
+"""Device milliseconds of one run of the train step under
 ``encoder``: the conv and MLP encoders, forward and backward."""
 
 from benchmarks.chip.span_reduce import scope_ms

@@ -1,4 +1,4 @@
-"""Device milliseconds of one ``jit_train_step`` run under
+"""Device milliseconds of one run of the train step under
 ``imagination``: the horizon scan inside the actor loss."""
 
 from benchmarks.chip.span_reduce import scope_ms

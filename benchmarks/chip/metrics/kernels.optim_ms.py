@@ -1,4 +1,4 @@
-"""Device milliseconds of one ``jit_train_step`` run under
+"""Device milliseconds of one run of the train step under
 ``optim``: clipping, the three optimizer updates and the target critic's."""
 
 from benchmarks.chip.span_reduce import scope_ms

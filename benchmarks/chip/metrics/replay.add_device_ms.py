@@ -1,4 +1,4 @@
-"""Device-busy milliseconds inside one execution of the HBM ring's write (``jit_replay_add``),
+"""Device-busy milliseconds inside one execution of the HBM ring's write (the family's ``replay_add`` executable),
 from the device trace."""
 
 from benchmarks.chip.span_reduce import module_ms

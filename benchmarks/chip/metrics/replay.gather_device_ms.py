@@ -1,4 +1,4 @@
-"""Device-busy milliseconds inside one execution of the HBM ring's sample (``jit_replay_gather``),
+"""Device-busy milliseconds inside one execution of the HBM ring's sample (the family's ``replay_gather`` executable),
 from the device trace."""
 
 from benchmarks.chip.span_reduce import module_ms

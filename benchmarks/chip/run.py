@@ -10,7 +10,7 @@ the contract; the numbers compared, each beside its limit, are its last key
 and the last lines of stderr.  Three flags the driver never passes are for
 showing that the comparison fails what it should: ``--precision`` puts the
 program's own path in another precision in the cell's place (the control),
-``--fault`` plants a fault of ``faults.py`` under the loop's train step, and
+``--fault`` plants one of the family's ``faults`` under the loop's train step, and
 ``--control`` also reads the reference in a lower precision against itself
 and prints the worst leaves on stderr.
 """
@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     parser.add_argument("--control", default="", help="comma-separated precisions to read the reference in as well")
     parser.add_argument("--precision", default="", help="run the program at this fabric.precision instead (a control)")
-    parser.add_argument("--fault", default="", help="plant this fault of faults.py under the train step")
+    parser.add_argument("--fault", default="", help="plant this fault of the configuration's family under the train step")
     args = parser.parse_args(argv)
 
     if ROOT not in sys.path:
@@ -50,7 +50,7 @@ def main(argv=None) -> int:
     try:
         manifest = Manifest(ROOT)
         cell = manifest.workload(args.workload)
-        manifest.config(cell["config"])
+        manifest.family(manifest.config(cell["config"]))
     except ManifestError as err:
         print(f"bench: {err}", file=sys.stderr)
         return 2
@@ -73,15 +73,17 @@ def main(argv=None) -> int:
         )
         return 1
 
-    from benchmarks.chip.faults import FAULTS
     from benchmarks.chip.harness import BenchFailure, run_cell
 
     try:
         result = run_cell(
             manifest, args.workload, args.seed, args.seconds, bool(args.trace), T_START, WORK_DIR,
-            break_step=FAULTS[args.fault] if args.fault else None,
+            fault=args.fault or None,
             controls=[c for c in args.control.split(",") if c], precision=args.precision or None,
         )
+    except ManifestError as err:  # a fault the cell's family does not have
+        print(f"bench: {err}", file=sys.stderr)
+        return 2
     except BenchFailure as err:
         print(f"bench: no measurement: {err}", file=sys.stderr)
         return 1

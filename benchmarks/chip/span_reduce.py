@@ -4,7 +4,9 @@
 executable.  This reduction reads what the program itself names: its jitted
 functions (``jit_<name>`` on the ``XLA Modules`` line), the ``jax.named_scope``
 each operation was traced under, and the loop's ``sheeprl/<phase>`` spans,
-which ``Diagnostics.span`` puts on the host plane of the same trace.  It works
+which ``Diagnostics.span`` puts on the host plane of the same trace.  Which
+executables and scopes an algorithm has, its family says (``executables``,
+``train_step_scopes`` in ``families/<family>.py``).  It works
 on a plain structure, ``trace_reduce``'s with one more field per event, so
 that a test can build one by hand:
 
@@ -27,24 +29,27 @@ import bisect
 import functools
 import os
 import re
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Pattern, Sequence, Tuple
 
 from benchmarks.chip import run as command
-from benchmarks.chip.trace_reduce import DEVICE_PREFIX, MODULES_LINE, OPS_LINE, _line, device_planes, find_xplane, merge_intervals
+from benchmarks.chip.trace_reduce import (
+    ANNOTATION_PREFIX as SPAN_PREFIX, DEVICE_PREFIX, MODULES_LINE, OPS_LINE, UNATTRIBUTED, _line, device_planes,  # UNATTRIBUTED: for the readers
+    find_xplane, idle_by_span, merge_intervals, owner_segments,
+)
 
-SPAN_PREFIX = "sheeprl/"
 ITERATION_SPAN = SPAN_PREFIX + "rollout"
 FETCH_SPAN = SPAN_PREFIX + "rollout/action-fetch"
-UNATTRIBUTED = "unattributed"
 UNSCOPED = "unscoped"
+SCOPED_EXECUTABLE = "train_step"  # the executable whose operations are split by scope
+# Nothing of the benchmark reads this: the scopes of a train step are its
+# family's (``train_step_scopes``).  The name, and ``scope_of``'s one-argument
+# form, stay for one test of the program outside the benchmark's directories
+# (``tests/test_diagnostics/test_profiler_spans.py``), until it can be
+# repointed (PERF.md section 7)
+SCOPES = ("encoder", "rssm_scan", "decoder_heads", "imagination", "behaviour_losses", "optim")
 # the counters of ``/metrics`` that the per-step and per-call readers divide by
 ENV_STEPS = "sheeprl_env_steps_total"
 TRAIN_CALLS = 'sheeprl_instrumented_calls_total{fn="train_step"}'
-# the executables of an iteration, by the jitted function's name
-MODULES = ("replay_gather", "replay_add", "player_step", "train_step")
-SCOPED_MODULE = "train_step"
-# the scopes inside the train step; an operation goes to the first its path names
-SCOPES = ("encoder", "rssm_scan", "decoder_heads", "imagination", "behaviour_losses", "optim")
 # the stats of an operation's metadata that may carry its op_name
 PATH_STATS = ("tf_op",)
 
@@ -145,9 +150,9 @@ def program_spans(trace: Dict[str, Any]) -> List[Tuple[str, int, int]]:
 # --------------------------------------------------------------------------
 # executables and scopes
 # --------------------------------------------------------------------------
-def _is_module(event_name: str, module: str) -> bool:
-    """``jit_train_step(123)`` is an execution of ``train_step``."""
-    return event_name.split("(", 1)[0] in (module, "jit_" + module)
+def _is_execution(event_name: str, executable: str) -> bool:
+    """``jit_step(123)`` is an execution of ``jit_step``."""
+    return event_name.split("(", 1)[0] == executable
 
 
 def _overlap(merged: Sequence[Interval], starts: Sequence[int], start: int, end: int) -> int:
@@ -160,19 +165,22 @@ def _overlap(merged: Sequence[Interval], starts: Sequence[int], start: int, end:
     return total
 
 
-_SCOPE_RES = {scope: re.compile(r"(?:^|[/(])" + re.escape(scope) + r"(?:[)/]|$)") for scope in SCOPES}
+@functools.lru_cache(maxsize=None)
+def _scope_re(scope: str) -> Pattern[str]:
+    return re.compile(r"(?:^|[/(])" + re.escape(scope) + r"(?:[)/]|$)")
 
 
-def scope_of(op_path: str) -> str:
-    """The first scope the path names as a component, bare or inside
+def scope_of(op_path: str, scopes: Sequence[str] = SCOPES) -> str:
+    """The first of ``scopes`` the path names as a component, bare or inside
     ``jvp(...)`` / ``transpose(jvp(...))``: forward and backward together."""
-    for scope in SCOPES:
-        if _SCOPE_RES[scope].search(op_path):
+    for scope in scopes:
+        if _scope_re(scope).search(op_path):
             return scope
     return UNSCOPED
 
 
-def _outermost_ns_by_scope(ops: Sequence[Event], op_starts: Sequence[int], start: int, end: int) -> Dict[str, int]:
+def _outermost_ns_by_scope(ops: Sequence[Event], op_starts: Sequence[int], start: int, end: int,
+                           scopes: Sequence[str]) -> Dict[str, int]:
     """Busy nanoseconds of ``[start, end)`` by scope, from ``ops`` sorted by
     start (the longer first of two that start together).  Each outermost event
     (one not inside another of the line: a ``while`` holds its body's
@@ -184,7 +192,7 @@ def _outermost_ns_by_scope(ops: Sequence[Event], op_starts: Sequence[int], start
     covered = start
     for _, s, d, path in ops[bisect.bisect_left(op_starts, start):bisect.bisect_left(op_starts, end)]:
         e = min(s + d, end)
-        scope = scope_of(path)
+        scope = scope_of(path, scopes)
         if s < covered and outermost and outermost[-1][0] == UNSCOPED:
             outermost[-1][0] = scope
         if e > covered:
@@ -197,57 +205,17 @@ def _outermost_ns_by_scope(ops: Sequence[Event], op_starts: Sequence[int], start
 
 
 # --------------------------------------------------------------------------
-# idle gaps by the program's spans
-# --------------------------------------------------------------------------
-def owner_segments(spans: Iterable[Tuple[str, int, int]]) -> List[Tuple[int, int, str]]:
-    """The host timeline cut at every span edge, each piece given to the
-    innermost span over it: the one that started last (the shorter of two that
-    start together).  Pieces under no span are left out."""
-    spans = sorted(spans, key=lambda sp: sp[1])
-    bounds = sorted({t for _, s, e in spans for t in (s, e)})
-    segments: List[Tuple[int, int, str]] = []
-    active: List[Tuple[str, int, int]] = []
-    nxt = 0
-    for a, b in zip(bounds, bounds[1:]):
-        while nxt < len(spans) and spans[nxt][1] <= a:
-            active.append(spans[nxt])
-            nxt += 1
-        active = [sp for sp in active if sp[2] > a]
-        if active:
-            owner = max(active, key=lambda sp: (sp[1], -sp[2]))
-            segments.append((a, b, owner[0]))
-    return segments
-
-
-def idle_by_span(gaps: Iterable[Interval], segments: Sequence[Tuple[int, int, str]]) -> Dict[str, int]:
-    """Nanoseconds of the gaps under each span's own pieces; the rest ``unattributed``."""
-    starts = [seg[0] for seg in segments]
-    out: Dict[str, int] = {}
-    for gs, ge in gaps:
-        left = ge - gs
-        for a, b, name in segments[max(0, bisect.bisect_right(starts, gs) - 1):]:
-            if a >= ge:
-                break
-            part = min(b, ge) - max(a, gs)
-            if part > 0:
-                out[name] = out.get(name, 0) + part
-                left -= part
-        if left > 0:
-            out[UNATTRIBUTED] = out.get(UNATTRIBUTED, 0) + left
-    return out
-
-
-# --------------------------------------------------------------------------
 # the reduction
 # --------------------------------------------------------------------------
-def reduce_spans(trace: Dict[str, Any]) -> Dict[str, Any]:
+def reduce_spans(trace: Dict[str, Any], executables: Mapping[str, str], scopes: Sequence[str]) -> Dict[str, Any]:
     """What the trace says under the program's names.
 
-    ``module_ms``: device-busy milliseconds per execution of each executable
-    of ``MODULES`` that ran, an execution at the trace's first or last
-    operation left out (the trace may have cut it).  ``scope_ms``: inside the
-    train step, milliseconds per run by scope (``SCOPES`` and ``unscoped``),
-    summing to its ``module_ms``; ``None`` where no operation names a scope.
+    ``module_ms``: device-busy milliseconds per execution of each of the
+    family's ``executables`` (role -> ``jit_<name>``) that ran, by role, an
+    execution at the trace's first or last operation left out (the trace may
+    have cut it).  ``scope_ms``: inside the train step, milliseconds per run
+    by scope (``scopes`` and ``unscoped``), summing to its ``module_ms``;
+    ``None`` where no operation names a scope.
     ``idle_ms``: the device's idle gaps (between the merged ``XLA Ops``
     intervals, as ``reduce_trace`` finds them) per iteration of the loop by
     the span the host was in, the rest ``unattributed``; iterations run from
@@ -255,8 +223,8 @@ def reduce_spans(trace: Dict[str, Any]) -> Dict[str, Any]:
     and the last start are left out; ``None`` with fewer than two such
     starts."""
     planes = [p for p in device_planes(trace) if _line(p, OPS_LINE)]
-    module_ns = {m: 0 for m in MODULES}
-    module_runs = {m: 0 for m in MODULES}
+    module_ns = {m: 0 for m in executables}
+    module_runs = {m: 0 for m in executables}
     scope_ns: Dict[str, int] = {}
     spans = program_spans(trace)
     rollouts = sorted(s for name, s, _ in spans if name == ITERATION_SPAN)
@@ -272,25 +240,25 @@ def reduce_spans(trace: Dict[str, Any]) -> Dict[str, Any]:
         for name, start, dur, _ in _line(plane, MODULES_LINE):
             if start <= merged[0][0] or start + dur >= merged[-1][1]:
                 continue  # at the trace's edge: it may have been cut there
-            for module in MODULES:
-                if _is_module(name, module):
+            for module, executable in executables.items():
+                if _is_execution(name, executable):
                     module_ns[module] += _overlap(merged, starts, start, start + dur)
                     module_runs[module] += 1
-                    if module == SCOPED_MODULE:
-                        for scope, ns in _outermost_ns_by_scope(ops, op_starts, start, start + dur).items():
+                    if module == SCOPED_EXECUTABLE:
+                        for scope, ns in _outermost_ns_by_scope(ops, op_starts, start, start + dur, scopes).items():
                             scope_ns[scope] = scope_ns.get(scope, 0) + ns
         if len(rollouts) >= 2:
             first, last = rollouts[0], rollouts[-1]
             gaps = [(max(a, first), min(b, last)) for a, b in zip((e for _, e in merged), starts[1:])]
             for name, ns in idle_by_span([g for g in gaps if g[1] > g[0]], segments).items():
                 idle_ns[name] = idle_ns.get(name, 0) + ns
-    runs = module_runs[SCOPED_MODULE]
-    scoped = any(scope_ns.get(scope) for scope in SCOPES)  # a program without the scopes has nothing to split
+    runs = module_runs.get(SCOPED_EXECUTABLE, 0)
+    scoped = any(scope_ns.get(scope) for scope in scopes)  # a program without the scopes has nothing to split
     iterations = (len(rollouts) - 1) * len(planes)
     return {
-        "module_ms": {m: module_ns[m] / module_runs[m] / 1e6 for m in MODULES if module_runs[m]},
+        "module_ms": {m: module_ns[m] / module_runs[m] / 1e6 for m in executables if module_runs[m]},
         "module_runs": {m: n for m, n in module_runs.items() if n},
-        "scope_ms": {s: scope_ns.get(s, 0) / runs / 1e6 for s in SCOPES + (UNSCOPED,)} if runs and scoped else None,
+        "scope_ms": {s: scope_ns.get(s, 0) / runs / 1e6 for s in tuple(scopes) + (UNSCOPED,)} if runs and scoped else None,
         "idle_ms": {k: v / iterations / 1e6 for k, v in idle_ns.items()} if iterations > 0 else None,
         "iterations": max(iterations, 0),
     }
@@ -300,17 +268,20 @@ def reduce_spans(trace: Dict[str, Any]) -> Dict[str, Any]:
 # what the readers under metrics/ call
 # --------------------------------------------------------------------------
 @functools.lru_cache(maxsize=1)
-def _reduced(xplane_path: str) -> Dict[str, Any]:
-    return reduce_spans(load_spans(xplane_path))
+def _reduced(xplane_path: str, executables: Tuple[Tuple[str, str], ...], scopes: Tuple[str, ...]) -> Dict[str, Any]:
+    return reduce_spans(load_spans(xplane_path), dict(executables), scopes)
 
 
 def for_run(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """The reduction of this run's trace, loaded once however many readers
-    ask; ``None`` for a run that was not traced or left no trace behind."""
-    if not run.get("trace"):
+    """The reduction of this run's trace under its family's names, loaded once
+    however many readers ask; ``None`` for a run that was not traced or left
+    no trace behind."""
+    family = run.get("family")
+    if not run.get("trace") or family is None:
         return None
     try:
-        return _reduced(find_xplane(os.path.join(command.WORK_DIR, run["cell"]["name"], "trace")))
+        path = find_xplane(os.path.join(command.WORK_DIR, run["cell"]["name"], "trace"))
+        return _reduced(path, tuple(family.executables.items()), tuple(family.train_step_scopes))
     except (OSError, KeyError, ValueError):
         return None
 

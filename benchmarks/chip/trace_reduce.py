@@ -9,11 +9,13 @@ The reduction works on a plain structure so that a test can build one by hand:
 but ``jax.profiler.ProfileData``.  Device planes are those named
 ``/device:TPU:<n>``; on each, the line ``XLA Ops`` holds one event per
 operation that ran and ``XLA Modules`` one per executable.  Host planes carry
-the harness's own ``bench/...`` annotations, on the same clock.
+the program's own ``sheeprl/<phase>`` spans (``Diagnostics.span``), on the
+same clock.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -22,7 +24,8 @@ Event = Tuple[str, int, int]
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-ANNOTATION_PREFIX = "bench/"
+ANNOTATION_PREFIX = "sheeprl/"
+UNATTRIBUTED = "unattributed"
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -75,13 +78,51 @@ def device_planes(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
 
 
 def annotations(trace: Dict[str, Any]) -> List[Event]:
-    """The harness's own host spans (``bench/...``), from every host plane."""
+    """The program's host spans (``sheeprl/...``), from every host plane."""
     out: List[Event] = []
     for plane in trace["planes"]:
         if plane["name"].startswith(DEVICE_PREFIX):
             continue
         for line in plane["lines"]:
             out.extend(ev for ev in line["events"] if ev[0].startswith(ANNOTATION_PREFIX))
+    return out
+
+
+def owner_segments(spans: Iterable[Tuple[str, int, int]]) -> List[Tuple[int, int, str]]:
+    """The host timeline cut at every edge of the ``(name, start, end)`` spans,
+    each piece given to the innermost span over it: the one that started last
+    (the shorter of two that start together).  Pieces under no span are left out."""
+    spans = sorted(spans, key=lambda sp: sp[1])
+    bounds = sorted({t for _, s, e in spans for t in (s, e)})
+    segments: List[Tuple[int, int, str]] = []
+    active: List[Tuple[str, int, int]] = []
+    nxt = 0
+    for a, b in zip(bounds, bounds[1:]):
+        while nxt < len(spans) and spans[nxt][1] <= a:
+            active.append(spans[nxt])
+            nxt += 1
+        active = [sp for sp in active if sp[2] > a]
+        if active:
+            owner = max(active, key=lambda sp: (sp[1], -sp[2]))
+            segments.append((a, b, owner[0]))
+    return segments
+
+
+def idle_by_span(gaps: Iterable[Tuple[int, int]], segments: Sequence[Tuple[int, int, str]]) -> Dict[str, int]:
+    """Nanoseconds of the gaps under each span's own pieces; the rest ``unattributed``."""
+    starts = [seg[0] for seg in segments]
+    out: Dict[str, int] = {}
+    for gs, ge in gaps:
+        left = ge - gs
+        for a, b, name in segments[max(0, bisect.bisect_right(starts, gs) - 1):]:
+            if a >= ge:
+                break
+            part = min(b, ge) - max(a, gs)
+            if part > 0:
+                out[name] = out.get(name, 0) + part
+                left -= part
+        if left > 0:
+            out[UNATTRIBUTED] = out.get(UNATTRIBUTED, 0) + left
     return out
 
 
@@ -97,8 +138,12 @@ def reduce_trace(trace: Dict[str, Any], module_match: str = "train_step", top: i
     """Busy and idle time of the device and the time of one executable.
 
     The span is what the device's own events cover, first start to last end,
-    averaged over the device planes.  Returns ``None`` values, never zeros,
-    for what the trace does not hold.
+    averaged over the device planes.  ``module_match`` names the executable
+    (the family's ``executables["train_step"]``); an execution of it at the
+    trace's first or last operation is left out, since the trace may have cut
+    it there.  The idle gaps go to the innermost of the program's spans the
+    host was in.  Returns ``None`` values, never zeros, for what the trace
+    does not hold.
     """
     planes = device_planes(trace)
     if not planes:
@@ -118,18 +163,14 @@ def reduce_trace(trace: Dict[str, Any], module_match: str = "train_step", top: i
             op_seconds[name] = op_seconds.get(name, 0.0) + dur / 1e9
         gaps.extend((merged[i][1], merged[i + 1][0]) for i in range(len(merged) - 1))
         for name, start, dur in _line(plane, MODULES_LINE):
-            if module_match in name:
+            if module_match in name and start > first and start + dur < last:
                 module_busy_s += _overlap(merged, start, start + dur) / 1e9
                 module_runs += 1
     if not busy_s:
         raise ValueError("no device plane of the trace has an 'XLA Ops' line with events")
     n = len(busy_s)
-    notes = annotations(trace)
-    idle: Dict[str, float] = {}
-    for start, end in gaps:
-        mid = (start + end) // 2
-        name = next((a[0] for a in notes if a[1] <= mid < a[1] + a[2]), "unattributed")
-        idle[name] = idle.get(name, 0.0) + (end - start) / 1e9
+    segments = owner_segments((name, start, start + dur) for name, start, dur in annotations(trace))
+    idle = {name: ns / 1e9 for name, ns in idle_by_span(gaps, segments).items()}
     longest = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
     return {
         "busy_s": sum(busy_s) / n,

@@ -1,9 +1,9 @@
 """Window arithmetic: the two end-to-end metrics from the env's own clock.
 
-``times`` are ``time.time()`` at every ``step`` call the env received, in
-order.  Both numbers are taken over the whole window ``[t0, t0 + seconds]``:
-a stall anywhere in it takes steps away from the rate and puts a long gap into
-the tail.
+``times`` are ``time.time()`` at every ``step`` call the envs received, in
+order (``steplog.py``).  Both numbers are taken over the whole window
+``[t0, t0 + seconds]``: a stall anywhere in it takes steps away from the rate
+and puts a long gap into the tail.
 """
 
 from __future__ import annotations
@@ -13,11 +13,13 @@ from typing import Dict
 import numpy as np
 
 
-def window_metrics(times: np.ndarray, t0: float, seconds: float) -> Dict[str, float]:
-    """``env_steps_per_s``: step calls inside the window over its seconds.
-    ``action_gap_p95_ms``: 95th percentile of the gaps between successive step
-    calls, over every gap that ends inside the window (so the gap that is open
-    when the window starts counts whole).  ``steps`` and ``gaps`` are counts;
+def window_metrics(times: np.ndarray, t0: float, seconds: float, env: np.ndarray) -> Dict[str, float]:
+    """``env_steps_per_s``: step calls inside the window over its seconds,
+    whichever env received them.  ``action_gap_p95_ms``: 95th percentile of
+    the gaps between successive step calls of one env (``env`` says which env
+    each stamp is from), over every gap that ends
+    inside the window (so the gap that is open when the window starts counts
+    whole), the envs' gaps pooled.  ``steps`` and ``gaps`` are counts;
     ``steps_by_10s`` counts the steps of each ten seconds of the window, which
     is where a person sees when a stall fell."""
     times = np.asarray(times, np.float64)
@@ -26,7 +28,8 @@ def window_metrics(times: np.ndarray, t0: float, seconds: float) -> Dict[str, fl
     t1 = t0 + seconds
     inside = (times >= t0) & (times <= t1)
     steps = int(inside.sum())
-    gaps = np.diff(times)[inside[1:]]
+    env = np.asarray(env)
+    gaps = np.concatenate([np.diff(times[env == e])[inside[env == e][1:]] for e in np.unique(env)] or [np.zeros(0)])
     if steps < 2 or gaps.size < 1:
         raise ValueError(f"only {steps} env steps fell inside the window: nothing to measure")
     return {

@@ -5,9 +5,8 @@ statistics are what the parameters ask for."""
 import numpy as np
 import pytest
 
-from benchmarks.chip.envs import (
-    BenchEnv, EpisodeSchedule, frame_bank, frame_index, frame_of, read_step_log, reward_of, reward_table,
-)
+from benchmarks.chip.envs import BenchEnv, EpisodeSchedule, frame_bank, frame_index, frame_of, reward_of, reward_table
+from benchmarks.chip.steplog import read_step_log
 
 
 def _roll(seed, steps, **params):
@@ -82,7 +81,7 @@ def test_the_step_log_keeps_the_clients_clock(tmp_path):
     log = read_step_log(path)
     assert len(log["times"]) == 10 and np.all(np.diff(log["times"]) >= 0)
     assert list(log["actions"]) == [i % 4 for i in range(10)]
-    assert np.all(np.diff(log["frames_at"]) >= 1)
+    assert np.all(np.diff(log["marks"]) >= 1) and set(log["env"]) == {0}
 
 
 def test_spaces():

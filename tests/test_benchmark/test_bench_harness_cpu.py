@@ -2,7 +2,7 @@
 tiny size on the CPU, bounded by the watcher, ended by SIGTERM, compared with
 the reference.  Sound, it comes out correct; with the timed path broken
 underneath (a step that returns its state unchanged, half of the batch left
-out; ``faults.py``) ``correct`` comes out false, and so it does with the
+out; the family's ``faults``) ``correct`` comes out false, and so it does with the
 control, the program's own path in the nearest lower precision, in the
 cell's place: all at a size a test run can hold."""
 
@@ -14,7 +14,6 @@ import time
 
 import pytest
 
-from benchmarks.chip.faults import FAULTS
 from benchmarks.chip.manifest import ROOT, Manifest
 
 BENCH = os.path.join(ROOT, "benchmarks", "chip")
@@ -59,11 +58,11 @@ def tiny(tmp_path_factory):
     return Manifest(str(root)), str(root / "runs")
 
 
-def _run(tiny, break_step=None, seed=2_200_000_123, precision=None):
+def _run(tiny, fault=None, seed=2_200_000_123, precision=None):
     from benchmarks.chip.harness import run_cell
 
     manifest, work_dir = tiny
-    return run_cell(manifest, "tiny.cpu", seed, 4.0, False, time.time(), work_dir, break_step=break_step, precision=precision)
+    return run_cell(manifest, "tiny.cpu", seed, 4.0, False, time.time(), work_dir, fault=fault, precision=precision)
 
 
 # what only a chip gives: a compile-free window is the chip cells' own check,
@@ -89,7 +88,7 @@ def test_a_sound_run_is_correct(tiny):
     ("half_batch", {"loss_gap.world_model", "grad_gap.world_model", "grad_gap.actor"}),
 ])
 def test_a_broken_step_is_not_correct(tiny, fault, caught_by):
-    result = _run(tiny, break_step=FAULTS[fault])
+    result = _run(tiny, fault=fault)
     assert result["correct"] is False
     assert caught_by & _failed(result), result["checks"]
     # the replay path is whole in both: the fault is the step's

@@ -9,7 +9,7 @@ import shutil
 import pytest
 
 from benchmarks.chip.harness import assemble_result, compose_overrides
-from benchmarks.chip.manifest import ROOT, Manifest, ManifestError, check_names
+from benchmarks.chip.manifest import FAMILY_ANSWERS, ROOT, Manifest, ManifestError, check_names
 
 BENCH = os.path.join(ROOT, "benchmarks", "chip")
 
@@ -34,7 +34,9 @@ def grown(tmp_path):
     data["per_layer"].append({"name": "loop.steps_per_gradient_step", "unit": "steps", "better": "lower", "source": "program_counter",
                               "layer": "loop", "moves": "env_steps_per_s", "workloads": ["dv3_new.slow_env"]})
     (root / "BENCHMARK.json").write_text(json.dumps(data))
-    return Manifest(str(root))
+    manifest = Manifest(str(root))
+    manifest.root_path = root
+    return manifest
 
 
 def _run(trace):
@@ -56,10 +58,11 @@ def _run(trace):
 def test_new_files_are_found_by_name(grown):
     cell = grown.workload("dv3_new.slow_env")
     config = grown.config(cell["config"])
-    run = {**_run(True), "config": config}
+    run = {**_run(True), "config": config, "family": grown.family(config)}
     assert cell["env"]["step_ms"] == 9.0 and "algo.horizon=7" in config["overrides"]
-    overrides = compose_overrides(config, cell, 5, "/tmp/log.npz")
+    overrides = compose_overrides(grown.family(config), config, cell, 5, "/tmp/log.npz")
     assert "env.wrapper.step_ms=9.0" in overrides and "algo.horizon=7" in overrides and "seed=5" in overrides
+    assert "env.wrapper.log_path=/tmp/log.npz" in overrides and sum(o.startswith("env=") for o in overrides) == 1
     result = assemble_result(grown, "dv3_new.slow_env", run, {}, trace=True)
     assert result["metrics"]["loop.steps_per_gradient_step"] == {"value": 2.0, "unit": "steps"}
     # the cell that was there does not report the new cell's metric
@@ -72,6 +75,13 @@ def test_unknown_names_are_errors(grown):
         grown.workload("no_such.cell")
     with pytest.raises(ManifestError):
         grown.reader("no.such.metric")
+    with pytest.raises(ManifestError, match="families/no_such_family.py"):
+        grown.family({**grown.config("dv3_new"), "family": "no_such_family"})
+    with pytest.raises(ManifestError, match="names no family"):
+        grown.family({"name": "dv3_new"})
+    (grown.root_path / "benchmarks" / "chip" / "families" / "half.py").write_text("def install(seed, recorder):\n    pass\n")
+    with pytest.raises(ManifestError, match="does not answer"):
+        grown.family({"name": "dv3_new", "family": "half"})
 
 
 def test_the_repos_benchmark_keeps_to_the_naming_rules():
@@ -88,15 +98,47 @@ def test_the_repos_benchmark_keeps_to_the_naming_rules():
     for m in data["per_layer"]:
         assert m["moves"] in e2e and callable(manifest.reader(m["name"]))
         layers.add(m["layer"])
-    assert layers <= {"entry", "loop", "train step", "device"}
+    assert layers <= {"entry", "loop", "train step", "kernels", "device"}
     for cell in data["workloads"]:
         merged = manifest.workload(cell["name"])
         assert merged["chips"] in (1, 4) and len(cell["why"]) <= 200 and "limits" in merged
         config = manifest.config(cell["config"])
-        assert {"source", "overrides", "reduced", "assumed", "shapes", "hyper"} <= set(config)
+        assert {"source", "family", "reference", "overrides", "reduced", "assumed", "shapes", "hyper"} <= set(config)
     for config in data["configs"]:
         assert config["file"].startswith(tuple(data["paths"]))
         assert config["reduced"] == manifest.config(config["name"])["reduced"]
+
+
+def test_every_configuration_names_a_family_whose_file_answers_for_it():
+    """The whole of what a family brings: a file found by the name in the configuration's file."""
+    manifest = Manifest(ROOT)
+    for entry in manifest.data["configs"]:
+        config = manifest.config(entry["name"])
+        assert os.path.isfile(os.path.join(BENCH, "families", config["family"] + ".py"))
+        assert os.path.isfile(config["reference"]) and config["reference"].startswith(BENCH)
+        family = manifest.family(config)
+        assert all(hasattr(family, answer) for answer in FAMILY_ANSWERS)
+        assert all(callable(getattr(family, name)) for name in ("install", "split_step", "compare", "train_step_flops", "env_overrides"))
+        assert isinstance(family.env_group, str) and os.path.isfile(os.path.join(BENCH, "hydra", "env", family.env_group + ".yaml"))
+        assert "train_step" in family.executables and family.train_step_flops(config)["total"] > 0
+        # the faults its comparison has to catch, and the scopes its step is split by (none is an answer too)
+        assert family.faults and all(callable(break_step) for break_step in family.faults.values())
+        assert isinstance(family.train_step_scopes, tuple) and all(isinstance(s, str) for s in family.train_step_scopes)
+    # a per-layer metric with no ``workloads`` key is asked of every cell, of whatever family
+    for metric in manifest.data["per_layer"]:
+        if "workloads" not in metric:
+            assert metric["layer"] in ("entry", "train step", "device"), metric["name"]
+
+
+SEAM_FILES = ["harness.py", "check.py", "run.py", "trace_reduce.py", "span_reduce.py"]
+
+
+@pytest.mark.parametrize("path", SEAM_FILES + sorted("metrics/" + f for f in os.listdir(os.path.join(BENCH, "metrics")) if f.endswith(".py")))
+def test_the_harness_names_no_algorithm(path):
+    """What knows which algorithm runs sits in ``families/``: ISSUE 29's grep, a file a case."""
+    text = open(os.path.join(BENCH, path)).read()
+    for word in ("dreamer", "PlayerDV3", "jit_train_step", "chipbench"):
+        assert word not in text, f"{path} names {word!r}"
 
 
 @pytest.mark.parametrize("entry,complaint", [

@@ -9,7 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.chip import reference as ref
-from benchmarks.chip.check import replay_mismatches, step_gaps, worst_leaf_gap
+from benchmarks.chip.check import step_gaps, worst_leaf_gap
+from benchmarks.chip.families.dreamer_v3 import MODULES, faults as FAULTS, replay_mismatches
 from benchmarks.chip.envs import BenchEnv
 from benchmarks.chip.weights import make_weights
 
@@ -148,7 +149,7 @@ def test_weights_come_from_the_seed(traffic):
 
 def test_the_reference_repeats_itself_and_moves_every_leaf(traffic, sound):
     again = _steps(traffic)
-    gaps = step_gaps(again, sound, traffic[0])
+    gaps = step_gaps(again, sound, traffic[0], MODULES)
     assert max(gaps.values()) == 0.0
     assert all(np.isfinite(loss).all() for loss in sound["losses"])
     for module in ("world_model", "actor", "critic"):
@@ -158,7 +159,7 @@ def test_the_reference_repeats_itself_and_moves_every_leaf(traffic, sound):
 
 
 def test_the_reference_in_a_lower_precision_reads_far_off(traffic, sound):
-    gaps = step_gaps(_steps(traffic, quant="bfloat16"), sound, traffic[0])
+    gaps = step_gaps(_steps(traffic, quant="bfloat16"), sound, traffic[0], MODULES)
     # the reference against itself reads 0.0 on every number (the test above)
     assert max(gaps[f"{kind}_gap.{module}"] for kind in ("grad", "change") for module in ("world_model", "actor", "critic")) > 0.05, gaps
 
@@ -170,8 +171,6 @@ def _toy_step(params, opt_states, moments_state, batch, key, tau):
 
 
 def test_the_half_batch_fault_keeps_the_shapes_and_drops_the_second_half():
-    from benchmarks.chip.faults import FAULTS
-
     x = jnp.arange(24, dtype=jnp.float32).reshape(3, 4, 2)  # [T, B, ...]: rows 2 and 3 of every step are left out
     state = ({"w": jnp.zeros(())}, {"count": jnp.zeros((), jnp.int32)}, {"low": jnp.zeros(())})
     seen = {}
@@ -186,8 +185,6 @@ def test_the_half_batch_fault_keeps_the_shapes_and_drops_the_second_half():
 
 
 def test_the_unchanged_fault_does_the_work_and_returns_the_state_it_got():
-    from benchmarks.chip.faults import FAULTS
-
     state = ({"w": jnp.ones(())}, {"count": jnp.zeros((), jnp.int32)}, {"low": jnp.zeros(())})
     out = FAULTS["unchanged"](_toy_step)(*state, {"x": jnp.full((2, 2, 1), 3.0)}, None, 1.0)
     assert out[0] is state[0] and out[1] is state[1] and out[2] is state[2]
@@ -214,7 +211,7 @@ def test_the_replay_check_catches_a_row_that_is_not_the_envs():
         rows.append(row)
         obs, last_reward, done, _, _ = env.step(action)
         first, final = 0.0, float(done)
-    log = {"times": env._times[: env._n], "actions": env._actions[: env._n], "frames_at": env._frames_at[: env._n]}
+    log = {"times": env.log.times[: env.log.n], "actions": env.log.actions[: env.log.n], "marks": env.log.marks[: env.log.n]}
     batch = {k: np.stack([r[k] for r in rows[:16]]).reshape((8, 2) + rows[0][k].shape, order="F") for k in rows[0]}
     clean = replay_mismatches([{"batch": batch}], log, env_params, seed)
     assert clean == {"replay_frame_mismatches": 0, "replay_order_breaks": 0, "replay_label_mismatches": 0}

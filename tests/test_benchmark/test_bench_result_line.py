@@ -32,11 +32,22 @@ def test_end_to_end_line(manifest):
     json.dumps(result)
 
 
+# the readers that need no trace file and no scrape: what a hand-built run can feed
+NO_TRACE_FILE = {"entry.first_train_step_s", "entry.compile_s", "loop.rollout_host_ms", "loop.train_host_ms",
+                 "train_step.device_ms", "train_step.mfu_pct", "device.idle_pct", "device.hbm_peak_gb"}
+
+
+def _traced_run(manifest):
+    config = manifest.config("dv3_s")
+    return {**_run(True), "config": config, "family": manifest.family(config)}
+
+
 def test_traced_line(manifest):
-    run = {**_run(True), "config": manifest.config("dv3_s")}
+    run = _traced_run(manifest)
     result = assemble_result(manifest, CELL, run, CHECKS, trace=True)
     names = {m["name"] for m in manifest.metrics_for(CELL, "per_layer")}
-    assert set(result["metrics"]) == names and len(names) == 8
+    assert len(names) == 26 and NO_TRACE_FILE <= names
+    assert set(result["metrics"]) == NO_TRACE_FILE  # the other 18 find nothing to read and are left out
     assert {"busy_s", "window_s"} <= set(result["device"]) and result["device"]["busy_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
     units = {m["name"]: m["unit"] for m in manifest.data["per_layer"]}
@@ -49,7 +60,7 @@ def test_traced_line(manifest):
 
 
 def test_a_reader_that_finds_nothing_leaves_its_metric_out(manifest):
-    run = {**_run(True), "config": manifest.config("dv3_s")}
+    run = _traced_run(manifest)
     run["trace"] = {**run["trace"], "module_device_ms": None}
     result = assemble_result(manifest, CELL, run, CHECKS, trace=True)
     assert "train_step.device_ms" not in result["metrics"] and "device.idle_pct" in result["metrics"]

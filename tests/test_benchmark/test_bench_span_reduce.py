@@ -12,11 +12,16 @@ import pytest
 
 from benchmarks.chip import span_reduce
 from benchmarks.chip.manifest import ROOT, Manifest, check_names
-from benchmarks.chip.span_reduce import FETCH_SPAN, UNATTRIBUTED, idle_by_span, owner_segments, reduce_spans, scope_of
+from benchmarks.chip.span_reduce import FETCH_SPAN, reduce_spans, scope_of
+from benchmarks.chip.trace_reduce import UNATTRIBUTED, idle_by_span, owner_segments
 from test_bench_manifest import _run
 
 MS = 1_000_000
-ENTRIES = json.load(open(os.path.join(ROOT, "benchmarks", "chip", "span_metrics.json")))["per_layer"]
+# the per-layer entries whose readers go through this reduction (PR 27's 18)
+ENTRIES = [m for m in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["per_layer"]
+           if "span_reduce" in open(os.path.join(ROOT, "benchmarks", "chip", "metrics", m["name"] + ".py")).read()]
+FAMILY = Manifest(ROOT).family(Manifest(ROOT).config("dv3_s"))
+EXECUTABLES, SCOPES = FAMILY.executables, FAMILY.train_step_scopes
 
 
 def _iteration(o):
@@ -67,10 +72,10 @@ def _trace(devices=1, spans=True):
 
 @pytest.mark.parametrize("devices", [1, 4])
 def test_executables_scopes_and_idle_classes(devices):
-    out = reduce_spans(_trace(devices))
+    out = reduce_spans(_trace(devices), EXECUTABLES, SCOPES)
     # the gather the trace's end cut is left out: (20 + 20 + 10) / 3 would read 16.7
-    assert out["module_ms"] == pytest.approx({"replay_gather": 20.0, "train_step": 29.0, "player_step": 2.0, "replay_add": 1.0})
-    assert out["module_runs"] == {"replay_gather": 2 * devices, "train_step": 2 * devices, "player_step": 2 * devices, "replay_add": 2 * devices}
+    assert out["module_ms"] == pytest.approx({"replay_gather": 20.0, "train_step": 29.0, "player": 2.0, "replay_add": 1.0})
+    assert out["module_runs"] == {"replay_gather": 2 * devices, "train_step": 2 * devices, "player": 2 * devices, "replay_add": 2 * devices}
     assert out["scope_ms"] == pytest.approx({"encoder": 4.0, "rssm_scan": 12.0, "decoder_heads": 5.0, "imagination": 0.0,
                                              "behaviour_losses": 0.0, "optim": 4.0, "unscoped": 4.0})
     assert sum(out["scope_ms"].values()) == pytest.approx(out["module_ms"]["train_step"])
@@ -84,26 +89,38 @@ def test_executables_scopes_and_idle_classes(devices):
 
 
 def test_a_trace_without_the_programs_names_reads_nothing():
-    out = reduce_spans(_trace(spans=False))
+    out = reduce_spans(_trace(spans=False), EXECUTABLES, SCOPES)
     assert out["idle_ms"] is None and out["iterations"] == 0 and out["module_ms"]["train_step"] == pytest.approx(29.0)
     bare = _trace()
     for plane in bare["planes"]:
         for line in plane["lines"]:
             line["events"] = [(n.replace("replay_gather", "_gather_all").replace("player_step", "_step"), s, d,
                                "jit(train_step)/jvp(WorldModel.encode)/cnn_encoder/conv" if p else "") for n, s, d, p in line["events"]]
-    out = reduce_spans(bare)  # the parent's trace: paths, and no scope or executable of these names
-    assert out["scope_ms"] is None and "replay_gather" not in out["module_ms"] and "player_step" not in out["module_ms"]
-    assert reduce_spans({"planes": []}) == {"module_ms": {}, "module_runs": {}, "scope_ms": None, "idle_ms": None, "iterations": 0}
+    out = reduce_spans(bare, EXECUTABLES, SCOPES)  # the parent's trace: paths, and no scope or executable of these names
+    assert out["scope_ms"] is None and "replay_gather" not in out["module_ms"] and "player" not in out["module_ms"]
+    assert reduce_spans({"planes": []}, EXECUTABLES, SCOPES) == {"module_ms": {}, "module_runs": {}, "scope_ms": None, "idle_ms": None, "iterations": 0}
 
 
 def test_a_scope_is_a_component_of_the_path_forward_or_backward():
-    assert scope_of("jit(train_step)/transpose(jvp(rssm_scan))/while/body/mul") == "rssm_scan"
-    assert scope_of("jit(train_step)/jvp(encoder)/WorldModel.encode/cnn_encoder/conv") == "encoder"
-    assert scope_of("jit(train_step)/jvp(decoder_heads)/WorldModel.decode/cnn_decoder/conv") == "decoder_heads"
-    assert scope_of("jit(train_step)/optim/sqrt") == "optim"
-    assert scope_of("jit(train_step)/jvp()/WorldModel.encode/cnn_encoder/conv") == "unscoped"
-    assert scope_of("jit(train_step)/jvp(imagination)/while/body/optimizer_like/x") == "imagination"
-    assert scope_of("") == "unscoped"
+    assert scope_of("jit(train_step)/transpose(jvp(rssm_scan))/while/body/mul", SCOPES) == "rssm_scan"
+    assert scope_of("jit(train_step)/jvp(encoder)/WorldModel.encode/cnn_encoder/conv", SCOPES) == "encoder"
+    assert scope_of("jit(train_step)/jvp(decoder_heads)/WorldModel.decode/cnn_decoder/conv", SCOPES) == "decoder_heads"
+    assert scope_of("jit(train_step)/optim/sqrt", SCOPES) == "optim"
+    assert scope_of("jit(train_step)/jvp()/WorldModel.encode/cnn_encoder/conv", SCOPES) == "unscoped"
+    assert scope_of("jit(train_step)/jvp(imagination)/while/body/optimizer_like/x", SCOPES) == "imagination"
+    assert scope_of("", SCOPES) == "unscoped"
+    # a family that names other scopes, or none, splits by those
+    assert scope_of("jit(train_step)/jvp(delta_rule)/while/body/mul", ("attention", "delta_rule")) == "delta_rule"
+    assert scope_of("jit(train_step)/jvp(encoder)/conv", ()) == "unscoped"
+
+
+def test_the_reducers_own_scopes_are_a_leftover_that_says_what_the_family_says():
+    """``span_reduce.SCOPES`` and the one-argument ``scope_of`` stay for one test outside the
+    benchmark's directories; until it is repointed they must not drift from the family's answer."""
+    assert span_reduce.SCOPES == SCOPES
+    assert scope_of("jit(train_step)/optim/sqrt") == scope_of("jit(train_step)/optim/sqrt", SCOPES) == "optim"
+    with pytest.raises(TypeError):
+        reduce_spans({"planes": []}, EXECUTABLES)  # the reduction itself takes its scopes from the caller
 
 
 def test_the_innermost_span_owns_its_time():
@@ -117,14 +134,13 @@ def test_the_innermost_span_owns_its_time():
 # --------------------------------------------------------------------------
 # the readers
 # --------------------------------------------------------------------------
-def test_the_entries_kept_for_the_manifest_each_have_a_reader():
+def test_the_entries_of_this_reduction_each_have_a_reader():
     manifest = Manifest(ROOT)
     assert len(ENTRIES) == 18 and check_names({"per_layer": ENTRIES}) == []
-    taken = {m["name"] for m in manifest.data["per_layer"]}
     e2e = {m["name"] for m in manifest.data["end_to_end"]}
     for entry in ENTRIES:
         assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert entry["name"] not in taken and entry["moves"] in e2e and entry["better"] == "lower"
+        assert entry["moves"] in e2e and entry["better"] == "lower"
         assert entry["workloads"] == ["dv3_s.hbm_replay"] and entry["source"] in ("program_counter", "device_trace")
         assert callable(manifest.reader(entry["name"]))
 
@@ -138,7 +154,7 @@ def test_a_reader_reads_none_where_there_is_nothing_to_read(entry):
 
 
 def test_the_readers_find_their_numbers(monkeypatch):
-    reduced = reduce_spans(_trace())
+    reduced = reduce_spans(_trace(), EXECUTABLES, SCOPES)
     monkeypatch.setattr(span_reduce, "for_run", lambda run: reduced)
     phase = 'sheeprl_phase_seconds_total{phase="%s"}'
     calls = 'sheeprl_instrumented_calls_total{fn="train_step"}'
@@ -170,7 +186,8 @@ def test_a_run_is_reduced_from_where_the_command_keeps_its_trace(monkeypatch, tm
     loads = []
     monkeypatch.setattr(span_reduce, "load_spans", lambda path: loads.append(path) or _trace())
     span_reduce._reduced.cache_clear()
-    run = {**_run(True), "cell": {"name": "some.cell"}}
+    run = {**_run(True), "cell": {"name": "some.cell"}, "family": FAMILY}
+    assert span_reduce.module_ms({**run, "family": None}, "replay_gather") is None  # no family, no names to look for
     assert span_reduce.module_ms(run, "replay_gather") == pytest.approx(20.0)
     assert span_reduce.scope_ms(run, "rssm_scan") == pytest.approx(12.0)
     assert span_reduce.idle_ms(run)[FETCH_SPAN] == pytest.approx(3.0)

@@ -1,5 +1,6 @@
 """The trace reduction on a small hand-built trace: overlapping operations, a
-gap, two executables, a second device, and the harness's own host spans."""
+gap, two executables, a second device, the program's host spans (one inside
+another), and an execution that the trace's edge cut."""
 
 import pytest
 
@@ -9,7 +10,8 @@ MS = 1_000_000
 
 
 def _trace(devices=1):
-    ops = [("fusion.1", 0, 4 * MS), ("fusion.2", 2 * MS, 4 * MS), ("copy.3", 10 * MS, 2 * MS), ("fusion.1", 12 * MS, 8 * MS)]
+    ops = [("reshape.0", -1 * MS, 1 * MS), ("fusion.1", 0, 4 * MS), ("fusion.2", 2 * MS, 4 * MS), ("copy.3", 10 * MS, 2 * MS),
+           ("fusion.1", 12 * MS, 8 * MS), ("reshape.0", 20 * MS, 1 * MS)]
     modules = [("jit_train_step(123)", 0, 7 * MS), ("jit__gather_all(9)", 10 * MS, 2 * MS), ("jit_train_step(123)", 12 * MS, 8 * MS)]
     planes = [
         {"name": f"/device:TPU:{i}", "lines": [{"name": "XLA Ops", "events": ops}, {"name": "XLA Modules", "events": modules},
@@ -17,7 +19,7 @@ def _trace(devices=1):
         for i in range(devices)
     ]
     planes.append({"name": "/host:CPU", "lines": [{"name": "python", "events": [
-        ("bench/train_dispatch", 5 * MS, 4 * MS), ("something_else", 0, 20 * MS)]}]})
+        ("sheeprl/rollout", 0, 20 * MS), ("sheeprl/rollout/action-fetch", 5 * MS, 4 * MS), ("something_else", 0, 20 * MS)]}]})
     return {"planes": planes}
 
 
@@ -28,14 +30,27 @@ def test_merge_intervals():
 @pytest.mark.parametrize("devices", [1, 4])
 def test_busy_idle_and_the_executable(devices):
     out = reduce_trace(_trace(devices))
-    assert out["window_s"] == pytest.approx(0.020)
-    assert out["busy_s"] == pytest.approx(0.016)  # 0-6 overlapping, 10-20; the gap is 6-10
-    assert out["idle_pct"] == pytest.approx(20.0)
+    assert out["window_s"] == pytest.approx(0.022)
+    assert out["busy_s"] == pytest.approx(0.018)  # -1-6 overlapping, 10-21; the gap is 6-10
+    assert out["idle_pct"] == pytest.approx(100 * 4 / 22)
     assert out["module_runs"] == 2 * devices
     assert out["module_device_ms"] == pytest.approx((6 + 8) / 2)  # busy inside the two train_step spans
     assert out["device_ops"][0] == ["fusion.1", pytest.approx(0.012)]
-    assert out["idle_by_span"] == [["bench/train_dispatch", pytest.approx(0.004)]]
+    # the gap goes to the innermost span over each piece of it
+    assert out["idle_by_span"] == [["sheeprl/rollout/action-fetch", pytest.approx(0.003)], ["sheeprl/rollout", pytest.approx(0.001)]]
     assert out["longest_gap_ms"][0] == pytest.approx(4.0)
+
+
+def test_an_execution_at_the_traces_edge_is_left_out():
+    """The trace may have cut it there: half a train step read as a whole one."""
+    trace = _trace()
+    for line in trace["planes"][0]["lines"]:
+        if line["name"] == "XLA Ops":
+            line["events"] = line["events"][1:]  # the trace now starts inside the first train step
+    out = reduce_trace(trace)
+    assert out["module_runs"] == 1 and out["module_device_ms"] == pytest.approx(8.0)
+    out = reduce_trace(trace, module_match="jit__gather_all")
+    assert out["module_runs"] == 1 and out["module_device_ms"] == pytest.approx(2.0)
 
 
 def test_an_unattributed_gap_is_listed_as_such():

@@ -4,7 +4,12 @@ taken over the whole window, so a stall anywhere in it must move both."""
 import numpy as np
 import pytest
 
-from benchmarks.chip.window import window_metrics
+from benchmarks.chip import window
+
+
+def window_metrics(times, t0, seconds):
+    """The window of one env: every stamp is its own."""
+    return window.window_metrics(times, t0, seconds, env=np.zeros(len(times), np.int64))
 
 
 def _steady(start, stop, period):
