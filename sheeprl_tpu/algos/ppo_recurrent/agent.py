@@ -200,6 +200,47 @@ class RecurrentPPOAgent(nn.Module):
         return self.critic(out)
 
 
+LSTM, OLMO_HYBRID = "lstm", "olmo_hybrid"
+
+
+def backbone_of(cfg) -> str:
+    """``algo.backbone``: ``lstm`` (the preset's ``algo.rnn``) or ``olmo_hybrid`` (``algo.olmo_hybrid``)."""
+    return str(cfg.algo.get("backbone", LSTM) or LSTM)
+
+
+def token_key(cfg) -> str:
+    """The one observation key a token policy reads."""
+    return list(cfg.algo.mlp_keys.encoder)[0]
+
+
+def build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state=None):
+    """The hybrid language model as the policy: the observation is a token id,
+    the action the next one, both over the ids this chip holds."""
+    from sheeprl_tpu.models.hybrid_lm import HybridConfig, HybridLM
+    from sheeprl_tpu.parallel.precision import compute_dtype_of
+
+    config = HybridConfig.from_cfg(cfg.algo.olmo_hybrid)
+    key = token_key(cfg)
+    space = obs_space[key]
+    if is_continuous or len(actions_dim) != 1 or not isinstance(space, gymnasium.spaces.Discrete):
+        raise ValueError(
+            f"algo.backbone={OLMO_HYBRID} needs one Discrete observation ({key!r}) and one Discrete action, "
+            f"got observation {space} and actions {tuple(actions_dim)}"
+        )
+    if int(space.n) != config.vocab_held or int(actions_dim[0]) != config.vocab_held:
+        raise ValueError(
+            f"the env speaks {int(space.n)} ids and takes {int(actions_dim[0])}; "
+            f"algo.olmo_hybrid.vocab_held is {config.vocab_held}"
+        )
+    agent = HybridLM(config, dtype=compute_dtype_of(cfg))
+    sample = jnp.zeros((1, 1), jnp.int32)
+    if agent_state is not None:
+        params = jax.tree_util.tree_map(jnp.asarray, agent_state)
+    else:
+        params = jax.jit(lambda k: agent.init(k, sample, sample, agent.init_state(1)))(jax.random.PRNGKey(int(cfg.seed or 0)))
+    return agent, params, {key: sample}
+
+
 def build_agent(
     runtime,
     actions_dim: Sequence[int],
@@ -210,6 +251,8 @@ def build_agent(
 ):
     cnn_keys = list(cfg.algo.cnn_keys.encoder)
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
+    if backbone_of(cfg) == OLMO_HYBRID:
+        return build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state)
     agent = RecurrentPPOAgent(
         actions_dim=tuple(int(a) for a in actions_dim),
         is_continuous=is_continuous,

@@ -9,6 +9,16 @@ fixed-length formulation: the rollout ``[T, N]`` is cut into sequences of
 requires at :226), each sequence starts from its stored LSTM state, and the
 `reset_recurrent_state_on_done` semantics are preserved by in-graph masked
 state resets at done steps.  No padding, no masks, one `lax.scan` per BPTT.
+
+Two backbones (``algo.backbone``).  ``lstm`` is the reference's: its ``hx``,
+``cx`` are a row per env, stored with every step.  ``olmo_hybrid`` is a hybrid
+language model as a token-action policy (``models/hybrid_lm.py``): its carried
+state (the linear layers' state and convolution tails, the full layers' keys
+and values of the running episode) is a pytree that stays on the device
+through the rollout, is donated to ``policy_step``, is copied once where a
+training sequence starts (the learner's constant, as ``hx0``/``cx0`` are) and
+never reaches the host; the rollout's log-probabilities and values are kept
+beside it and fetched once a rollout; resets are in-graph in both forms.
 """
 
 from __future__ import annotations
@@ -24,7 +34,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
-from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent
+from sheeprl_tpu.algos.ppo_recurrent.agent import OLMO_HYBRID, backbone_of, build_agent, token_key
 from sheeprl_tpu.algos.ppo_recurrent.utils import (  # noqa: F401
     AGGREGATOR_KEYS,
     MODELS_TO_REGISTER,
@@ -33,6 +43,7 @@ from sheeprl_tpu.algos.ppo_recurrent.utils import (  # noqa: F401
 )
 from sheeprl_tpu.config import instantiate
 from sheeprl_tpu.data.slab import step_slab
+from sheeprl_tpu.models.hybrid_lm import carry_bytes
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.envs.env import make_env, make_env_fns, pipelined_vector_env
 from sheeprl_tpu.ops.numerics import gae
@@ -51,17 +62,39 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch
     distributed = world > 1
     cdt = compute_dtype_of(cfg)
     obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
+    token_policy = backbone_of(cfg) == OLMO_HYBRID
+
+    def evaluate_tokens(params, batch):
+        """The sequence forward of the token policy from each sequence's
+        snapshot; leaves are time-major ``[L, S, 1]``, the model's batch-major."""
+        tokens = batch[token_key(cfg)][..., 0].T.astype(jnp.int32)
+        resets = batch["resets"][..., 0].T.astype(jnp.int32)
+        state0 = jax.tree_util.tree_map(lambda x: x[0], batch["state0"])
+        logits, values, _ = agent.apply(cast_floating(params, cdt), tokens, resets, state0)
+        with jax.named_scope("ppo_loss"):
+            logp_all = jax.nn.log_softmax(logits, axis=-1)
+            actions = batch["actions"][..., 0].T.astype(jnp.int32)
+            logprobs = jnp.take_along_axis(logp_all, actions[..., None], axis=-1)
+            entropy = -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1, keepdims=True)
+        return logprobs.swapaxes(0, 1), entropy.swapaxes(0, 1), values.T[..., None]
 
     def loss_fn(params, batch, clip_coef, ent_coef, vf_coef):
-        _, new_logprobs, entropy, new_values, _ = agent.apply(
-            cast_floating(params, cdt),
-            cast_floating({k: batch[k] for k in obs_keys}, cdt),
-            cast_floating(batch["prev_actions"], cdt),
-            cast_floating(batch["hx0"][0], cdt),
-            cast_floating(batch["cx0"][0], cdt),
-            resets=batch["resets"],
-            actions=batch["actions"],
-        )
+        if token_policy:
+            new_logprobs, entropy, new_values = evaluate_tokens(params, batch)
+        else:
+            _, new_logprobs, entropy, new_values, _ = agent.apply(
+                cast_floating(params, cdt),
+                cast_floating({k: batch[k] for k in obs_keys}, cdt),
+                cast_floating(batch["prev_actions"], cdt),
+                cast_floating(batch["hx0"][0], cdt),
+                cast_floating(batch["cx0"][0], cdt),
+                resets=batch["resets"],
+                actions=batch["actions"],
+            )
+        with jax.named_scope("ppo_loss"):
+            return ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef)
+
+    def ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef):
         new_values = new_values.astype(jnp.float32)
         advantages = batch["advantages"]
         if cfg.algo.normalize_advantages:
@@ -92,15 +125,20 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch
                 if distributed:
                     grads = jax.lax.pmean(grads, "data")
                     aux = jax.lax.pmean(aux, "data")
-                updates, opt_state = optimizer.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                return (params, opt_state), jnp.stack(aux)
+                with jax.named_scope("optim"):
+                    grad_norms = jnp.stack([jnp.linalg.norm(g.astype(jnp.float32)) for g in jax.tree_util.tree_leaves(grads)])
+                    updates, opt_state = optimizer.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+                return (params, opt_state), (jnp.stack(aux), grad_norms)
 
             return jax.lax.scan(mb_body, (params, opt_state), idxs)
 
         keys = jax.random.split(key, cfg.algo.update_epochs)
-        (params, opt_state), losses = jax.lax.scan(epoch_body, (params, opt_state), keys)
-        return params, opt_state, jnp.mean(losses.reshape(-1, 3), axis=0)
+        (params, opt_state), (losses, grad_norms) = jax.lax.scan(epoch_body, (params, opt_state), keys)
+        # the mean losses as ever; then every gradient step's own, and the norm of
+        # every leaf's gradient as the optimizer got it, in the order of the steps
+        by_step = {"losses": losses.reshape(-1, 3), "grad_norms": grad_norms.reshape(-1, grad_norms.shape[-1])}
+        return params, opt_state, jnp.mean(by_step["losses"], axis=0), by_step
 
     if distributed:
         from jax import shard_map
@@ -115,12 +153,50 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch
                 body,
                 mesh=mesh,
                 in_specs=(P(), P(), P(None, "data"), P(), P()),
-                out_specs=(P(), P(), P()),
+                out_specs=(P(), P(), P(), P()),
                 check_vma=False,
             )(params, opt_state, data, key, coefs)
 
         return jax.jit(sharded, donate_argnums=(0, 1))
     return jax.jit(update, donate_argnums=(0, 1))
+
+
+def make_token_player(agent, cfg, rollout_steps: int):
+    """The token policy's three programs of the rollout.  ``policy_step``
+    decodes one token an env through the carried state, which it is donated
+    (a cache of a gigabyte is written in place, not copied a token), samples
+    the next token and stores its log-probability and the value at the
+    rollout's step ``t``; ``value_step`` reads the value of the next
+    observation and writes nothing; ``snapshot_of`` copies the carried state
+    where a training sequence starts.  ``staged`` is ``[2, N]`` int32: the
+    observed tokens and the resets."""
+    cdt = compute_dtype_of(cfg)
+
+    def policy_step(params, carry, staged):
+        tokens, resets = staged[0][:, None], staged[1][:, None]
+        logits, values, state = agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True)
+        key, sample_key = jax.random.split(carry["key"])
+        logp_all = jax.nn.log_softmax(logits[:, 0], axis=-1)
+        actions = jax.random.categorical(sample_key, logp_all, axis=-1)
+        logprobs = jnp.take_along_axis(logp_all, actions[:, None], axis=-1)[:, 0]
+        t = carry["t"] % rollout_steps
+        carry = {
+            "state": state,
+            "key": key,
+            "t": carry["t"] + 1,
+            "logprobs": carry["logprobs"].at[t].set(logprobs),
+            "values": carry["values"].at[t].set(values[:, 0]),
+        }
+        return actions.astype(jnp.int32), carry
+
+    def value_step(params, carry, staged):
+        tokens, resets = staged[0][:, None], staged[1][:, None]
+        return agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True, write=False)[1][:, 0]
+
+    def snapshot_of(state):
+        return jax.tree_util.tree_map(jnp.copy, state)
+
+    return jax.jit(policy_step, donate_argnums=(1,)), jax.jit(value_step), jax.jit(snapshot_of)
 
 
 @register_algorithm()
@@ -213,18 +289,29 @@ def main(runtime, cfg):
     diag.register_footprint("params", params)
     diag.register_footprint("opt_state", opt_state)
 
-    hidden = cfg.algo.rnn.lstm.hidden_size
+    token_policy = backbone_of(cfg) == OLMO_HYBRID
+    if token_policy:
+        policy_step, value_step, snapshot_of = make_token_player(agent, cfg, rollout_steps)
 
-    @jax.jit
-    def policy_step(params, obs, prev_actions, hx, cx, key):
-        actions, logprobs, _, values, (hx, cx) = agent.apply(
-            params, obs, prev_actions, hx, cx, key=key
-        )
-        return actions, logprobs, values, hx, cx
+        def stage_tokens(obs, prev_dones):
+            """The observed tokens and the resets, staged together: ``[2, N]`` int32, which the
+            call into the program puts on the device (a ``device_put`` of its own ahead of the
+            call costs the vector step 0.3 ms more: PERF.md section 6, PR 31)."""
+            tokens = np.asarray(obs[obs_keys[0]]).reshape(num_envs)
+            return np.stack([tokens, prev_dones[:, 0]]).astype(np.int32)
+    else:
+        hidden = cfg.algo.rnn.lstm.hidden_size
 
-    @jax.jit
-    def value_step(params, obs, prev_actions, hx, cx):
-        return agent.apply(params, obs, prev_actions, hx, cx, method="get_values")
+        @jax.jit
+        def policy_step(params, obs, prev_actions, hx, cx, key):
+            actions, logprobs, _, values, (hx, cx) = agent.apply(
+                params, obs, prev_actions, hx, cx, key=key
+            )
+            return actions, logprobs, values, hx, cx
+
+        @jax.jit
+        def value_step(params, obs, prev_actions, hx, cx):
+            return agent.apply(params, obs, prev_actions, hx, cx, method="get_values")
 
     rb = ReplayBuffer(
         rollout_steps,
@@ -245,29 +332,62 @@ def main(runtime, cfg):
     clip_coef = initial_clip
 
     obs, _ = envs.reset(seed=cfg.seed)
-    hx = jnp.zeros((num_envs, hidden), jnp.float32)
-    cx = jnp.zeros((num_envs, hidden), jnp.float32)
-    prev_actions_np = np.zeros((num_envs, act_sum), np.float32)
     prev_dones = np.zeros((num_envs, 1), np.float32)
+    if token_policy:
+        rng_key, carry_key = jax.random.split(rng_key)
+        carry = {
+            "state": agent.init_state(num_envs),
+            "key": carry_key,
+            "t": jnp.zeros((), jnp.int32),
+            "logprobs": jnp.zeros((rollout_steps, num_envs), jnp.float32),
+            "values": jnp.zeros((rollout_steps, num_envs), jnp.float32),
+        }
+        carry_nbytes = carry_bytes(carry["state"])
+        diag.register_footprint("policy_carry", carry_nbytes)
+        episode_positions = np.zeros(num_envs, np.int64)  # the host's mirror of the caches' lengths
+    else:
+        hx = jnp.zeros((num_envs, hidden), jnp.float32)
+        cx = jnp.zeros((num_envs, hidden), jnp.float32)
+        prev_actions_np = np.zeros((num_envs, act_sum), np.float32)
 
     for iter_num in range(start_iter, total_iters + 1):
-        with timer("Time/env_interaction_time"):
-            for _ in range(rollout_steps):
+        with timer("Time/env_interaction_time"), diag.span("rollout"):
+            snapshots = []
+            for step in range(rollout_steps):
                 policy_step_count += num_envs
-                rng_key, step_key = jax.random.split(rng_key)
-                torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
-                # reset state on done BEFORE stepping (reference resets at episode starts)
-                if cfg.algo.reset_recurrent_state_on_done and prev_dones.any():
-                    mask = jnp.asarray(1.0 - prev_dones, jnp.float32)
-                    hx = hx * mask
-                    cx = cx * mask
-                    prev_actions_np = prev_actions_np * (1.0 - prev_dones)
-                hx0_np = np.asarray(hx)
-                cx0_np = np.asarray(cx)
-                actions, logprobs, values, hx, cx = policy_step(
-                    params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx, step_key
-                )
-                actions_np = np.asarray(actions)[0]
+                diag.note_env_steps(num_envs)
+                if token_policy:
+                    if step % seq_len == 0:
+                        # where a training sequence starts: the learner's constant, one copy on the device
+                        snapshots.append(snapshot_of(carry["state"]))
+                    with diag.span("rollout/obs-stage"):
+                        staged = stage_tokens(obs, prev_dones)
+                    with diag.span("rollout/player-forward"):
+                        actions, carry = policy_step(params, carry, staged)  # the step's one put rides the call
+                    with diag.span("rollout/action-fetch"):
+                        actions_np = np.asarray(actions).reshape(num_envs, 1)  # the step's one fetch
+                    episode_positions = np.where(prev_dones[:, 0] > 0, 0, episode_positions) + 1
+                    diag.note_policy_state(int(prev_dones.sum()), int(episode_positions.sum()), carry_nbytes)
+                else:
+                    with diag.span("rollout/obs-stage"):
+                        rng_key, step_key = jax.random.split(rng_key)
+                        torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
+                        # reset state on done BEFORE stepping (reference resets at episode starts)
+                        if cfg.algo.reset_recurrent_state_on_done and prev_dones.any():
+                            mask = jnp.asarray(1.0 - prev_dones, jnp.float32)
+                            hx = hx * mask
+                            cx = cx * mask
+                            prev_actions_np = prev_actions_np * (1.0 - prev_dones)
+                        hx0_np = np.asarray(hx)
+                        cx0_np = np.asarray(cx)
+                    with diag.span("rollout/player-forward"):
+                        actions, logprobs, values, hx, cx = policy_step(
+                            params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx, step_key
+                        )
+                    with diag.span("rollout/action-fetch"):
+                        actions_np = np.asarray(actions)[0]
+                        logprobs_np = np.asarray(logprobs)[0].reshape(num_envs, -1)
+                        values_np = np.asarray(values)[0].reshape(num_envs, -1)
                 if is_continuous:
                     env_actions = actions_np.reshape(num_envs, -1)
                 elif is_multidiscrete:
@@ -281,22 +401,24 @@ def main(runtime, cfg):
                 if cfg.env.clip_rewards:
                     rewards = np.tanh(rewards)
 
-                step_data: Dict[str, np.ndarray] = step_slab(
-                    num_envs,
-                    {
+                with diag.span("rollout/replay-add"):
+                    row = {
                         **{k: obs[k] for k in obs_keys},
                         "actions": actions_np.reshape(num_envs, -1),
-                        "prev_actions": prev_actions_np.reshape(num_envs, -1),
-                        "logprobs": np.asarray(logprobs)[0].reshape(num_envs, -1),
-                        "values": np.asarray(values)[0].reshape(num_envs, -1),
                         "rewards": rewards,
                         "dones": dones,
                         "resets": prev_dones,
-                        "hx": hx0_np.reshape(num_envs, -1),
-                        "cx": cx0_np.reshape(num_envs, -1),
-                    },
-                )
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
+                    }
+                    if not token_policy:  # the token policy keeps these three on the device
+                        row.update(
+                            prev_actions=prev_actions_np.reshape(num_envs, -1),
+                            logprobs=logprobs_np,
+                            values=values_np,
+                            hx=hx0_np.reshape(num_envs, -1),
+                            cx=cx0_np.reshape(num_envs, -1),
+                        )
+                    step_data: Dict[str, np.ndarray] = step_slab(num_envs, row)
+                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
                 if "final_info" in info and "episode" in info["final_info"]:
                     ep = info["final_info"]["episode"]
@@ -308,7 +430,9 @@ def main(runtime, cfg):
 
                 # prev-action input to the RNN is one-hot for discrete heads
                 # (reference ppo_recurrent.py:284,356: dim = sum(actions_dim))
-                if is_continuous:
+                if token_policy:
+                    pass  # the observed token is the input; a one-hot over the vocabulary is nobody's
+                elif is_continuous:
                     prev_actions_np = actions_np.reshape(num_envs, -1).astype(np.float32)
                 else:
                     onehots = []
@@ -319,20 +443,27 @@ def main(runtime, cfg):
                 obs = next_obs
 
         # bootstrap + GAE (reference ppo_recurrent.py:358-396)
-        local = {k: np.asarray(rb[k][:rollout_steps]) for k in rb.buffer.keys()}
-        torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
-        next_values = value_step(params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx)
-        returns, advantages = gae(
-            jnp.asarray(local["rewards"]),
-            jnp.asarray(local["values"]),
-            jnp.asarray(local["dones"]),
-            jnp.asarray(np.asarray(next_values)[0]),
-            rollout_steps,
-            cfg.algo.gamma,
-            cfg.algo.gae_lambda,
-        )
-        local["returns"] = np.asarray(returns)
-        local["advantages"] = np.asarray(advantages)
+        with diag.span("gae"):
+            local = {k: np.asarray(rb[k][:rollout_steps]) for k in rb.buffer.keys()}
+            if token_policy:
+                next_values = np.asarray(value_step(params, carry, stage_tokens(obs, prev_dones))).reshape(num_envs, 1)
+                # the rollout's one fetch of what the player stored while decoding
+                local["logprobs"] = np.asarray(carry["logprobs"])[..., None]
+                local["values"] = np.asarray(carry["values"])[..., None]
+            else:
+                torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
+                next_values = np.asarray(value_step(params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx))[0]
+            returns, advantages = gae(
+                jnp.asarray(local["rewards"]),
+                jnp.asarray(local["values"]),
+                jnp.asarray(local["dones"]),
+                jnp.asarray(next_values),
+                rollout_steps,
+                cfg.algo.gamma,
+                cfg.algo.gae_lambda,
+            )
+            local["returns"] = np.asarray(returns)
+            local["advantages"] = np.asarray(advantages)
 
         # [T, N, ...] -> sequences [L, S, ...], S = (T/L)*N
         def to_seq(x):
@@ -346,9 +477,14 @@ def main(runtime, cfg):
             )
 
         data = {k: to_seq(local[k]) for k in local.keys() if k not in ("hx", "cx")}
-        # initial LSTM state of each sequence = stored state at its first step
-        data["hx0"] = to_seq(local["hx"])[:1]
-        data["cx0"] = to_seq(local["cx"])[:1]
+        if token_policy:
+            # initial state of each sequence = the player's where it starts (sequence s = chunk * N + env)
+            data["state0"] = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0)[None], *snapshots)
+            del snapshots
+        else:
+            # initial LSTM state of each sequence = stored state at its first step
+            data["hx0"] = to_seq(local["hx"])[:1]
+            data["cx0"] = to_seq(local["cx"])[:1]
         device_data = jax.tree_util.tree_map(jnp.asarray, data)
         if world_size > 1:
             from sheeprl_tpu.parallel.mesh import replicated_sharding
@@ -367,31 +503,35 @@ def main(runtime, cfg):
             )
 
         with timer("Time/train_time"):
-            rng_key, train_key = jax.random.split(rng_key)
-            coefs = (
-                jnp.asarray(clip_coef, jnp.float32),
-                jnp.asarray(ent_coef, jnp.float32),
-                jnp.asarray(cfg.algo.vf_coef, jnp.float32),
-            )
-            params, opt_state, losses = train_step(params, opt_state, device_data, train_key, coefs)
+            with diag.span("train"):
+                rng_key, train_key = jax.random.split(rng_key)
+                coefs = (
+                    jnp.asarray(clip_coef, jnp.float32),
+                    jnp.asarray(ent_coef, jnp.float32),
+                    jnp.asarray(cfg.algo.vf_coef, jnp.float32),
+                )
+                params, opt_state, losses, _ = train_step(params, opt_state, device_data, train_key, coefs)
+                del device_data, data
+            # the wait for the update, under no span of its own: the device is at work
             losses = np.asarray(losses)
 
-        aggregator.update("Loss/policy_loss", float(losses[0]))
-        aggregator.update("Loss/value_loss", float(losses[1]))
-        aggregator.update("Loss/entropy_loss", float(losses[2]))
+        with diag.span("bookkeeping"):
+            aggregator.update("Loss/policy_loss", float(losses[0]))
+            aggregator.update("Loss/value_loss", float(losses[1]))
+            aggregator.update("Loss/entropy_loss", float(losses[2]))
 
-        if policy_step_count - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run:
-            metrics = aggregator.compute()
-            timers = timer.compute()
-            if timers.get("Time/env_interaction_time", 0) > 0:
-                metrics["Time/sps_env_interaction"] = (
-                    (policy_step_count - last_log) / timers["Time/env_interaction_time"]
-                )
-            if runtime.is_global_zero:
-                logger.log_metrics(metrics, policy_step_count)
-            aggregator.reset()
-            timer.reset()
-            last_log = policy_step_count
+            if policy_step_count - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run:
+                metrics = aggregator.compute()
+                timers = timer.compute()
+                if timers.get("Time/env_interaction_time", 0) > 0:
+                    metrics["Time/sps_env_interaction"] = (
+                        (policy_step_count - last_log) / timers["Time/env_interaction_time"]
+                    )
+                if runtime.is_global_zero:
+                    logger.log_metrics(metrics, policy_step_count)
+                aggregator.reset()
+                timer.reset()
+                last_log = policy_step_count
 
         # a pending preemption (signal or drill) forces the branch: the save
         # below IS the emergency snapshot (howto/resilience.md)
@@ -403,9 +543,11 @@ def main(runtime, cfg):
             or (iter_num == total_iters and cfg.checkpoint.save_last)
         ):
             last_checkpoint = policy_step_count
+            # the state goes to the writer as it lies on the device: the writer's own snapshot is then
+            # the one host copy (np.asarray here and its copy there would be two, of 9 GB for a language model)
             ckpt_state = {
-                "agent": jax.tree_util.tree_map(np.asarray, params),
-                "opt_state": jax.tree_util.tree_map(np.asarray, opt_state),
+                "agent": params,
+                "opt_state": opt_state,
                 "iter_num": iter_num,
                 "policy_step": policy_step_count,
                 "last_log": last_log,
@@ -419,7 +561,7 @@ def main(runtime, cfg):
                 diag.on_preempted(policy_step_count, iter_num, ckpt_path)
 
     envs.close()
-    if runtime.is_global_zero and cfg.algo.run_test:
+    if runtime.is_global_zero and cfg.algo.run_test and not token_policy:  # the greedy test episode is the LSTM's
         test_env = make_env(cfg, cfg.seed, 0, log_dir, "test", vector_env_idx=0)()
         cumulative_rew = test(agent.apply, params, test_env, runtime, cfg, log_dir)
         logger.log_metrics({"Test/cumulative_reward": cumulative_rew}, policy_step_count)

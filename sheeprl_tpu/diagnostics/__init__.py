@@ -499,6 +499,14 @@ class Diagnostics:
         if self.telemetry is not None:
             self.telemetry.note_env_steps(n)
 
+    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int) -> None:
+        """A sequence policy's carried state, once a vector step: episode
+        resets applied to it, the positions its caches hold over all envs, its
+        bytes on the device (``sheeprl_policy_*`` on ``/metrics``).  No-op
+        when telemetry is disabled."""
+        if self.telemetry is not None:
+            self.telemetry.note_policy_state(resets, cache_positions, carry_bytes)
+
     def note_fetch(self, n: int = 1) -> None:
         """Count a blocking obs→action fetch outside the instrumented rollout
         dispatch path (Dreamer's direct action fetch).  No-op when disabled."""

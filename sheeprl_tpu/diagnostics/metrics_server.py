@@ -143,6 +143,12 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
     emit_family("sheeprl_phase_calls_total", "phase", snapshot.get("phase_calls_total") or {}, fmt=".0f")
     emit_family("sheeprl_instrumented_calls_total", "fn", snapshot.get("calls_total") or {}, fmt=".0f")
 
+    # a sequence policy's carried state (Diagnostics.note_policy_state): absent where no loop reports one
+    policy_state = snapshot.get("policy_state") or {}
+    for key, mtype in (("state_resets_total", "counter"), ("cache_positions", "gauge"), ("carry_bytes", "gauge")):
+        if key in policy_state:
+            emit("policy_" + key, mtype, policy_state[key])
+
     lag = snapshot.get("journal_lag_seconds")
     if lag is not None:
         emit(

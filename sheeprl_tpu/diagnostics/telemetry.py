@@ -662,6 +662,7 @@ class Telemetry:
         # kind="rollout" dispatch == one obs->action->fetch round trip)
         self._env_steps_interval = 0
         self._env_steps_total = 0
+        self._policy_state: Dict[str, int] = {}
         self._rollout_calls_interval = 0
         # offline dataset feed: rows streamed from the loader (the env-free
         # mode's throughput axis) and the loader's epoch counter
@@ -758,6 +759,16 @@ class Telemetry:
         with self._lock:
             self._env_steps_interval += int(n)
             self._env_steps_total += int(n)
+
+    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int) -> None:
+        """A sequence policy's carried state (``ppo_recurrent`` with a
+        language-model backbone): the resets counted, the other two as they stand."""
+        with self._lock:
+            self._policy_state = {
+                "state_resets_total": self._policy_state.get("state_resets_total", 0) + int(resets),
+                "cache_positions": int(cache_positions),
+                "carry_bytes": int(carry_bytes),
+            }
 
     def note_fetch(self, n: int = 1) -> None:
         """Count a blocking obs→action fetch that did NOT go through an
@@ -961,6 +972,7 @@ class Telemetry:
                 "phase_seconds_total": dict(self._phase_total),
                 "phase_calls_total": dict(self._phase_calls_total),
                 "calls_total": dict(self._calls_total),
+                "policy_state": dict(self._policy_state),
                 "flops_per_call": {
                     name: inst.flops_per_call
                     for name, inst in self._instrumented.items()

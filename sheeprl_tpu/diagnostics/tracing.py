@@ -70,6 +70,7 @@ KNOWN_PHASES = (
     "env_step_async",
     "env_wait",
     "bookkeeping",
+    "gae",
     "buffer-sample",
     "train",
     "checkpoint",

@@ -77,9 +77,16 @@ class PipelinedVectorEnv:
         return future.result()
 
     def step(self, actions: Any):
-        """Serialized convenience path (identical results to async+wait)."""
-        self.step_async(actions)
-        return self.step_wait()
+        """Serialized convenience path (identical results to async+wait).  A
+        sync executor steps on the caller's thread: with nothing to overlap,
+        the hand-over to the ``env-step`` thread and back is two wake-ups a
+        vector step that a busy host stretches (PERF.md section 6, PR 31)."""
+        if self._native:
+            self.step_async(actions)
+            return self.step_wait()
+        if self._pending:
+            raise RuntimeError("step() called while a step_async is in flight")
+        return self.envs.step(actions)
 
     # -- passthrough -------------------------------------------------------
     def reset(self, *, seed=None, options=None):

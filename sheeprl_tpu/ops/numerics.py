@@ -12,6 +12,7 @@ enclosing jitted training step (one XLA graph, no host round-trips).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -84,6 +85,7 @@ def uniform_mix(logits: jax.Array, unimix: float = 0.01) -> jax.Array:
     return jnp.log(probs)
 
 
+@functools.partial(jax.jit, static_argnames=("num_steps", "gamma", "gae_lambda"))
 def gae(
     rewards: jax.Array,
     values: jax.Array,
@@ -97,7 +99,9 @@ def gae(
 
     Behaviorally equivalent to the reference's reversed Python loop
     (utils/utils.py:63-103) but expressed as a reverse ``lax.scan`` so it
-    compiles into the training-step graph.
+    compiles into the training-step graph.  Jitted here, so that a loop which
+    calls it from the host once an iteration compiles it once: a bare scan
+    over a body made anew in every call is compiled again every time.
     """
     del num_steps  # shape-derived under jit; kept for API parity
     not_dones = 1.0 - dones.astype(values.dtype)

@@ -331,6 +331,34 @@ def test_step_async_misuse_raises():
     envs.close()
 
 
+def test_step_of_a_sync_executor_runs_on_the_callers_thread():
+    """``step()`` has nothing to overlap: a sync executor's envs step where the
+    caller stands (the hand-over to the ``env-step`` thread is ``step_async``'s),
+    and ``step()`` while a ``step_async`` is in flight is still refused."""
+    import threading
+
+    stepped_on = []
+
+    class Marked(DiscreteDummyEnv):
+        def step(self, action):
+            stepped_on.append(threading.get_ident())
+            return super().step(action)
+
+    envs = PipelinedVectorEnv(
+        gym.vector.SyncVectorEnv(
+            [lambda: Marked(image_size=(3, 8, 8))], autoreset_mode=gym.vector.AutoresetMode.SAME_STEP
+        )
+    )
+    envs.reset(seed=0)
+    envs.step(np.zeros(1, np.int64))
+    envs.step_async(np.zeros(1, np.int64))
+    with pytest.raises(RuntimeError):
+        envs.step(np.zeros(1, np.int64))
+    envs.step_wait()
+    envs.close()
+    assert stepped_on[0] == threading.get_ident() and stepped_on[1] != threading.get_ident()
+
+
 # ---- CLI e2e smoke: the real training loops over the shm executor ---------
 
 _COMMON_CLI = [
