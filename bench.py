@@ -556,6 +556,11 @@ def measure_env_overlap(
     process, back to back, so machine drift cancels within the pair; every
     timing uses the value-fetch barrier discipline of measure_compute.  The deterministic ``sleep_ms`` makes the
     expected gap exact: serialized ≈ pipelined + sleep_ms per iteration.
+
+    ``pipelined`` is the ``env_overlap`` order of ``_dreamer_main``; where the
+    env step is shorter than the host's sample and dispatch the loop has a
+    second order, ``train_first``, and times the two itself
+    (``algos/dreamer_v3/loop_order.py``): this pair does not measure that.
     """
     import jax
     import jax.numpy as jnp

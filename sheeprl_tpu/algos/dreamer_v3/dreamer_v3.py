@@ -34,6 +34,7 @@ import numpy as np
 import optax
 
 from sheeprl_tpu.algos.dreamer_v3.agent import PlayerDV3, build_agent
+from sheeprl_tpu.algos.dreamer_v3.loop_order import TRAIN_FIRST, LoopOrder
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
 from sheeprl_tpu.algos.dreamer_v3.utils import (  # noqa: F401
     AGGREGATOR_KEYS,
@@ -722,157 +723,192 @@ def _dreamer_main(
         return np.stack(idxs, axis=-1)
 
     metrics_drain = DeviceMetricsDrain()
+    # which of its two orders an iteration runs in (loop_order.py): measured,
+    # not configured.  Only the HBM ring has two: on the host the add needs
+    # the fetched action, so the sample cannot precede the fetch without
+    # seeing one row less.
+    loop_order = LoopOrder(
+        use_device_buffer and not cfg.dry_run, count=diag.note_loop_order, journal=diag.on_loop_order
+    )
+
+    def dispatch_gradient_steps() -> None:
+        """Sample and dispatch this iteration's gradient steps, if any are due.
+
+        The sample includes everything up to and including the current policy
+        step (both buffer modes and both orders: the add always precedes the
+        sampling); episode-end bookkeeping rows from *this* step (known only
+        at `step_wait`) become sampleable one iteration later.  Likewise the
+        restart_on_exception truncation surgery (below) lands only after these
+        gradient steps have sampled, so a crashed-env discontinuity can be
+        trained on once as a normal transition: rare and bounded to one
+        iteration (the reference patches before training; we accept the lag
+        as the price of the overlap)."""
+        nonlocal params, opt_states, moments_state, rng_key
+        nonlocal has_trained, cumulative_grad_steps, train_step_count
+        if iter_num < learning_starts:
+            return
+        per_rank_gradient_steps = ratio(
+            (policy_step_count - prefill_steps * policy_steps_per_iter)
+        )
+        if cfg.dry_run:
+            per_rank_gradient_steps = 1
+        if per_rank_gradient_steps <= 0:
+            return
+        has_trained = True
+        with diag.span("buffer-sample"):
+            local_data = rb.sample(
+                local_sample_size(cfg.algo.per_rank_batch_size * world_size, use_device_buffer),
+                sequence_length=cfg.algo.per_rank_sequence_length,
+                n_samples=per_rank_gradient_steps,
+            )
+            batches = train_batches(
+                local_data,
+                per_rank_gradient_steps,
+                runtime.mesh if world_size > 1 else None,
+                cnn_keys,
+                use_device_buffer,
+            )
+
+        with timer("Time/train_time"), diag.span("train"):
+            for batch in batches:
+                batch = diag.maybe_inject_nan(iter_num, batch)
+                target_freq = cfg.algo.critic.get("per_rank_target_network_update_freq", 0)
+                if target_freq and cumulative_grad_steps % target_freq == 0:
+                    tau = 1.0 if cumulative_grad_steps == 0 else cfg.algo.critic.get("tau", 1.0)
+                else:
+                    tau = 0.0
+                rng_key, train_key = jax.random.split(rng_key)
+                out = train_step(
+                    params, opt_states, moments_state, batch, train_key, jnp.float32(tau)
+                )
+                # P2E's step builders return 4 outputs (no health
+                # tree); the DV3/JEPA steps return 5 ({} when
+                # diagnostics.health is off)
+                params, opt_states, moments_state, metrics = out[:4]
+                step_health = out[4] if len(out) > 4 else None
+                cumulative_grad_steps += 1
+            train_step_count += 1
+        metrics_drain.append(metrics, extra=step_health)
 
     for iter_num in range(start_iter, total_iters + 1):
         policy_step_count += policy_steps_per_iter
         diag.note_env_steps(num_envs)
+        player_acts = iter_num > learning_starts or bool(cfg.checkpoint.resume_from)
+        order = loop_order.begin(player_acts, train_step_count)
 
         # ---- policy forward + env dispatch + replay write -----------------
-        # Split-phase iteration: the player forward is dispatched, its action
-        # values are fetched, and `step_async` is issued THE MOMENT the
-        # values land — the env workers then step
-        # concurrently with everything below: the replay write, the sampling
-        # + dispatch of this iteration's gradient steps, and the device
-        # executing them.  Only `step_wait` (after the train dispatch) blocks
-        # on the envs, so the per-iteration critical path is
-        # ``fwd + fetch + max(train dispatch, env_step)`` instead of the
-        # reference hot loop's full serialization (dreamer_v3.py:637-672).
-        # Ordering tradeoff: the gradient-step dispatch (~ms of host work)
-        # can hide behind either the action fetch (the pre-pipeline order) or
-        # the env step (this order) but not both — the fetch's device->host copy
-        # is started at the same point either way, so the swing is only the host
-        # dispatch time, and this order wins whenever env_step exceeds it
-        # (every real simulator; bench.py's env_overlap pair measures it).
-        with timer("Time/env_interaction_time"), diag.span("rollout"):
-            actions_jnp = None
-            if iter_num <= learning_starts and not cfg.checkpoint.resume_from:
-                real_actions = actions = np.asarray(envs.action_space.sample())
-                if not is_continuous:
-                    actions = np.concatenate(
-                        [
-                            np.eye(act_dim, dtype=np.float32)[act]
-                            for act, act_dim in zip(actions.reshape(len(actions_dim), -1), actions_dim)
-                        ],
-                        axis=-1,
-                    )
-                step_data["actions"] = actions.reshape(1, num_envs, -1)
-                if store_rssm_state:
-                    # prefill rows: the player never ran, so no state exists —
-                    # valid=0 makes chunk starts here reset to the learned
-                    # initial state instead of training on zeros
-                    step_data.update(
-                        rssm_state_slab(
-                            num_envs, rssm_zero_recurrent, rssm_zero_stochastic, valid=False
+        # Split-phase iteration.  The device's work is enqueued in one
+        # sequence whatever the order: player forward, ring add, ring sample,
+        # batch staging, train step.  The player reads the parameters the
+        # previous train step wrote, so the action fetch returns when that
+        # step and the player have ended, and `step_wait` is the only other
+        # place the host blocks.  What the order decides is where those two
+        # waits stand against the host's sample and train dispatch:
+        # - ENV_OVERLAP: fetch, `step_async`, then sample + dispatch while the
+        #   env workers step.  Critical path ``fwd + fetch + max(sample +
+        #   dispatch, env_step)``: the host's sample and dispatch hide behind
+        #   the env step, and the device idles under them, from the previous
+        #   step's end until the next is enqueued.
+        # - TRAIN_FIRST (HBM ring only): sample + dispatch, then fetch and
+        #   `step_async`.  The device runs player -> add -> sample -> step
+        #   back to back while the host fetches, steps the envs and stages
+        #   the next obs; the env step stands on the host's serial path.
+        # A fast env wants the second, a slow simulator the first, and where
+        # they meet depends on the model: `loop_order` times both and keeps
+        # the faster (bench.py's env_overlap pair shows the first's gain over
+        # the reference hot loop's full serialization, dreamer_v3.py:637-672).
+        # Params, optimizer state and ring contents are the same bit for bit.
+        with diag.span("rollout"):
+            with timer("Time/env_interaction_time"):
+                actions_jnp = None
+                if not player_acts:
+                    real_actions = actions = np.asarray(envs.action_space.sample())
+                    if not is_continuous:
+                        actions = np.concatenate(
+                            [
+                                np.eye(act_dim, dtype=np.float32)[act]
+                                for act, act_dim in zip(actions.reshape(len(actions_dim), -1), actions_dim)
+                            ],
+                            axis=-1,
                         )
-                    )
-            else:
-                rng_key, step_key = jax.random.split(rng_key)
-                with diag.span("rollout/obs-stage"):
-                    torch_obs = prepare_obs(
-                        obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs, sharding=stage_sharding
-                    )
-                # mask_* observation keys feed MinedojoActor's hierarchical
-                # action masking (reference dreamer_v3.py:614-617)
-                mask = {k: v for k, v in torch_obs.items() if k.startswith("mask")} or None
-                with diag.span("rollout/player-forward"):
-                    actions_jnp = player.get_actions(
-                        params["world_model"], player_actor_fn(params, has_trained), torch_obs, step_key,
-                        mask=mask,
-                    )
-                if use_device_buffer:
-                    # device-resident actions go straight into the HBM ring
-                    # (no fetch needed for the write); the chunked-scan state
-                    # record stays on device with them
-                    step_data["actions"] = jnp.reshape(actions_jnp, (1, num_envs, -1))
+                    step_data["actions"] = actions.reshape(1, num_envs, -1)
                     if store_rssm_state:
+                        # prefill rows: the player never ran, so no state exists —
+                        # valid=0 makes chunk starts here reset to the learned
+                        # initial state instead of training on zeros
                         step_data.update(
                             rssm_state_slab(
-                                num_envs,
-                                player.state["recurrent"],
-                                player.state["stochastic"],
-                                valid=True,
+                                num_envs, rssm_zero_recurrent, rssm_zero_stochastic, valid=False
                             )
                         )
+                else:
+                    rng_key, step_key = jax.random.split(rng_key)
+                    with diag.span("rollout/obs-stage"):
+                        torch_obs = prepare_obs(
+                            obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs, sharding=stage_sharding
+                        )
+                    # mask_* observation keys feed MinedojoActor's hierarchical
+                    # action masking (reference dreamer_v3.py:614-617)
+                    mask = {k: v for k, v in torch_obs.items() if k.startswith("mask")} or None
+                    with diag.span("rollout/player-forward"):
+                        actions_jnp = player.get_actions(
+                            params["world_model"], player_actor_fn(params, has_trained), torch_obs, step_key,
+                            mask=mask,
+                        )
+                    if use_device_buffer:
+                        # device-resident actions go straight into the HBM ring
+                        # (no fetch needed for the write); the chunked-scan state
+                        # record stays on device with them
+                        step_data["actions"] = jnp.reshape(actions_jnp, (1, num_envs, -1))
+                        if store_rssm_state:
+                            step_data.update(
+                                rssm_state_slab(
+                                    num_envs,
+                                    player.state["recurrent"],
+                                    player.state["stochastic"],
+                                    valid=True,
+                                )
+                            )
+                        with diag.span("rollout/replay-add"):
+                            rb.add(step_data, validate_args=cfg.buffer.validate_args)
+
+            if order == TRAIN_FIRST:
+                # queued behind the player and the add, ahead of the host's
+                # two waits: `buffer-sample` and `train` lie inside `rollout`
+                # here, which keeps their seconds out of its self time
+                dispatch_gradient_steps()
+
+            with timer("Time/env_interaction_time"):
+                if actions_jnp is not None:
+                    diag.note_fetch()  # the iteration's ONE blocking d2h
+                    # the host waits here for the device: the player forward and
+                    # whatever was queued before it
+                    with diag.span("rollout/action-fetch"):
+                        if store_rssm_state and not use_device_buffer:
+                            # the stored states ride the SAME blocking fetch as the
+                            # action values — still one d2h round trip per vector step
+                            actions, host_recurrent, host_stochastic = fetch_values(
+                                actions_jnp, player.state["recurrent"], player.state["stochastic"]
+                            )
+                            step_data.update(
+                                rssm_state_slab(num_envs, host_recurrent, host_stochastic, valid=True)
+                            )
+                        else:
+                            actions = np.asarray(actions_jnp)  # blocking value fetch
+                    real_actions = split_real_actions(actions)
+                    if not use_device_buffer:
+                        step_data["actions"] = actions.reshape(1, num_envs, -1)
+                with diag.span("env_step_async"):
+                    envs.step_async(real_actions.reshape(envs.action_space.shape))
+                if actions_jnp is None or not use_device_buffer:
+                    # prefill / host-buffer write — overlaps the env workers
                     with diag.span("rollout/replay-add"):
                         rb.add(step_data, validate_args=cfg.buffer.validate_args)
-                diag.note_fetch()  # the iteration's ONE blocking d2h
-                # the host waits here for the device: the player forward and
-                # whatever was queued before it
-                with diag.span("rollout/action-fetch"):
-                    if store_rssm_state and not use_device_buffer:
-                        # the stored states ride the SAME blocking fetch as the
-                        # action values — still one d2h round trip per vector step
-                        actions, host_recurrent, host_stochastic = fetch_values(
-                            actions_jnp, player.state["recurrent"], player.state["stochastic"]
-                        )
-                        step_data.update(
-                            rssm_state_slab(num_envs, host_recurrent, host_stochastic, valid=True)
-                        )
-                    else:
-                        actions = np.asarray(actions_jnp)  # blocking value fetch
-                real_actions = split_real_actions(actions)
-                if not use_device_buffer:
-                    step_data["actions"] = actions.reshape(1, num_envs, -1)
-            with diag.span("env_step_async"):
-                envs.step_async(real_actions.reshape(envs.action_space.shape))
-            if actions_jnp is None or not use_device_buffer:
-                # prefill / host-buffer write — overlaps the env workers
-                with diag.span("rollout/replay-add"):
-                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
         # ---- dispatch this iteration's gradient steps ---------------------
-        # Runs while the env workers are stepping.  The sample includes
-        # everything up to and including the current policy step (both buffer
-        # modes — the add above always precedes the sampling); episode-end
-        # bookkeeping rows from *this* step (known only at `step_wait`)
-        # become sampleable one iteration later.  Likewise the
-        # restart_on_exception truncation surgery (below) lands only after
-        # these gradient steps have sampled, so a crashed-env discontinuity
-        # can be trained on once as a normal transition — rare and bounded to
-        # one iteration (the reference patches before training; we accept the
-        # lag as the price of the overlap).
-        if iter_num >= learning_starts:
-            per_rank_gradient_steps = ratio(
-                (policy_step_count - prefill_steps * policy_steps_per_iter)
-            )
-            if cfg.dry_run:
-                per_rank_gradient_steps = 1
-            if per_rank_gradient_steps > 0:
-                has_trained = True
-                with diag.span("buffer-sample"):
-                    local_data = rb.sample(
-                        local_sample_size(cfg.algo.per_rank_batch_size * world_size, use_device_buffer),
-                        sequence_length=cfg.algo.per_rank_sequence_length,
-                        n_samples=per_rank_gradient_steps,
-                    )
-                    batches = train_batches(
-                        local_data,
-                        per_rank_gradient_steps,
-                        runtime.mesh if world_size > 1 else None,
-                        cnn_keys,
-                        use_device_buffer,
-                    )
-
-                with timer("Time/train_time"), diag.span("train"):
-                    for batch in batches:
-                        batch = diag.maybe_inject_nan(iter_num, batch)
-                        target_freq = cfg.algo.critic.get("per_rank_target_network_update_freq", 0)
-                        if target_freq and cumulative_grad_steps % target_freq == 0:
-                            tau = 1.0 if cumulative_grad_steps == 0 else cfg.algo.critic.get("tau", 1.0)
-                        else:
-                            tau = 0.0
-                        rng_key, train_key = jax.random.split(rng_key)
-                        out = train_step(
-                            params, opt_states, moments_state, batch, train_key, jnp.float32(tau)
-                        )
-                        # P2E's step builders return 4 outputs (no health
-                        # tree); the DV3/JEPA steps return 5 ({} when
-                        # diagnostics.health is off)
-                        params, opt_states, moments_state, metrics = out[:4]
-                        step_health = out[4] if len(out) > 4 else None
-                        cumulative_grad_steps += 1
-                    train_step_count += 1
-                metrics_drain.append(metrics, extra=step_health)
+        # ENV_OVERLAP: runs while the env workers are stepping.
+        if order != TRAIN_FIRST:
+            dispatch_gradient_steps()
 
         # ---- collect the env step results (device keeps training) --------
         with timer("Time/env_interaction_time"), diag.span("env_wait"):

@@ -507,6 +507,18 @@ class Diagnostics:
         if self.telemetry is not None:
             self.telemetry.note_policy_state(resets, cache_positions, carry_bytes)
 
+    def note_loop_order(self, order: str) -> None:
+        """Count one iteration under the order it ran in
+        (``sheeprl_loop_order_iterations_total{order}`` on ``/metrics``).
+        No-op when telemetry is disabled."""
+        if self.telemetry is not None:
+            self.telemetry.note_loop_order(order)
+
+    def on_loop_order(self, **decision: Any) -> None:
+        """Journal one decision of the loop's order controller
+        (``algos/dreamer_v3/loop_order.py``): the ``loop_order`` event."""
+        self._journal_event("loop_order", **decision)
+
     def note_fetch(self, n: int = 1) -> None:
         """Count a blocking obs→action fetch outside the instrumented rollout
         dispatch path (Dreamer's direct action fetch).  No-op when disabled."""

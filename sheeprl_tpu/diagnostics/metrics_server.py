@@ -142,6 +142,10 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
     emit_family("sheeprl_phase_seconds_total", "phase", snapshot.get("phase_seconds_total") or {})
     emit_family("sheeprl_phase_calls_total", "phase", snapshot.get("phase_calls_total") or {}, fmt=".0f")
     emit_family("sheeprl_instrumented_calls_total", "fn", snapshot.get("calls_total") or {}, fmt=".0f")
+    # absent where the loop has one order only to run (every loop but the Dreamer engine)
+    emit_family(
+        "sheeprl_loop_order_iterations_total", "order", snapshot.get("loop_order_iterations_total") or {}, fmt=".0f"
+    )
 
     # a sequence policy's carried state (Diagnostics.note_policy_state): absent where no loop reports one
     policy_state = snapshot.get("policy_state") or {}

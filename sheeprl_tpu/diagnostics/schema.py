@@ -76,6 +76,7 @@ EVENT_KINDS: Dict[str, str] = {
     "dataset_export": "replay experience exported as dataset shards (rows/bytes/shards written, cumulative totals, dataset path)",
     "dataset_open": "offline training opened a dataset: verified streams/segments/shards/rows/bytes and how many shards were skipped",
     "dataset_shard_skipped": "dataset open rejected a torn/corrupt shard (no_manifest / size_mismatch / digest_mismatch) with the reason",
+    "loop_order": "the Dreamer engine measured its two iteration orders and kept the faster: both medians (ms), the order kept, the training iteration it fell on",
     "preempted": "graceful preemption: emergency snapshot landed at a loop boundary; the process exits with code 75 (fsync'd)",
     "restart": "supervisor respawned the run after a non-clean exit: attempt, rc, backoff, measured downtime, resume source",
     "run_end": "completed / halted / aborted / preempted — absent after a kill",
@@ -103,6 +104,7 @@ METRICS: Dict[str, str] = {
     "sheeprl_phase_seconds_total": "cumulative wall-clock per host phase (label: phase; self time, a slash part inclusive)",
     "sheeprl_phase_calls_total": "cumulative spans closed per host phase or part (label: phase)",
     "sheeprl_instrumented_calls_total": "cumulative dispatches through each instrumented jitted step (label: fn)",
+    "sheeprl_loop_order_iterations_total": "iterations the Dreamer engine ran in each of its two orders (label: order = env_overlap | train_first)",
     "sheeprl_journal_lag_seconds": "seconds since the last journal write",
     # telemetry counters (Telemetry.snapshot()["counters"])
     "sheeprl_recompiles_total": "watchdog: new dispatch signatures seen",

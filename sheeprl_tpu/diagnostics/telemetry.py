@@ -663,6 +663,7 @@ class Telemetry:
         self._env_steps_interval = 0
         self._env_steps_total = 0
         self._policy_state: Dict[str, int] = {}
+        self._loop_order_iterations: Dict[str, int] = {}
         self._rollout_calls_interval = 0
         # offline dataset feed: rows streamed from the loader (the env-free
         # mode's throughput axis) and the loader's epoch counter
@@ -769,6 +770,12 @@ class Telemetry:
                 "cache_positions": int(cache_positions),
                 "carry_bytes": int(carry_bytes),
             }
+
+    def note_loop_order(self, order: str) -> None:
+        """One iteration of a loop that has two orders, under the order it ran
+        in (``algos/dreamer_v3/loop_order.py``)."""
+        with self._lock:
+            self._loop_order_iterations[order] = self._loop_order_iterations.get(order, 0) + 1
 
     def note_fetch(self, n: int = 1) -> None:
         """Count a blocking obs→action fetch that did NOT go through an
@@ -973,6 +980,7 @@ class Telemetry:
                 "phase_calls_total": dict(self._phase_calls_total),
                 "calls_total": dict(self._calls_total),
                 "policy_state": dict(self._policy_state),
+                "loop_order_iterations_total": dict(self._loop_order_iterations),
                 "flops_per_call": {
                     name: inst.flops_per_call
                     for name, inst in self._instrumented.items()
