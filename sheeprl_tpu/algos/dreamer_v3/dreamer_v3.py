@@ -94,10 +94,10 @@ def make_train_step(
     horizon = cfg.algo.horizon
     # lax.scan unroll factor for the RSSM/imagination loops: unrolling
     # amortizes per-iteration scan overhead (one pre-PR-1 S-size sweep showed
-    # ~6% at unroll=8, and the interleaved A/B harness — tools/perf_study.py
-    # measure_unroll_ab — is how to (re)confirm it on a given chip; PERF.md
-    # §5) at the cost of ~unroll x longer compiles, so it defaults to 1 and
-    # is a deploy-time knob.  Caveat: cost_analysis() FLOPs inflate under
+    # ~6% at unroll=8; it has no chip verdict: ROADMAP S3's sweep on the
+    # DV3 cells decides it and deletes what loses, PERF.md §5) at the cost
+    # of ~unroll x longer compiles, so it defaults to 1 and is a
+    # deploy-time knob.  Caveat: cost_analysis() FLOPs inflate under
     # unrolling, so compare step_ms — the telemetry_cost journal event
     # carries this caveat (cost_note) whenever unroll > 1.
     scan_unroll = int(cfg.algo.get("scan_unroll", 1))
@@ -815,8 +815,8 @@ def _dreamer_main(
         #   the next obs; the env step stands on the host's serial path.
         # A fast env wants the second, a slow simulator the first, and where
         # they meet depends on the model: `loop_order` times both and keeps
-        # the faster (bench.py's env_overlap pair shows the first's gain over
-        # the reference hot loop's full serialization, dreamer_v3.py:637-672).
+        # the faster (PERF.md §6, PR 32; the reference hot loop serializes all
+        # of it, dreamer_v3.py:637-672).
         # Params, optimizer state and ring contents are the same bit for bit.
         with diag.span("rollout"):
             with timer("Time/env_interaction_time"):

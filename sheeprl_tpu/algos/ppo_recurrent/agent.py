@@ -200,14 +200,6 @@ class RecurrentPPOAgent(nn.Module):
         return self.critic(out)
 
 
-LSTM, OLMO_HYBRID = "lstm", "olmo_hybrid"
-
-
-def backbone_of(cfg) -> str:
-    """``algo.backbone``: ``lstm`` (the preset's ``algo.rnn``) or ``olmo_hybrid`` (``algo.olmo_hybrid``)."""
-    return str(cfg.algo.get("backbone", LSTM) or LSTM)
-
-
 def token_key(cfg) -> str:
     """The one observation key a token policy reads."""
     return list(cfg.algo.mlp_keys.encoder)[0]
@@ -224,7 +216,7 @@ def build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state=No
     space = obs_space[key]
     if is_continuous or len(actions_dim) != 1 or not isinstance(space, gymnasium.spaces.Discrete):
         raise ValueError(
-            f"algo.backbone={OLMO_HYBRID} needs one Discrete observation ({key!r}) and one Discrete action, "
+            f"algo.backbone=olmo_hybrid needs one Discrete observation ({key!r}) and one Discrete action, "
             f"got observation {space} and actions {tuple(actions_dim)}"
         )
     if int(space.n) != config.vocab_held or int(actions_dim[0]) != config.vocab_held:
@@ -251,7 +243,9 @@ def build_agent(
 ):
     cnn_keys = list(cfg.algo.cnn_keys.encoder)
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
-    if backbone_of(cfg) == OLMO_HYBRID:
+    # ``algo.backbone``: ``lstm`` (the preset's ``algo.rnn``) or ``olmo_hybrid`` (``algo.olmo_hybrid``); the one place
+    # that compares the name: the loop takes its player from the kind of agent built here (``players.make_player``)
+    if str(cfg.algo.get("backbone", "lstm") or "lstm") == "olmo_hybrid":
         return build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state)
     agent = RecurrentPPOAgent(
         actions_dim=tuple(int(a) for a in actions_dim),

@@ -8,6 +8,7 @@ from typing import Any, Dict
 import gymnasium as gym
 
 from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent
+from sheeprl_tpu.algos.ppo_recurrent.players import make_player
 from sheeprl_tpu.algos.ppo_recurrent.utils import test
 from sheeprl_tpu.envs.env import make_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -31,6 +32,6 @@ def evaluate_ppo_recurrent(runtime, cfg, state: Dict[str, Any]) -> None:
         else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
     )
     agent, params, _ = build_agent(runtime, actions_dim, is_continuous, cfg, observation_space, state["agent"])
-    cumulative_rew = test(agent.apply, params, env, runtime, cfg, log_dir)
+    cumulative_rew = test(make_player(agent, cfg, greedy=True), params, env, cfg)
     logger.log_metrics({"Test/cumulative_reward": cumulative_rew}, 0)
     logger.finalize()

@@ -10,15 +10,11 @@ requires at :226), each sequence starts from its stored LSTM state, and the
 `reset_recurrent_state_on_done` semantics are preserved by in-graph masked
 state resets at done steps.  No padding, no masks, one `lax.scan` per BPTT.
 
-Two backbones (``algo.backbone``).  ``lstm`` is the reference's: its ``hx``,
-``cx`` are a row per env, stored with every step.  ``olmo_hybrid`` is a hybrid
-language model as a token-action policy (``models/hybrid_lm.py``): its carried
-state (the linear layers' state and convolution tails, the full layers' keys
-and values of the running episode) is a pytree that stays on the device
-through the rollout, is donated to ``policy_step``, is copied once where a
-training sequence starts (the learner's constant, as ``hx0``/``cx0`` are) and
-never reaches the host; the rollout's log-probabilities and values are kept
-beside it and fetched once a rollout; resets are in-graph in both forms.
+Two backbones (``algo.backbone``), each a player (``players.py``): ``lstm`` is
+the reference's, ``olmo_hybrid`` a hybrid language model as a token-action
+policy (``models/hybrid_lm.py``).  What a policy carries through a rollout,
+where it lives and what a training sequence starts from is the player's; the
+loop below names no backbone.
 """
 
 from __future__ import annotations
@@ -34,63 +30,30 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
-from sheeprl_tpu.algos.ppo_recurrent.agent import OLMO_HYBRID, backbone_of, build_agent, token_key
-from sheeprl_tpu.algos.ppo_recurrent.utils import (  # noqa: F401
-    AGGREGATOR_KEYS,
-    MODELS_TO_REGISTER,
-    prepare_obs,
-    test,
-)
+from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent
+from sheeprl_tpu.algos.ppo_recurrent.players import make_player, make_token_player  # noqa: F401
+from sheeprl_tpu.algos.ppo_recurrent.utils import AGGREGATOR_KEYS, MODELS_TO_REGISTER, KeyStream  # noqa: F401
 from sheeprl_tpu.config import instantiate
 from sheeprl_tpu.data.slab import step_slab
-from sheeprl_tpu.models.hybrid_lm import carry_bytes
 from sheeprl_tpu.data.buffers import ReplayBuffer
-from sheeprl_tpu.envs.env import make_env, make_env_fns, pipelined_vector_env
+from sheeprl_tpu.envs.env import make_env_fns, pipelined_vector_env
 from sheeprl_tpu.ops.numerics import gae
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator
-from sheeprl_tpu.parallel.precision import cast_floating, compute_dtype_of
+from sheeprl_tpu.parallel.precision import cast_floating
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import get_diagnostics, polynomial_decay, save_configs
 
 
-def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch: int):
+def make_train_step(player, optimizer, cfg, mesh, num_minibatches: int, seq_batch: int):
     """Jitted update over sequence minibatches: data leaves are
     ``[L, S, ...]`` with S sequences sharded over the mesh."""
     world = mesh.devices.size
     distributed = world > 1
-    cdt = compute_dtype_of(cfg)
-    obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
-    token_policy = backbone_of(cfg) == OLMO_HYBRID
-
-    def evaluate_tokens(params, batch):
-        """The sequence forward of the token policy from each sequence's
-        snapshot; leaves are time-major ``[L, S, 1]``, the model's batch-major."""
-        tokens = batch[token_key(cfg)][..., 0].T.astype(jnp.int32)
-        resets = batch["resets"][..., 0].T.astype(jnp.int32)
-        state0 = jax.tree_util.tree_map(lambda x: x[0], batch["state0"])
-        logits, values, _ = agent.apply(cast_floating(params, cdt), tokens, resets, state0)
-        with jax.named_scope("ppo_loss"):
-            logp_all = jax.nn.log_softmax(logits, axis=-1)
-            actions = batch["actions"][..., 0].T.astype(jnp.int32)
-            logprobs = jnp.take_along_axis(logp_all, actions[..., None], axis=-1)
-            entropy = -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1, keepdims=True)
-        return logprobs.swapaxes(0, 1), entropy.swapaxes(0, 1), values.T[..., None]
 
     def loss_fn(params, batch, clip_coef, ent_coef, vf_coef):
-        if token_policy:
-            new_logprobs, entropy, new_values = evaluate_tokens(params, batch)
-        else:
-            _, new_logprobs, entropy, new_values, _ = agent.apply(
-                cast_floating(params, cdt),
-                cast_floating({k: batch[k] for k in obs_keys}, cdt),
-                cast_floating(batch["prev_actions"], cdt),
-                cast_floating(batch["hx0"][0], cdt),
-                cast_floating(batch["cx0"][0], cdt),
-                resets=batch["resets"],
-                actions=batch["actions"],
-            )
+        new_logprobs, entropy, new_values = player.evaluate(params, batch)
         with jax.named_scope("ppo_loss"):
             return ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef)
 
@@ -161,44 +124,6 @@ def make_train_step(agent, optimizer, cfg, mesh, num_minibatches: int, seq_batch
     return jax.jit(update, donate_argnums=(0, 1))
 
 
-def make_token_player(agent, cfg, rollout_steps: int):
-    """The token policy's three programs of the rollout.  ``policy_step``
-    decodes one token an env through the carried state, which it is donated
-    (a cache of a gigabyte is written in place, not copied a token), samples
-    the next token and stores its log-probability and the value at the
-    rollout's step ``t``; ``value_step`` reads the value of the next
-    observation and writes nothing; ``snapshot_of`` copies the carried state
-    where a training sequence starts.  ``staged`` is ``[2, N]`` int32: the
-    observed tokens and the resets."""
-    cdt = compute_dtype_of(cfg)
-
-    def policy_step(params, carry, staged):
-        tokens, resets = staged[0][:, None], staged[1][:, None]
-        logits, values, state = agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True)
-        key, sample_key = jax.random.split(carry["key"])
-        logp_all = jax.nn.log_softmax(logits[:, 0], axis=-1)
-        actions = jax.random.categorical(sample_key, logp_all, axis=-1)
-        logprobs = jnp.take_along_axis(logp_all, actions[:, None], axis=-1)[:, 0]
-        t = carry["t"] % rollout_steps
-        carry = {
-            "state": state,
-            "key": key,
-            "t": carry["t"] + 1,
-            "logprobs": carry["logprobs"].at[t].set(logprobs),
-            "values": carry["values"].at[t].set(values[:, 0]),
-        }
-        return actions.astype(jnp.int32), carry
-
-    def value_step(params, carry, staged):
-        tokens, resets = staged[0][:, None], staged[1][:, None]
-        return agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True, write=False)[1][:, 0]
-
-    def snapshot_of(state):
-        return jax.tree_util.tree_map(jnp.copy, state)
-
-    return jax.jit(policy_step, donate_argnums=(1,)), jax.jit(value_step), jax.jit(snapshot_of)
-
-
 @register_algorithm()
 def main(runtime, cfg):
     world_size = runtime.world_size
@@ -221,7 +146,7 @@ def main(runtime, cfg):
     seq_batch = max(1, seq_per_device // num_batches)
     num_minibatches = seq_per_device // seq_batch
 
-    rng_key = runtime.seed_everything(cfg.seed)
+    keys = KeyStream(runtime.seed_everything(cfg.seed))  # the loop's random stream, which its player draws from too
     logger = get_logger(runtime, cfg)
     log_dir = get_log_dir(runtime, cfg.root_dir, cfg.run_name)
     if runtime.is_global_zero:
@@ -238,9 +163,7 @@ def main(runtime, cfg):
     action_space = envs.single_action_space
     if not isinstance(observation_space, gym.spaces.Dict):
         raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {observation_space}")
-    cnn_keys = cfg.algo.cnn_keys.encoder
-    mlp_keys = cfg.algo.mlp_keys.encoder
-    obs_keys = list(cnn_keys) + list(mlp_keys)
+    obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
     is_continuous = isinstance(action_space, gym.spaces.Box)
     is_multidiscrete = isinstance(action_space, gym.spaces.MultiDiscrete)
     actions_dim = tuple(
@@ -248,13 +171,13 @@ def main(runtime, cfg):
         if is_continuous
         else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
     )
-    act_sum = int(sum(actions_dim)) if not is_continuous else int(np.prod(action_space.shape))
 
     state = runtime.load(cfg.checkpoint.resume_from) if cfg.checkpoint.resume_from else None
     agent, params, _ = build_agent(
         runtime, actions_dim, is_continuous, cfg, observation_space, state["agent"] if state else None
     )
     params = cast_floating(params, runtime.param_dtype)
+    player = make_player(agent, cfg)
     policy_steps_per_iter = int(num_envs * rollout_steps)
     total_iters = int(cfg.algo.total_steps // policy_steps_per_iter) if not cfg.dry_run else 1
     if cfg.algo.anneal_lr:
@@ -280,38 +203,17 @@ def main(runtime, cfg):
         )
 
     # telemetry + memory instrumentation — see tools/check_instrumentation.py
+    # The update differentiates the forward of a player that is never started.  The rollout's player holds `diag`,
+    # which holds this step: reached from the step's closure it would close a cycle through a jitted function, which
+    # the collector does not free, and what it carries (a gigabyte of cache) would outlive the loop on the device.
     train_step = diag.instrument(
         "train_step",
-        make_train_step(agent, optimizer, cfg, runtime.mesh, num_minibatches, seq_batch),
+        make_train_step(make_player(agent, cfg), optimizer, cfg, runtime.mesh, num_minibatches, seq_batch),
         kind="train",
         donate_argnums=(0, 1),
     )
     diag.register_footprint("params", params)
     diag.register_footprint("opt_state", opt_state)
-
-    token_policy = backbone_of(cfg) == OLMO_HYBRID
-    if token_policy:
-        policy_step, value_step, snapshot_of = make_token_player(agent, cfg, rollout_steps)
-
-        def stage_tokens(obs, prev_dones):
-            """The observed tokens and the resets, staged together: ``[2, N]`` int32, which the
-            call into the program puts on the device (a ``device_put`` of its own ahead of the
-            call costs the vector step 0.3 ms more: PERF.md section 6, PR 31)."""
-            tokens = np.asarray(obs[obs_keys[0]]).reshape(num_envs)
-            return np.stack([tokens, prev_dones[:, 0]]).astype(np.int32)
-    else:
-        hidden = cfg.algo.rnn.lstm.hidden_size
-
-        @jax.jit
-        def policy_step(params, obs, prev_actions, hx, cx, key):
-            actions, logprobs, _, values, (hx, cx) = agent.apply(
-                params, obs, prev_actions, hx, cx, key=key
-            )
-            return actions, logprobs, values, hx, cx
-
-        @jax.jit
-        def value_step(params, obs, prev_actions, hx, cx):
-            return agent.apply(params, obs, prev_actions, hx, cx, method="get_values")
 
     rb = ReplayBuffer(
         rollout_steps,
@@ -333,61 +235,20 @@ def main(runtime, cfg):
 
     obs, _ = envs.reset(seed=cfg.seed)
     prev_dones = np.zeros((num_envs, 1), np.float32)
-    if token_policy:
-        rng_key, carry_key = jax.random.split(rng_key)
-        carry = {
-            "state": agent.init_state(num_envs),
-            "key": carry_key,
-            "t": jnp.zeros((), jnp.int32),
-            "logprobs": jnp.zeros((rollout_steps, num_envs), jnp.float32),
-            "values": jnp.zeros((rollout_steps, num_envs), jnp.float32),
-        }
-        carry_nbytes = carry_bytes(carry["state"])
-        diag.register_footprint("policy_carry", carry_nbytes)
-        episode_positions = np.zeros(num_envs, np.int64)  # the host's mirror of the caches' lengths
-    else:
-        hx = jnp.zeros((num_envs, hidden), jnp.float32)
-        cx = jnp.zeros((num_envs, hidden), jnp.float32)
-        prev_actions_np = np.zeros((num_envs, act_sum), np.float32)
+    player.start(diag, keys, num_envs, rollout_steps, seq_len)  # after the train step is instrumented
 
     for iter_num in range(start_iter, total_iters + 1):
         with timer("Time/env_interaction_time"), diag.span("rollout"):
-            snapshots = []
             for step in range(rollout_steps):
                 policy_step_count += num_envs
                 diag.note_env_steps(num_envs)
-                if token_policy:
-                    if step % seq_len == 0:
-                        # where a training sequence starts: the learner's constant, one copy on the device
-                        snapshots.append(snapshot_of(carry["state"]))
-                    with diag.span("rollout/obs-stage"):
-                        staged = stage_tokens(obs, prev_dones)
-                    with diag.span("rollout/player-forward"):
-                        actions, carry = policy_step(params, carry, staged)  # the step's one put rides the call
-                    with diag.span("rollout/action-fetch"):
-                        actions_np = np.asarray(actions).reshape(num_envs, 1)  # the step's one fetch
-                    episode_positions = np.where(prev_dones[:, 0] > 0, 0, episode_positions) + 1
-                    diag.note_policy_state(int(prev_dones.sum()), int(episode_positions.sum()), carry_nbytes)
-                else:
-                    with diag.span("rollout/obs-stage"):
-                        rng_key, step_key = jax.random.split(rng_key)
-                        torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
-                        # reset state on done BEFORE stepping (reference resets at episode starts)
-                        if cfg.algo.reset_recurrent_state_on_done and prev_dones.any():
-                            mask = jnp.asarray(1.0 - prev_dones, jnp.float32)
-                            hx = hx * mask
-                            cx = cx * mask
-                            prev_actions_np = prev_actions_np * (1.0 - prev_dones)
-                        hx0_np = np.asarray(hx)
-                        cx0_np = np.asarray(cx)
-                    with diag.span("rollout/player-forward"):
-                        actions, logprobs, values, hx, cx = policy_step(
-                            params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx, step_key
-                        )
-                    with diag.span("rollout/action-fetch"):
-                        actions_np = np.asarray(actions)[0]
-                        logprobs_np = np.asarray(logprobs)[0].reshape(num_envs, -1)
-                        values_np = np.asarray(values)[0].reshape(num_envs, -1)
+                player.begin_step(prev_dones)
+                with diag.span("rollout/obs-stage"):
+                    staged = player.stage(obs, prev_dones)
+                with diag.span("rollout/player-forward"):
+                    actions = player.act(params, staged)
+                with diag.span("rollout/action-fetch"):
+                    actions_np, row_extras = player.fetch(actions)
                 if is_continuous:
                     env_actions = actions_np.reshape(num_envs, -1)
                 elif is_multidiscrete:
@@ -408,15 +269,8 @@ def main(runtime, cfg):
                         "rewards": rewards,
                         "dones": dones,
                         "resets": prev_dones,
+                        **row_extras,
                     }
-                    if not token_policy:  # the token policy keeps these three on the device
-                        row.update(
-                            prev_actions=prev_actions_np.reshape(num_envs, -1),
-                            logprobs=logprobs_np,
-                            values=values_np,
-                            hx=hx0_np.reshape(num_envs, -1),
-                            cx=cx0_np.reshape(num_envs, -1),
-                        )
                     step_data: Dict[str, np.ndarray] = step_slab(num_envs, row)
                     rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
@@ -428,31 +282,14 @@ def main(runtime, cfg):
                             aggregator.update("Rewards/rew_avg", float(r))
                             aggregator.update("Game/ep_len_avg", float(l))
 
-                # prev-action input to the RNN is one-hot for discrete heads
-                # (reference ppo_recurrent.py:284,356: dim = sum(actions_dim))
-                if token_policy:
-                    pass  # the observed token is the input; a one-hot over the vocabulary is nobody's
-                elif is_continuous:
-                    prev_actions_np = actions_np.reshape(num_envs, -1).astype(np.float32)
-                else:
-                    onehots = []
-                    for j, d in enumerate(actions_dim):
-                        onehots.append(np.eye(d, dtype=np.float32)[actions_np[:, j].astype(np.int64)])
-                    prev_actions_np = np.concatenate(onehots, axis=-1)
                 prev_dones = dones
                 obs = next_obs
 
         # bootstrap + GAE (reference ppo_recurrent.py:358-396)
         with diag.span("gae"):
             local = {k: np.asarray(rb[k][:rollout_steps]) for k in rb.buffer.keys()}
-            if token_policy:
-                next_values = np.asarray(value_step(params, carry, stage_tokens(obs, prev_dones))).reshape(num_envs, 1)
-                # the rollout's one fetch of what the player stored while decoding
-                local["logprobs"] = np.asarray(carry["logprobs"])[..., None]
-                local["values"] = np.asarray(carry["values"])[..., None]
-            else:
-                torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
-                next_values = np.asarray(value_step(params, torch_obs, jnp.asarray(prev_actions_np)[None], hx, cx))[0]
+            next_values, kept = player.end_rollout(params, obs, prev_dones)
+            local.update(kept)
             returns, advantages = gae(
                 jnp.asarray(local["rewards"]),
                 jnp.asarray(local["values"]),
@@ -476,15 +313,8 @@ def main(runtime, cfg):
                 .swapaxes(0, 1)
             )
 
-        data = {k: to_seq(local[k]) for k in local.keys() if k not in ("hx", "cx")}
-        if token_policy:
-            # initial state of each sequence = the player's where it starts (sequence s = chunk * N + env)
-            data["state0"] = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0)[None], *snapshots)
-            del snapshots
-        else:
-            # initial LSTM state of each sequence = stored state at its first step
-            data["hx0"] = to_seq(local["hx"])[:1]
-            data["cx0"] = to_seq(local["cx"])[:1]
+        data = player.initial_state(local)  # what each sequence starts from; takes out of `local` what only it reads
+        data.update({k: to_seq(v) for k, v in local.items()})
         device_data = jax.tree_util.tree_map(jnp.asarray, data)
         if world_size > 1:
             from sheeprl_tpu.parallel.mesh import replicated_sharding
@@ -504,7 +334,7 @@ def main(runtime, cfg):
 
         with timer("Time/train_time"):
             with diag.span("train"):
-                rng_key, train_key = jax.random.split(rng_key)
+                train_key = keys.next()
                 coefs = (
                     jnp.asarray(clip_coef, jnp.float32),
                     jnp.asarray(ent_coef, jnp.float32),
@@ -561,8 +391,7 @@ def main(runtime, cfg):
                 diag.on_preempted(policy_step_count, iter_num, ckpt_path)
 
     envs.close()
-    if runtime.is_global_zero and cfg.algo.run_test and not token_policy:  # the greedy test episode is the LSTM's
-        test_env = make_env(cfg, cfg.seed, 0, log_dir, "test", vector_env_idx=0)()
-        cumulative_rew = test(agent.apply, params, test_env, runtime, cfg, log_dir)
+    cumulative_rew = player.test(params, log_dir) if runtime.is_global_zero and cfg.algo.run_test else None
+    if cumulative_rew is not None:
         logger.log_metrics({"Test/cumulative_reward": cumulative_rew}, policy_step_count)
     logger.finalize()

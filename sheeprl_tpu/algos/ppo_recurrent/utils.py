@@ -4,8 +4,8 @@ from __future__ import annotations
 
 from typing import Dict, Sequence
 
+import gymnasium as gym
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.algos.ppo.utils import prepare_obs as _ppo_prepare_obs
@@ -28,46 +28,33 @@ def prepare_obs(
     return {k: v[None] for k, v in out.items()}
 
 
-def test(agent_apply, params, env, runtime, cfg, log_dir: str) -> float:
-    """One greedy episode carrying LSTM state (reference utils.py:19-66)."""
+class KeyStream:
+    """The loop's random stream, shared with its player: every ``next()`` splits one key off."""
+
+    def __init__(self, key: jax.Array):
+        self.key = key
+
+    def next(self) -> jax.Array:
+        self.key, out = jax.random.split(self.key)
+        return out
+
+
+def test(player, params, env, cfg) -> float:
+    """One greedy episode through the player, at one env (reference utils.py:19-66)."""
+    player.start(None, KeyStream(jax.random.PRNGKey(cfg.seed or 0)), num_envs=1, rollout_steps=1, seq_len=1)
     done = False
     cumulative_rew = 0.0
     obs, _ = env.reset(seed=cfg.seed)
-    cnn_keys = cfg.algo.cnn_keys.encoder
-    mlp_keys = cfg.algo.mlp_keys.encoder
-    hidden = cfg.algo.rnn.lstm.hidden_size
-    hx = jnp.zeros((1, hidden), jnp.float32)
-    cx = jnp.zeros((1, hidden), jnp.float32)
-    import gymnasium as gym
-
-    if isinstance(env.action_space, gym.spaces.Discrete):
-        actions_dim = [int(env.action_space.n)]
-    elif isinstance(env.action_space, gym.spaces.MultiDiscrete):
-        actions_dim = [int(d) for d in env.action_space.nvec]
-    else:
-        actions_dim = list(env.action_space.shape)
-    act_sum = int(np.sum(actions_dim))
-    prev_actions = jnp.zeros((1, 1, act_sum), jnp.float32)
-    key = jax.random.PRNGKey(cfg.seed or 0)
+    is_first = np.zeros((1, 1), np.float32)
     while not done:
-        torch_obs = prepare_obs(obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys)
-        actions, _, _, _, (hx, cx) = agent_apply(
-            params, torch_obs, prev_actions, hx, cx, key=key, greedy=True
-        )
-        actions_np = np.asarray(actions)
+        player.begin_step(is_first)
+        actions, _ = player.fetch(player.act(params, player.stage(obs, is_first)))
         if isinstance(env.action_space, gym.spaces.Box):
-            prev_actions = actions
-            env_actions = actions_np.reshape(env.action_space.shape)
+            env_actions = actions.reshape(env.action_space.shape)
+        elif isinstance(env.action_space, gym.spaces.Discrete):
+            env_actions = int(actions[0, 0])
         else:
-            onehots = [
-                np.eye(d, dtype=np.float32)[actions_np[0, :, j].astype(np.int64)]
-                for j, d in enumerate(actions_dim)
-            ]
-            prev_actions = jnp.asarray(np.concatenate(onehots, axis=-1))[None]
-            if isinstance(env.action_space, gym.spaces.Discrete):
-                env_actions = int(actions_np[0, 0, 0])
-            else:
-                env_actions = actions_np[0, 0].astype(np.int64)
+            env_actions = actions[0].astype(np.int64)
         obs, reward, terminated, truncated, _ = env.step(env_actions)
         done = bool(terminated or truncated)
         cumulative_rew += float(reward)

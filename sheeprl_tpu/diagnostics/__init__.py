@@ -894,7 +894,7 @@ class Diagnostics:
 
 def build_diagnostics(cfg: Optional[Mapping[str, Any]]) -> Diagnostics:
     """Construct the facade from a composed run config (never raises on a
-    missing ``diagnostics`` section — direct entrypoint callers like bench.py
+    missing ``diagnostics`` section — direct callers with a partial config
     simply get a disabled facade).  Installs the process-wide compile-event
     listener early so compiles that happen before the run dir exists (agent
     build, warmup jits) are still counted."""

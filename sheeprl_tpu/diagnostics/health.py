@@ -57,7 +57,7 @@ class HealthSpec(NamedTuple):
 def health_spec(cfg: Mapping[str, Any]) -> HealthSpec:
     """Extract the :class:`HealthSpec` from a composed run config.
 
-    Tolerates configs without a ``diagnostics`` section (bench.py and the HLO
+    Tolerates configs without a ``diagnostics`` section (the HLO
     tests compose partial configs and call ``make_train_step`` directly):
     missing means disabled, which keeps those compiled graphs byte-identical.
     """

@@ -819,59 +819,3 @@ class MemoryMonitor:
         if per_device:
             out["components_per_device"] = per_device
         return out
-
-
-# ---------------------------------------------------------------------------
-# stderr-capture transfer counting (bench.py)
-
-
-def count_guard_log_lines(fn: Callable[[], Any]) -> Tuple[Any, Optional[int]]:
-    """Run ``fn`` under ``jax.transfer_guard("log")`` while capturing fd-level
-    stderr, and count the runtime's transfer-log lines.
-
-    The guard logs from C++ (not via Python logging), so the only faithful
-    counter is the file descriptor itself.  Used by ``bench.py`` around its
-    bounded headline stage — NOT in the training hot loop, where hijacking
-    fd 2 would eat tracebacks.  Returns ``(result, count)``; count is None
-    when the capture could not be set up (the result still lands).
-    """
-    import re
-    import sys
-    import tempfile
-
-    import jax
-
-    try:
-        sys.stderr.flush()
-        saved_fd = os.dup(2)
-        tmp = tempfile.TemporaryFile(mode="w+b")
-        os.dup2(tmp.fileno(), 2)
-    except Exception:
-        with jax.transfer_guard("log"):
-            return fn(), None
-    try:
-        with jax.transfer_guard("log"):
-            result = fn()
-    finally:
-        # restore fd 2 FIRST, then replay everything captured — especially
-        # when fn raised: the runtime's error output written during the
-        # stage must reach the real stderr, not vanish with the temp file
-        sys.stderr.flush()
-        os.dup2(saved_fd, 2)
-        os.close(saved_fd)
-        try:
-            tmp.seek(0)
-            text = tmp.read().decode(errors="replace")
-            if text:
-                sys.stderr.write(text)
-                sys.stderr.flush()
-        except Exception:
-            text = None
-        finally:
-            tmp.close()
-    if text is None:
-        return result, None
-    # host crossings only: device-to-device copies (resharding) are logged by
-    # the guard too but are not host transfers
-    count = len(re.findall(r"(host-to-device|device-to-host) transfer", text))
-    return result, count

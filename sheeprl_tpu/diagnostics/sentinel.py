@@ -47,7 +47,7 @@ class SentinelSpec(NamedTuple):
 def sentinel_spec(cfg: Mapping[str, Any]) -> SentinelSpec:
     """Extract the :class:`SentinelSpec` from a composed run config.
 
-    Tolerates configs without a ``diagnostics`` section (bench.py and the HLO
+    Tolerates configs without a ``diagnostics`` section (the HLO
     tests compose partial configs and call ``make_train_step`` directly):
     missing means disabled, which keeps those compiled graphs byte-identical.
     """

@@ -2,8 +2,8 @@
 
 The run-health subsystem (journal/sentinel/tracing, ISSUE 1) answers "is the
 run *healthy*?"; this module answers "is the run *fast*?" — continuously, from
-inside the run itself, instead of from offline ``bench.py`` snapshots
-(PERF.md's numbers).  Three mechanisms, all behind the ``Diagnostics`` facade:
+inside the run itself (the benchmark's numbers are ``benchmarks/chip/``'s,
+from a profiler trace: PERF.md).  Three mechanisms, all behind the ``Diagnostics`` facade:
 
 * **Recompilation watchdog** — the training loops wrap their jitted train /
   rollout steps with :meth:`Telemetry.instrument`.  Every dispatch computes
@@ -69,8 +69,8 @@ from sheeprl_tpu.diagnostics.tracing import is_part, profiler_annotation
 
 TELEMETRY_PREFIX = "Telemetry/"
 
-# Peak dense-matmul FLOP/s per chip by device kind (same table as bench.py's
-# `_chip_peak`, kept self-contained so telemetry never imports the bench).
+# Peak dense-matmul FLOP/s per chip by device kind (the live gauge's own table;
+# the benchmark's is ``benchmarks/chip/peaks.json``).
 # Unknown kinds (CPU, forced-host platforms) resolve to None: MFU is then
 # only reported when `telemetry.mfu.peak_tflops_per_device` is set — an
 # unknown denominator would make the gauge silently wrong, not conservative.
@@ -847,22 +847,6 @@ class Telemetry:
             return self._phase_total.get("train", 0.0)
 
     # -- phase spans -------------------------------------------------------
-    def span(self, name: str):
-        """Standalone span context manager (the facade routes its ``span``
-        through ``span_enter``/``span_exit`` directly; bench.py uses this to
-        produce the same phase accounting without a facade)."""
-        from contextlib import contextmanager
-
-        @contextmanager
-        def _span():
-            token = self.span_enter(name)
-            try:
-                yield
-            finally:
-                self.span_exit(token)
-
-        return _span()
-
     def span_enter(self, name: str) -> List:
         rec = [name, self._clock(), 0.0]  # [name, t0, child seconds]
         if is_part(name):  # off the self-time stack: its phase reads as without it

@@ -194,7 +194,7 @@ class Runtime:
         diagnostics facade carries one (async off-critical-path writer +
         manifest sidecar + ckpt_begin/ckpt_end journaling); otherwise a plain
         synchronous save that still writes the manifest, so resume-time
-        verification works for every producer (eval helpers, tests, bench).
+        verification works for every producer (eval helpers, tests).
 
         Multi-process (``jax.distributed``) saves are *coordinated* group
         snapshots (resilience/coordination.py): barrier → broadcast-agreed

@@ -50,7 +50,7 @@ def host_snapshot(tree: Any):
 class AsyncCheckpointWriter:
     """Background checkpoint writer behind ``ResilienceMonitor.save``.
 
-    ``journal_fn(kind, **fields)`` may be None (direct/bench callers);
+    ``journal_fn(kind, **fields)`` may be None (direct callers);
     ``clock`` is injectable for deterministic tests.
     """
 

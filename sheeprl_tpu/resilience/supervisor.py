@@ -20,8 +20,8 @@ The run name must be pinned for resumes to land in the same run dir; when the
 caller does not pass ``run_name=...`` the supervisor pins the composed
 (timestamped) one and says so.
 
-``--kill-after-first-checkpoint`` is the chaos drill used by the e2e tests
-and ``bench.py``'s recovery block: the supervisor SIGKILLs its *first* child
+``--kill-after-first-checkpoint`` is the chaos drill used by the e2e tests:
+the supervisor SIGKILLs its *first* child
 the moment a verified checkpoint exists, then lets the normal restart path
 prove the whole cycle.
 """

@@ -311,7 +311,7 @@ def _ppo_recurrent_handle(cfg, obs_space, action_space, agent_state) -> PolicyHa
     """ppo_recurrent: the LSTM agent served statefully.  Per-session state is
     ``{hx, cx, prev_actions}``; the step masks all three by ``1 - is_first``
     BEFORE the apply — exactly the host-side reset the training player does
-    (``ppo_recurrent.py``: ``hx *= (1 - dones)`` etc.) — then advances one
+    (``players.py::LSTMPlayer.begin_step``: ``hx *= (1 - dones)`` etc.) — then advances one
     sequence step and rebuilds ``prev_actions`` (one-hot per discrete head,
     raw actions when continuous) for the next request."""
     from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent
@@ -504,8 +504,7 @@ def agent_state_from_checkpoint(state: Mapping[str, Any]) -> Dict[str, Any]:
 
 def build_policy(cfg, obs_space, action_space, agent_state: Optional[Dict[str, Any]] = None) -> PolicyHandle:
     """Adapter dispatch: ``cfg.algo.name`` -> :class:`PolicyHandle` (random
-    init params when ``agent_state`` is None — bench.py serves a throughput
-    probe without any checkpoint)."""
+    init params when ``agent_state`` is None)."""
     algo = str(cfg.algo.name)
     builder = SERVABLE_BUILDERS.get(algo)
     if builder is None:

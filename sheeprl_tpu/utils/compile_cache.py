@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives — the one place that decides.
 
-Every entry point that compiles (the CLI, ``chip_smoke.py``, ``bench.py``,
+Every entry point that compiles (the CLI, ``chip_smoke.py``,
 ``tools/serve.py`` through the CLI) calls :func:`enable_compile_cache` before
 its first compile.  The rule:
 

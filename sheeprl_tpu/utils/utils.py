@@ -188,7 +188,7 @@ def get_diagnostics(runtime, cfg: Mapping[str, Any], log_dir: str):
 
 def subprocess_cli_env(device_count: int | None = None) -> Dict[str, str]:
     """Environment for spawning ``python -m sheeprl_tpu`` children from an
-    arbitrary cwd (chaos drills, bench topology pairs): force the CPU
+    arbitrary cwd (chaos drills): force the CPU
     platform, pin the virtual host-device count — REPLACING any inherited
     pin, so the caller gets the mesh it asked for even under a test
     harness's own ``XLA_FLAGS`` — and prepend this checkout to PYTHONPATH

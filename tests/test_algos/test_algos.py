@@ -352,11 +352,12 @@ def test_ppo_recurrent(devices, env_id):
     assert _checkpoint_paths(), "no checkpoint written"
 
 
-def test_ppo_decoupled():
+@pytest.mark.parametrize("n_devices", ["2", "5"])  # one player and one trainer; one player and a sub-mesh of four
+def test_ppo_decoupled(n_devices):
     _run_cli(
         "exp=ppo_decoupled",
         *COMMON,
-        "fabric.devices=2",
+        f"fabric.devices={n_devices}",
         "fabric.accelerator=cpu",
         "env.id=discrete_dummy",
         "algo.rollout_steps=8",

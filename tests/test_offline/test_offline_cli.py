@@ -1,5 +1,5 @@
 """The offline training mode through the real CLI: config validation, the
-env-construction guard, resume-into-offline overrides, and the slow-marked
+env-construction guard, resume-into-offline overrides, and the
 acceptance drill — tiny SAC collect → export → (planted corrupt shard) →
 env-free offline train → verified final checkpoint with finite losses
 (howto/offline_rl.md)."""
@@ -116,7 +116,6 @@ def test_resume_allows_offline_overrides(tmp_path, monkeypatch):
     assert merged2.algo.gamma == archived["algo"]["gamma"]
 
 
-@pytest.mark.slow
 def test_sac_offline_acceptance_drill(run_cli, tmp_path):
     """The end-to-end offline drill through the real CLI: collect a tiny SAC
     run, export it, plant a corrupt shard, then train env-free — asserting

@@ -138,6 +138,47 @@ def test_supervise_hands_newest_verified_checkpoint_to_restarted_child(tmp_path)
     assert restart["resume_from"] == good
 
 
+#: Stub child of the kill drill: the first attempt leaves a verified checkpoint
+#: and hangs; a restarted one, handed a checkpoint to resume from, completes.
+_CKPT_STUB = """
+import sys, time
+sys.path.insert(0, %r)
+if len(sys.argv) > 2:
+    sys.exit(0)
+import numpy as np
+from sheeprl_tpu.resilience.manifest import save_verified_checkpoint
+save_verified_checkpoint(sys.argv[1], {"agent": {"w": np.ones(2, np.float32)}, "policy_step": 16})
+time.sleep(300)
+""" % (str(REPO_ROOT),)
+
+
+def test_supervise_kill_drill_kills_the_first_child_once_and_the_second_resumes(tmp_path):
+    """``kill_after_first_checkpoint`` with a stub child (the slow e2e below drives a
+    real run): SIGKILL as soon as a verified checkpoint exists, one restart, which is
+    handed that checkpoint and is left alone."""
+    run_dir = tmp_path / "run"
+    ckpt = run_dir / "version_0" / "checkpoint" / "ckpt_16_0.ckpt"
+    ckpt.parent.mkdir(parents=True)
+
+    def argv_builder(resume):
+        return [sys.executable, "-c", _CKPT_STUB, str(ckpt)] + ([str(resume)] if resume else [])
+
+    rc = supervise_command(
+        argv_builder,
+        str(run_dir),
+        max_restarts=2,
+        backoff_base_s=0.0,
+        kill_after_first_checkpoint=True,
+        poll_s=0.05,
+        sleep_fn=lambda s: None,
+    )
+    assert rc == 0
+    events = read_journal(str(run_dir / SUPERVISOR_JOURNAL))
+    (restart,) = [e for e in events if e["event"] == "restart"]
+    assert restart["rc"] == -9 and not restart["preempted"]  # SIGKILL
+    assert restart["resume_from"] == str(ckpt)
+
+
 @pytest.mark.slow
 def test_supervised_sigkill_auto_resume_e2e_with_goodput_report(tmp_path):
     """Acceptance: a supervised training run SIGKILLed mid-training (the

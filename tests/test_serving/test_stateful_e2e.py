@@ -153,9 +153,9 @@ def _run_monitor_module():
 
 def test_ppo_recurrent_http_sessions_bit_identical_to_player():
     """A recurrent policy trained through the real CLI, served over HTTP with
-    N interleaved sessions, against a host-side mirror of the TRAINING
-    player's state handling (keep-mask resets, one-hot prev-action feed,
-    ``ppo_recurrent.py``'s env loop) running the same agent apply: every
+    N interleaved sessions, against the TRAINING player itself
+    (``algos/ppo_recurrent/players.py::LSTMPlayer``, greedy, one env a
+    session: its reset on done, its one-hot prev-action feed): every
     action identical, including the ``reset`` flag mid-episode and the
     re-initialized state after an LRU eviction; the recurrent state equal to
     a few float32 ulp (the served width-2 dispatch and the player's width-1
@@ -175,9 +175,10 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
         assert app.handle.stateful and app.service.sessions is not None
 
         import jax
-        import jax.numpy as jnp
 
         from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent
+        from sheeprl_tpu.algos.ppo_recurrent.players import LSTMPlayer
+        from sheeprl_tpu.algos.ppo_recurrent.utils import KeyStream
         from sheeprl_tpu.envs.env import make_env
         from sheeprl_tpu.utils.checkpoint import load_state
 
@@ -188,35 +189,19 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
             None, (n_actions,), False, cfg, env.observation_space, state["agent"]
         )
         env.close()
-        hidden = int(cfg.algo.rnn.lstm.hidden_size)
 
-        def player_step(mirror_state, obs_row, is_first):
-            """One training-player step at num_envs=1: the same keep-mask
-            reset `ppo_recurrent.py` applies before stepping, the same
-            one-hot prev-action feed for the next step."""
-            keep = 1.0 - is_first
-            hx = jnp.asarray(mirror_state["hx"] * keep)[None]
-            cx = jnp.asarray(mirror_state["cx"] * keep)[None]
-            prev = jnp.asarray(mirror_state["prev"] * keep)[None, None]
-            seq_obs = {"state": jnp.asarray(obs_row, jnp.float32)[None, None]}
-            actions, _, _, _, (new_hx, new_cx) = agent.apply(
-                params, seq_obs, prev, hx, cx, key=jax.random.PRNGKey(0), greedy=True
-            )
-            act_row = np.asarray(actions)[0, 0]
-            one_hot = np.zeros(n_actions, np.float32)
-            one_hot[int(act_row[0])] = 1.0
-            return act_row, {
-                "hx": np.asarray(new_hx)[0],
-                "cx": np.asarray(new_cx)[0],
-                "prev": one_hot,
-            }
+        def fresh_player():
+            player = LSTMPlayer(agent, cfg, greedy=True)
+            player.start(None, KeyStream(jax.random.PRNGKey(0)), num_envs=1, rollout_steps=1, seq_len=1)
+            return player
 
-        def fresh_state():
-            return {
-                "hx": np.zeros(hidden, np.float32),
-                "cx": np.zeros(hidden, np.float32),
-                "prev": np.zeros(n_actions, np.float32),
-            }
+        def player_step(player, obs_row, is_first):
+            """One step of the loop's rollout at num_envs=1, as the loop
+            drives its player."""
+            dones = np.full((1, 1), is_first, np.float32)
+            player.begin_step(dones)
+            actions, _ = player.fetch(player.act(params, player.stage({"state": obs_row}, dones)))
+            return actions[0]
 
         # interleaved sessions over capacity 2: c's arrival evicts b, b's
         # return evicts a, a's return evicts c — each return re-inits
@@ -230,19 +215,20 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
             ("b", False),  # returns as a NEW session; evicts a
             ("a", False),  # returns as a NEW session; evicts c
         ]
-        mirror: "OrderedDict[str, dict]" = OrderedDict()
+        mirror: "OrderedDict[str, LSTMPlayer]" = OrderedDict()
         rng = np.random.default_rng(7)
         for step_no, (sid, reset) in enumerate(ops):
             obs_row = rng.standard_normal(10).astype(np.float32)
             if sid in mirror:
                 mirror.move_to_end(sid)
-                ref_state = mirror[sid]
+                player = mirror[sid]
             else:
                 if len(mirror) >= 2:
                     mirror.popitem(last=False)
-                ref_state = fresh_state()
+                player = fresh_player()
             is_first = 1.0 if (reset or sid not in mirror) else 0.0
-            ref_action, mirror[sid] = player_step(ref_state, obs_row, is_first)
+            mirror[sid] = player
+            ref_action = player_step(player, obs_row, is_first)
 
             response = _post_act(
                 url, {"state": obs_row.tolist()}, session=sid, reset=reset
@@ -263,13 +249,13 @@ def test_ppo_recurrent_http_sessions_bit_identical_to_player():
         for sid in ("b", "a"):
             slot = store._lru[sid]
             np.testing.assert_allclose(
-                np.asarray(store.slab["hx"])[slot], mirror[sid]["hx"], rtol=1e-5, atol=1e-7
+                np.asarray(store.slab["hx"])[slot], np.asarray(mirror[sid].hx)[0], rtol=1e-5, atol=1e-7
             )
             np.testing.assert_allclose(
-                np.asarray(store.slab["cx"])[slot], mirror[sid]["cx"], rtol=1e-5, atol=1e-7
+                np.asarray(store.slab["cx"])[slot], np.asarray(mirror[sid].cx)[0], rtol=1e-5, atol=1e-7
             )
             np.testing.assert_array_equal(
-                np.asarray(store.slab["prev_actions"])[slot], mirror[sid]["prev"]
+                np.asarray(store.slab["prev_actions"])[slot], mirror[sid].prev_actions[0]
             )
 
         health = _get_json(url, "/healthz")

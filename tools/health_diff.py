@@ -3,8 +3,8 @@
 metric trajectories under configurable tolerance bands.
 
 The CI primitive for "did this PR change learning?": point it at a baseline
-run's journal and a candidate run's journal (e.g. two ``bench.py``-launched
-drills, or two real training runs of the same experiment) and it exits
+run's journal and a candidate run's journal (two training runs of the same
+experiment) and it exits
 **non-zero iff a watched trajectory leaves its tolerance band**:
 
 * each watched metric present in BOTH journals is resampled to ``--points``

@@ -33,7 +33,6 @@ PY_ROOT_FILES = (
     "sheeprl.py",
     "sheeprl_eval.py",
     "sheeprl_model_manager.py",
-    "bench.py",
     "chip_smoke.py",
     "__graft_entry__.py",
 )
