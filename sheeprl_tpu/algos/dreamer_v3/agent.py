@@ -11,8 +11,16 @@ re-expressed functionally:
   agent.py:1190-1235) but simply *the same or EMA'd params values*;
 - convolutions run NHWC (XLA-native TPU layout); the CHW buffer convention is
   transposed once inside the graph;
-- the T-step dynamic unroll and H-step imagination are `jax.lax.scan` bodies
-  built in the train step (../dreamer_v3/dreamer_v3.py), not Python loops;
+- the T-step dynamic unroll and H-step imagination are `jax.lax.scan` loops,
+  not Python loops.  The dynamic scan's body (`RSSM.scan_step`, driven by
+  `utils.py::dynamic_learning_scan`) holds only what depends on its carry
+  `(posterior, recurrent)`: the state's half of the two input products, the
+  LayerNorm-GRU and the representation head.  What does not — the action's
+  and the observation's rows of those products (`RSSM.scan_projections`), the
+  learned initial state, the draws' Gumbel noise (`RSSM.scan_noise`) and the
+  whole prior head (`RSSM.prior_logits`) — runs once on all `T x B` rows,
+  before or after the loop.  `RSSM.dynamic` is the same mathematics one step
+  at a time (the init path and the tests' reference);
 - stochastic states are kept flattened [..., stochastic*discrete] and
   reshaped at the categorical boundaries.
 """
@@ -54,14 +62,29 @@ class DenseStack(nn.Module):
     layer_norm: bool = True
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, tail: Optional[jax.Array] = None) -> jax.Array:
+        """With ``tail``, ``x`` is only the leading columns of the stack's
+        input ``concat(x, u)`` and ``tail`` is ``u``'s product with the first
+        kernel's remaining rows, computed elsewhere (`tail_product`)."""
         fn = get_activation(self.act)
-        for _ in range(self.layers):
-            x = nn.Dense(self.units, use_bias=not self.layer_norm, kernel_init=trunc_normal_init)(x)
+        for i in range(self.layers):
+            dense = nn.Dense(self.units, use_bias=not self.layer_norm, kernel_init=trunc_normal_init)
+            if i == 0 and tail is not None:
+                p = dense.variables["params"]
+                x = x @ p["kernel"][: x.shape[-1]] + tail
+                x = x + p["bias"] if dense.use_bias else x
+            else:
+                x = dense(x)
             if self.layer_norm:
                 x = nn.LayerNorm(epsilon=self.eps)(x)
             x = fn(x)
         return x
+
+
+def tail_product(stack_params, u: jax.Array) -> jax.Array:
+    """``u``'s rows of a `DenseStack`'s first product over ``concat(x, u)``:
+    the ``tail`` its call takes (a bias, where there is one, stays with the call)."""
+    return u @ stack_params["Dense_0"]["kernel"][-u.shape[-1] :]
 
 
 class CNNEncoderDV3(nn.Module):
@@ -187,8 +210,8 @@ class RecurrentModel(nn.Module):
     fused_gru: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array, recurrent_state: jax.Array) -> jax.Array:
-        feat = DenseStack(self.dense_units, 1, self.eps, self.act, self.layer_norm)(x)
+    def __call__(self, x: jax.Array, recurrent_state: jax.Array, tail: Optional[jax.Array] = None) -> jax.Array:
+        feat = DenseStack(self.dense_units, 1, self.eps, self.act, self.layer_norm)(x, tail)
         return LayerNormGRUCell(
             hidden_size=self.recurrent_state_size,
             use_bias=not self.gru_layer_norm,
@@ -211,13 +234,24 @@ def _unimix(logits: jax.Array, discrete: int, unimix: float) -> jax.Array:
     return logits.reshape(shape)
 
 
-def compute_stochastic_state(logits: jax.Array, discrete: int, key: Optional[jax.Array], sample: bool = True):
+def compute_stochastic_state(
+    logits: jax.Array,
+    discrete: int,
+    key: Optional[jax.Array],
+    sample: bool = True,
+    noise: Optional[jax.Array] = None,
+):
     """Straight-through sample of the [stoch, discrete] categorical block,
-    returned flattened (reference algos/dreamer_v2/agent.py compute_stochastic_state)."""
+    returned flattened (reference algos/dreamer_v2/agent.py compute_stochastic_state).
+    ``noise`` stands in for ``key``: the Gumbel noise `jax.random.categorical`
+    would draw from it, drawn by the caller (`RSSM.scan_noise`)."""
     shape = logits.shape
     logits = logits.reshape(shape[:-1] + (-1, discrete))
     if sample:
-        idx = jax.random.categorical(key, logits, axis=-1)
+        if noise is None:
+            idx = jax.random.categorical(key, logits, axis=-1)
+        else:
+            idx = jnp.argmax(noise + logits, axis=-1)
         hard = jax.nn.one_hot(idx, discrete, dtype=logits.dtype)
         probs = jax.nn.softmax(logits, axis=-1)
         out = hard + probs - jax.lax.stop_gradient(probs)  # straight-through
@@ -295,8 +329,11 @@ class RSSM(nn.Module):
         logits = _unimix(self.representation_model(inp), self.discrete_size, self.unimix)
         return logits, compute_stochastic_state(logits, self.discrete_size, key)
 
+    def prior_logits(self, recurrent_out):
+        return _unimix(self.transition_model(recurrent_out), self.discrete_size, self.unimix)
+
     def _transition(self, recurrent_out, key, sample_state: bool = True):
-        logits = _unimix(self.transition_model(recurrent_out), self.discrete_size, self.unimix)
+        logits = self.prior_logits(recurrent_out)
         return logits, compute_stochastic_state(logits, self.discrete_size, key, sample=sample_state)
 
     def dynamic(self, posterior, recurrent_state, action, embedded_obs, is_first, key):
@@ -313,6 +350,48 @@ class RSSM(nn.Module):
         prior_logits, prior = self._transition(recurrent_state, k1)
         posterior_logits, posterior = self._representation(recurrent_state, embedded_obs, k2)
         return recurrent_state, posterior, prior, posterior_logits, prior_logits
+
+    # -- `dynamic` over T steps, split by what depends on the scan's carry --
+    # (driven by utils.py::dynamic_learning_scan; with `prior_logits` on the
+    # stacked recurrent states it is `dynamic`'s mathematics, the two input
+    # products summed as two partial sums)
+    def scan_projections(self, actions, embedded_obs):
+        """Before the loop, on all rows: the actions' rows of the recurrent
+        model's input product and the observation's of the representation
+        model's — with ``decoupled`` the whole representation head, which
+        then reads no state at all."""
+
+        def rows(model, u):
+            return tail_product(model.variables["params"]["DenseStack_0"], u)
+
+        if self.decoupled:
+            obs_rows = _unimix(self.representation_model(embedded_obs), self.discrete_size, self.unimix)
+        else:
+            obs_rows = rows(self.representation_model, embedded_obs)
+        return rows(self.recurrent_model, actions), obs_rows
+
+    def scan_noise(self, keys, rows: int, dtype):
+        """Before the loop: the Gumbel noise of every step's posterior draw,
+        bit for bit what ``dynamic(..., key_t)`` draws inside
+        `jax.random.categorical` from its second sub-key."""
+        shape = (rows, self.stochastic_size, self.discrete_size)
+        return jax.vmap(lambda key: jax.random.gumbel(jax.random.split(key)[1], shape, dtype))(keys)
+
+    def scan_step(self, posterior, recurrent_state, action_rows, obs_rows, is_first, noise, initial_states):
+        """Inside the loop: ``action_rows`` are already masked by
+        ``1 - is_first``; ``initial_states`` is `get_initial_states(())`."""
+        initial_recurrent, initial_posterior = initial_states
+        recurrent_state = (1 - is_first) * recurrent_state + is_first * initial_recurrent
+        posterior = (1 - is_first) * posterior + is_first * initial_posterior
+        recurrent_state = self.recurrent_model(posterior, recurrent_state, tail=action_rows)
+        if self.decoupled:
+            posterior_logits = obs_rows
+        else:
+            posterior_logits = _unimix(
+                self.representation_model(recurrent_state, tail=obs_rows), self.discrete_size, self.unimix
+            )
+        posterior = compute_stochastic_state(posterior_logits, self.discrete_size, None, noise=noise)
+        return recurrent_state, posterior, posterior_logits
 
     def imagination(self, prior, recurrent_state, actions, key):
         """One-step latent imagination (reference agent.py:478-498)."""
@@ -335,8 +414,8 @@ class _StochHead(nn.Module):
     head_scale: float = 1.0
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        x = DenseStack(self.hidden_size, 1, self.eps, self.act, self.layer_norm)(x)
+    def __call__(self, x: jax.Array, tail: Optional[jax.Array] = None) -> jax.Array:
+        x = DenseStack(self.hidden_size, 1, self.eps, self.act, self.layer_norm)(x, tail)
         init = uniform_init(self.head_scale) if self.head_scale != -1 else trunc_normal_init
         return nn.Dense(self.out_size, kernel_init=init)(x)
 

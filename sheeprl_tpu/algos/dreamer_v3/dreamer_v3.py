@@ -5,7 +5,14 @@ The reference's two hot loops are Python ``for`` loops over GRU cells
 (dynamic learning over T≈64 steps, dreamer_v3.py:134-145; imagination over
 H=15, :235-241).  Here each gradient step is ONE jitted XLA graph:
 
-- dynamic learning = `lax.scan` over the sequence axis;
+- dynamic learning = `lax.scan` over the sequence axis, whose body holds only
+  what depends on its carry `(posterior, recurrent)`: the state's half of the
+  recurrent and representation models' input products, the LayerNorm-GRU and
+  the representation head.  The action's and the embedded observation's
+  halves of those products, the learned initial state, the draws' noise
+  (before the loop) and the whole prior head (after it, on the stacked
+  recurrent states) run once on all T x B rows
+  (`utils.py::dynamic_learning_scan`, shared with the JEPA and P2E steps);
 - imagination = `lax.scan` over the horizon **inside the actor loss**, so
   gradients flow through the dynamics for continuous control exactly as the
   reference's autograd tape does;
@@ -39,7 +46,7 @@ from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
 from sheeprl_tpu.algos.dreamer_v3.utils import (  # noqa: F401
     AGGREGATOR_KEYS,
     MODELS_TO_REGISTER,
-    chunked_dynamic_scan,
+    dynamic_learning_scan,
     init_moments_state,
     prepare_obs,
     rssm_scan_spec,
@@ -104,7 +111,7 @@ def make_train_step(
     # chunked sequence-parallel RSSM scan (PERF.md §5): split the T-step
     # dynamic-learning scan into K chunks seeded from replay-stored states
     # and fold the chunk axis into the batch axis — the GRU GEMM then runs at
-    # B*K rows.  rssm_chunks=1 is bit-identical to the sequential scan.
+    # B*K rows.  rssm_chunks=1 is the sequential scan.
     rssm_chunks, rssm_burn_in = rssm_scan_spec(cfg)
     gamma = cfg.algo.gamma
     cnn_dec_keys = list(cfg.algo.cnn_keys.decoder)
@@ -159,17 +166,10 @@ def make_train_step(
             with jax.named_scope("encoder"):
                 embedded = world_model_def.apply(wm_params, batch_obs, method="encode")
 
-            def scan_body(carry, x):
-                posterior, recurrent = carry
-                action_t, embed_t, is_first_t, key_t = x
-                recurrent, posterior, _, post_logits, prior_logits = world_model_def.apply(
-                    wm_params, posterior, recurrent, action_t, embed_t, is_first_t, key_t, method="dynamic"
-                )
-                return (posterior, recurrent), (recurrent, posterior, post_logits, prior_logits)
-
             with jax.named_scope("rssm_scan"):
-                recurrents, posteriors, post_logits, prior_logits = chunked_dynamic_scan(
-                    scan_body,
+                recurrents, posteriors, post_logits, prior_logits = dynamic_learning_scan(
+                    world_model_def,
+                    wm_params,
                     batch_actions,
                     embedded,
                     is_first,

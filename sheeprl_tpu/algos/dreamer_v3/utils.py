@@ -56,6 +56,7 @@ def chunked_dynamic_scan(
     stored_posterior: jax.Array | None = None,
     stored_valid: jax.Array | None = None,
     unroll: int = 1,
+    step_inputs=lambda *xs: xs,
 ):
     """Run the T-step dynamic-learning scan, optionally split into ``chunks``
     independent chunks whose initial states come from replay-stored RSSM
@@ -65,10 +66,15 @@ def chunked_dynamic_scan(
     across chunk boundaries for stored — possibly stale — states, the
     SEED-RL/R2D2 playbook).
 
-    ``scan_body`` is the per-step body the callers already wrote:
-    ``((posterior, recurrent), (action_t, embed_t, is_first_t, key_t)) ->
-    ((posterior, recurrent), ys)``.  Returns the stacked ``ys`` pytree in the
-    original ``[T, B, ...]`` layout.
+    ``scan_body`` is the per-step body: ``((posterior, recurrent), x_t) ->
+    ((posterior, recurrent), ys)``.  ``step_inputs`` maps one loop's
+    ``(actions, embedded, is_first, keys)``, each with that loop's leading
+    axis and folded rows, to the ``xs`` the body reads a step of: whatever of
+    a step depends on neither carry is computed there, once for all the
+    loop's rows (`dynamic_learning_scan` below; the default hands the body
+    the four as they are).  ``batch_actions`` and ``embedded`` are any two
+    ``[T, B, ...]`` leaves.  Returns the stacked ``ys`` pytree in the original
+    ``[T, B, ...]`` layout.
 
     * ``chunks == 1`` reproduces today's sequential scan **bit-identically**
       (same zero init, same ``jax.random.split(key, T)`` per-step keys, same
@@ -88,7 +94,7 @@ def chunked_dynamic_scan(
         keys_t = jax.random.split(key, T)
         init = (jnp.zeros((B, stoch_flat), cdt), jnp.zeros((B, recurrent_size), cdt))
         _, ys = jax.lax.scan(
-            scan_body, init, (batch_actions, embedded, is_first, keys_t), unroll=unroll
+            scan_body, init, step_inputs(batch_actions, embedded, is_first, keys_t), unroll=unroll
         )
         return ys
 
@@ -145,7 +151,7 @@ def chunked_dynamic_scan(
         bf = gather_fold(is_first)
         invalid = 1.0 - valid[init_rows].reshape(((K - 1) * B, 1))
         bf = bf.at[0].set(jnp.maximum(bf[0], invalid))
-        xs_burn = (
+        xs_burn = step_inputs(
             gather_fold(batch_actions),
             gather_fold(embedded),
             bf,
@@ -169,9 +175,44 @@ def chunked_dynamic_scan(
     z_init = jnp.concatenate([jnp.zeros((1, B, stoch_flat), cdt), z_rest], axis=0)
     h_init = jnp.concatenate([jnp.zeros((1, B, recurrent_size), cdt), h_rest], axis=0)
     init = (z_init.reshape((K * B, stoch_flat)), h_init.reshape((K * B, recurrent_size)))
-    xs = (fold(batch_actions), fold(embedded), fold(is_first_adj), jax.random.split(k_main, C))
+    xs = step_inputs(fold(batch_actions), fold(embedded), fold(is_first_adj), jax.random.split(k_main, C))
     _, ys = jax.lax.scan(scan_body, init, xs, unroll=unroll)
     return jax.tree_util.tree_map(unfold, ys)
+
+
+def dynamic_learning_scan(world_model_def, wm_params, batch_actions, embedded, is_first, key, *, cdt, **scan_spec):
+    """The dynamic-learning pass of the DV3 family's train steps
+    (DV3/JEPA/P2E): ``(recurrents, posteriors, post_logits, prior_logits)``,
+    each ``[T, B, ...]``, by `RSSM.dynamic`'s mathematics and draws.
+
+    The loop's body (`RSSM.scan_step`) keeps what depends on its carry: the
+    reset, the state's half of the recurrent and representation models' input
+    products, the LayerNorm-GRU, the representation head and the argmax of
+    the draw.  Outside it, once on all rows: the actions' and the embedded
+    observations' halves of those products (before `chunked_dynamic_scan`
+    folds them like any ``[T, B, ...]`` leaf), the learned initial state, each
+    loop's Gumbel noise, and — after it, on the stacked recurrent states — the
+    prior head, which nothing carries and the burn-in loop never needs.
+    ``scan_spec`` is `chunked_dynamic_scan`'s keyword arguments."""
+
+    def rssm(method, *args):
+        return world_model_def.apply(wm_params, *args, method=lambda wm, *a: getattr(wm.rssm, method)(*a))
+
+    action_rows, obs_rows = rssm("scan_projections", batch_actions, embedded)
+    initial_states = rssm("get_initial_states", ())
+
+    def step_inputs(action_rows, obs_rows, is_first, keys):
+        noise = rssm("scan_noise", keys, is_first.shape[1], cdt)
+        return (1 - is_first) * action_rows, obs_rows, is_first, noise
+
+    def scan_body(carry, x):
+        recurrent, posterior, post_logits = rssm("scan_step", *carry, *x, initial_states)
+        return (posterior, recurrent), (recurrent, posterior, post_logits)
+
+    recurrents, posteriors, post_logits = chunked_dynamic_scan(
+        scan_body, action_rows, obs_rows, is_first, key, cdt=cdt, step_inputs=step_inputs, **scan_spec
+    )
+    return recurrents, posteriors, post_logits, rssm("prior_logits", recurrents)
 
 
 AGGREGATOR_KEYS = {

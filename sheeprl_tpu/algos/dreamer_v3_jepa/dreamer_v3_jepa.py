@@ -20,7 +20,7 @@ import optax
 
 from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import _dreamer_main
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
-from sheeprl_tpu.algos.dreamer_v3.utils import chunked_dynamic_scan, rssm_scan_spec, update_moments
+from sheeprl_tpu.algos.dreamer_v3.utils import dynamic_learning_scan, rssm_scan_spec, update_moments
 from sheeprl_tpu.algos.dreamer_v3_jepa.agent import build_agent as _build_agent_full, encoder_subtree
 from sheeprl_tpu.algos.dreamer_v3_jepa.utils import AGGREGATOR_KEYS, MODELS_TO_REGISTER  # noqa: F401
 from sheeprl_tpu.models.jepa import jepa_loss, make_two_views
@@ -129,16 +129,9 @@ def make_train_step(
             jepa_online = cast_floating(jepa_online, cdt)
             embedded = world_model_def.apply(wm_params, batch_obs, method="encode")
 
-            def scan_body(carry, x):
-                posterior, recurrent = carry
-                action_t, embed_t, is_first_t, key_t = x
-                recurrent, posterior, _, post_logits, prior_logits = world_model_def.apply(
-                    wm_params, posterior, recurrent, action_t, embed_t, is_first_t, key_t, method="dynamic"
-                )
-                return (posterior, recurrent), (recurrent, posterior, post_logits, prior_logits)
-
-            recurrents, posteriors, post_logits, prior_logits = chunked_dynamic_scan(
-                scan_body,
+            recurrents, posteriors, post_logits, prior_logits = dynamic_learning_scan(
+                world_model_def,
+                wm_params,
                 batch_actions,
                 embedded,
                 is_first,

@@ -32,7 +32,7 @@ from sheeprl_tpu.algos.dreamer_v3.agent import PlayerDV3  # noqa: F401  (re-expo
 from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import _dreamer_main
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
 from sheeprl_tpu.algos.dreamer_v3.utils import (
-    chunked_dynamic_scan,
+    dynamic_learning_scan,
     init_moments_state,
     rssm_scan_spec,
     test,
@@ -175,16 +175,9 @@ def make_train_step(
             wm_params = cast_floating(wm_params, cdt)
             embedded = world_model_def.apply(wm_params, batch_obs, method="encode")
 
-            def scan_body(carry, x):
-                posterior, recurrent = carry
-                action_t, embed_t, is_first_t, key_t = x
-                recurrent, posterior, _, post_logits, prior_logits = world_model_def.apply(
-                    wm_params, posterior, recurrent, action_t, embed_t, is_first_t, key_t, method="dynamic"
-                )
-                return (posterior, recurrent), (recurrent, posterior, post_logits, prior_logits)
-
-            recurrents, posteriors, post_logits, prior_logits = chunked_dynamic_scan(
-                scan_body,
+            recurrents, posteriors, post_logits, prior_logits = dynamic_learning_scan(
+                world_model_def,
+                wm_params,
                 batch_actions,
                 embedded,
                 is_first,

@@ -2,10 +2,12 @@
 
 The contract under test, layer by layer:
 
-* ``rssm_chunks=1`` is **bit-identical** to the sequential scan — golden
-  tests run the real tiny ``WorldModel.dynamic`` body through
-  ``chunked_dynamic_scan`` and through a hand-inlined ``jax.lax.scan`` (the
-  pre-chunking code) and compare exactly;
+* ``rssm_chunks=1`` is the sequential scan — the golden test runs the
+  train steps' ``dynamic_learning_scan`` (whose loop holds only what depends
+  on its carry) against a hand-inlined ``jax.lax.scan`` of the real tiny
+  one-step ``WorldModel.dynamic``: the same draws exactly, the rest to
+  float32 re-association; with a body of its own ``chunked_dynamic_scan`` at
+  ``chunks=1`` is bit-identical to ``jax.lax.scan``;
 * stored-state slicing: with the exact sequential carries stored per row,
   the chunked scan reproduces the sequential trajectory (deterministic body
   — the per-step RNG key layout legitimately differs once chunks fold into
@@ -32,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sheeprl_tpu.algos.dreamer_v3.utils import RSSM_STATE_KEYS, chunked_dynamic_scan
+from sheeprl_tpu.algos.dreamer_v3.utils import RSSM_STATE_KEYS, chunked_dynamic_scan, dynamic_learning_scan
 
 T, B, Z, H = 8, 3, 6, 5
 A, E = 2, 4
@@ -82,13 +84,14 @@ def _sequential_carries(body, actions, embedded, is_first, key):
 
 
 # ---------------------------------------------------------------------------
-# golden: chunks=1 is bit-identical to the sequential scan
+# golden: chunks=1 is the sequential scan
 
 
-def test_chunks1_bit_identical_with_real_rssm_dynamic():
-    """The real ``WorldModel.dynamic`` body (straight-through categorical
-    sampling and all) through the helper at chunks=1 vs the hand-inlined
-    pre-chunking ``lax.scan`` — exact equality, not allclose."""
+def test_chunks1_equals_hand_inlined_scan_of_real_rssm_dynamic():
+    """The train steps' scan at chunks=1 vs a hand-inlined ``lax.scan`` of the
+    real one-step ``WorldModel.dynamic`` (straight-through categorical
+    sampling and all): the same draws, exactly; logits and recurrent states
+    to float32 re-association (the two input products are summed in halves)."""
     import gymnasium as gym
 
     from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
@@ -142,8 +145,9 @@ def test_chunks1_bit_identical_with_real_rssm_dynamic():
     keys_t = jax.random.split(key, t)
     init = (jnp.zeros((b, stoch_flat)), jnp.zeros((b, rec_size)))
     _, ref = jax.lax.scan(scan_body, init, (actions, embedded, is_first, keys_t))
-    got = chunked_dynamic_scan(
-        scan_body,
+    got = dynamic_learning_scan(
+        wm_def,
+        wm_params,
         actions,
         embedded,
         is_first,
@@ -154,7 +158,9 @@ def test_chunks1_bit_identical_with_real_rssm_dynamic():
         chunks=1,
     )
     for name, r, g in zip(("recurrents", "posteriors", "post_logits", "prior_logits"), ref, got):
-        assert (np.asarray(r) == np.asarray(g)).all(), f"{name} not bit-identical at chunks=1"
+        np.testing.assert_allclose(np.asarray(r), np.asarray(g), rtol=1e-5, atol=1e-6, err_msg=name)
+    # a straight-through value is (one_hot + p) - p: the one-hot to an ulp
+    assert (np.rint(np.asarray(ref[1])) == np.rint(np.asarray(got[1]))).all(), "another draw at chunks=1"
 
 
 def test_chunks1_ignores_stored_state_and_matches_same_unroll():
