@@ -19,20 +19,25 @@ step.  :class:`TokenPlayer` drives any model of ``models/hybrid_lm.py``'s contra
 state is a pytree that stays on the device through the rollout, is donated to
 ``policy_step``, is copied once where a training sequence starts (the learner's constant,
 as ``hx0``/``cx0`` are) and never reaches the host; the rollout's log-probabilities and
-values are kept beside it and fetched once a rollout; resets are in-graph.
+values are kept beside it and fetched once a rollout; resets are in-graph.  Its programs
+read a *view* of the parameters (:func:`make_policy_view`), made once an update and
+dropped before it.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax import linen as nn
 
 from sheeprl_tpu.algos.ppo_recurrent.agent import token_key
 from sheeprl_tpu.algos.ppo_recurrent.utils import prepare_obs, test
 from sheeprl_tpu.envs.env import make_env
 from sheeprl_tpu.models.hybrid_lm import carry_bytes
-from sheeprl_tpu.parallel.precision import cast_floating, compute_dtype_of
+from sheeprl_tpu.parallel.precision import cast_floating, compute_dtype_of, resolve_precision
 
 
 def make_token_player(agent, cfg, rollout_steps: int):
@@ -42,13 +47,13 @@ def make_token_player(agent, cfg, rollout_steps: int):
     the next token and stores its log-probability and the value at the
     rollout's step ``t``; ``value_step`` reads the value of the next
     observation and writes nothing; ``snapshot_of`` copies the carried state
-    where a training sequence starts.  ``staged`` is ``[2, N]`` int32: the
-    observed tokens and the resets."""
-    cdt = compute_dtype_of(cfg)
+    where a training sequence starts.  ``params`` is the player's view of the
+    parameters (:func:`make_policy_view`: the two steps cast nothing);
+    ``staged`` is ``[2, N]`` int32: the observed tokens and the resets."""
 
     def policy_step(params, carry, staged):
         tokens, resets = staged[0][:, None], staged[1][:, None]
-        logits, values, state = agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True)
+        logits, values, state = agent.apply(params, tokens, resets, carry["state"], decode=True)
         key, sample_key = jax.random.split(carry["key"])
         logp_all = jax.nn.log_softmax(logits[:, 0], axis=-1)
         actions = jax.random.categorical(sample_key, logp_all, axis=-1)
@@ -65,12 +70,94 @@ def make_token_player(agent, cfg, rollout_steps: int):
 
     def value_step(params, carry, staged):
         tokens, resets = staged[0][:, None], staged[1][:, None]
-        return agent.apply(cast_floating(params, cdt), tokens, resets, carry["state"], decode=True, write=False)[1][:, 0]
+        return agent.apply(params, tokens, resets, carry["state"], decode=True, write=False)[1][:, 0]
 
     def snapshot_of(state):
         return jax.tree_util.tree_map(jnp.copy, state)
 
     return jax.jit(policy_step, donate_argnums=(1,)), jax.jit(value_step), jax.jit(snapshot_of)
+
+
+def products_round_to_bfloat16(cfg) -> bool:
+    """Whether a float32 product at the default precision takes its operands
+    rounded to bfloat16: the TPU's MXU in one pass, unless ``matmul_precision``
+    asks for more passes.  A CPU's float32 product is exact."""
+    return jax.default_backend() == "tpu" and str(cfg.get("matmul_precision", "default")) in ("default", "bfloat16")
+
+
+def policy_view_dtypes(agent, cfg, num_envs: int):
+    """The abstract parameters, and in their leaves' order the dtype the
+    player's view holds each in (``None``: the parameter itself).
+
+    - ``bf16-mixed`` (parameters wider than the compute dtype): every floating
+      leaf in the compute dtype, the cast ``policy_step`` used to make anew
+      every token;
+    - ``32-true`` where :func:`products_round_to_bfloat16`: as bfloat16 the
+      leaves that are operands of such a product, and nothing else: the
+      kernels of the ``nn.Dense`` modules a decoded token goes through, found
+      by what they are and not by their name (a convolution's taps and the
+      embedding's rows are leaves named ``kernel`` too: multiplied
+      elementwise and gathered, rounding them would change the numbers).
+      A product with one row or one column is none of them: XLA:TPU rewrites
+      it as a multiply and a reduction in float32, which reads all of its
+      operands (the value head's one column; every product of a single env).
+      Rounding once an update what the MXU rounds every token changes no number;
+    - otherwise (``bf16-true``, more passes asked for, off the TPU): none."""
+    through_the_mxu = set()
+
+    def note(next_fun, args, kwargs, context):
+        module = context.module
+        if (isinstance(module, nn.Dense) and context.method_name == "__call__" and module.precision is None
+                and module.features > 1 and math.prod(args[0].shape[:-1]) > 1):
+            through_the_mxu.add(("params",) + tuple(module.path) + ("kernel",))
+        return next_fun(*args, **kwargs)
+
+    with nn.intercept_methods(note):  # abstract all through: nothing is put on the device to find this out
+        shapes = jax.eval_shape(
+            lambda key, tokens: agent.init(key, tokens, tokens, agent.init_state(num_envs), decode=True),
+            jax.ShapeDtypeStruct((2,), jnp.uint32), jax.ShapeDtypeStruct((num_envs, 1), jnp.int32))
+    param_dtype, compute_dtype = (jnp.dtype(d) for d in resolve_precision(cfg.fabric.precision))
+    leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    if compute_dtype != param_dtype:
+        dtypes = [compute_dtype if jnp.issubdtype(x.dtype, jnp.floating) else None for _, x in leaves]
+    elif param_dtype == jnp.float32 and products_round_to_bfloat16(cfg):
+        dtypes = [jnp.dtype(jnp.bfloat16) if tuple(k.key for k in path) in through_the_mxu else None for path, _ in leaves]
+    else:
+        dtypes = [None] * len(leaves)
+    return shapes, dtypes
+
+
+def make_policy_view(agent, cfg, num_envs: int):
+    """``(view_of, nbytes)``: ``view_of(params)`` is the tree ``policy_step``
+    and ``value_step`` read, each leaf in the dtype :func:`policy_view_dtypes`
+    gives it, and ``nbytes`` what it holds on the device beside the parameters.
+    One program (``jit_policy_view``) casts the leaves that change; the others
+    are the parameters' own arrays, not copies.  Where none changes the view
+    *is* the parameters."""
+    shapes, dtypes = policy_view_dtypes(agent, cfg, num_envs)
+    cast_at = [i for i, d in enumerate(dtypes) if d is not None]
+    if not cast_at:
+        return (lambda params: params), 0
+
+    @jax.jit
+    def policy_view(kernels):
+        return [x.astype(dtypes[i]) for i, x in zip(cast_at, kernels)]
+
+    def view_of(params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        for i, x in zip(cast_at, policy_view([leaves[i] for i in cast_at])):
+            leaves[i] = x
+        return treedef.unflatten(leaves)
+
+    sizes = [x.size for x in jax.tree_util.tree_leaves(shapes)]
+    return view_of, int(sum(sizes[i] * dtypes[i].itemsize for i in cast_at))
+
+
+def _view(player, params):
+    """The player's view of ``params``, made when it first meets them: once an update, once more after a resume."""
+    if player.viewed is not params:
+        player.viewed, player.view = params, player.view_of(params)
+    return player.view
 
 
 class TokenPlayer:
@@ -105,6 +192,9 @@ class TokenPlayer:
         }
         self.carry_nbytes = carry_bytes(self.carry["state"])
         diag.register_footprint("policy_carry", self.carry_nbytes)
+        self.view_of, self.view_nbytes = make_policy_view(self.agent, self.cfg, num_envs)
+        diag.register_footprint("policy_view", self.view_nbytes)
+        self.viewed = self.view = None  # the parameters last seen and the view of them; both dropped at a rollout's end
         self.positions = np.zeros(num_envs, np.int64)  # the host's mirror of the caches' lengths
         self.diag, self.num_envs, self.seq_len = diag, num_envs, seq_len
         self.steps, self.snapshots = 0, []  # vector steps taken; the copies where this rollout's sequences start
@@ -115,7 +205,7 @@ class TokenPlayer:
             self.snapshots.append(self.snapshot_of(self.carry["state"]))
         self.steps += 1
         self.positions = np.where(prev_dones[:, 0] > 0, 0, self.positions) + 1
-        self.diag.note_policy_state(int(prev_dones.sum()), int(self.positions.sum()), self.carry_nbytes)
+        self.diag.note_policy_state(int(prev_dones.sum()), int(self.positions.sum()), self.carry_nbytes, self.view_nbytes)
 
     def stage(self, obs, prev_dones):
         """The observed tokens and the resets, staged together: ``[2, N]`` int32, which the
@@ -125,7 +215,7 @@ class TokenPlayer:
         return np.stack([tokens, prev_dones[:, 0]]).astype(np.int32)
 
     def act(self, params, staged):
-        actions, self.carry = self.policy_step(params, self.carry, staged)  # the step's one put rides the call
+        actions, self.carry = self.policy_step(_view(self, params), self.carry, staged)  # the step's one put rides the call
         return actions
 
     def fetch(self, out):
@@ -133,7 +223,9 @@ class TokenPlayer:
         return np.asarray(out).reshape(self.num_envs, 1), {}
 
     def end_rollout(self, params, obs, prev_dones):
-        next_values = np.asarray(self.value_step(params, self.carry, self.stage(obs, prev_dones))).reshape(self.num_envs, 1)
+        next_values = np.asarray(self.value_step(_view(self, params), self.carry, self.stage(obs, prev_dones))).reshape(self.num_envs, 1)
+        # the value is on the host, so nothing reads the view any more: it must not be alive beside the update's temporaries
+        self.viewed = self.view = None
         # the rollout's one fetch of what the player stored while decoding
         return next_values, {k: np.asarray(self.carry[k])[..., None] for k in ("logprobs", "values")}
 

@@ -499,13 +499,14 @@ class Diagnostics:
         if self.telemetry is not None:
             self.telemetry.note_env_steps(n)
 
-    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int) -> None:
+    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int, view_bytes: int = 0) -> None:
         """A sequence policy's carried state, once a vector step: episode
         resets applied to it, the positions its caches hold over all envs, its
-        bytes on the device (``sheeprl_policy_*`` on ``/metrics``).  No-op
-        when telemetry is disabled."""
+        bytes on the device, and those of the player's view of the parameters
+        beside them (``sheeprl_policy_*`` on ``/metrics``).  No-op when
+        telemetry is disabled."""
         if self.telemetry is not None:
-            self.telemetry.note_policy_state(resets, cache_positions, carry_bytes)
+            self.telemetry.note_policy_state(resets, cache_positions, carry_bytes, view_bytes)
 
     def note_loop_order(self, order: str) -> None:
         """Count one iteration under the order it ran in

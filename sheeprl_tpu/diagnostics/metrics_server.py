@@ -149,7 +149,9 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
 
     # a sequence policy's carried state (Diagnostics.note_policy_state): absent where no loop reports one
     policy_state = snapshot.get("policy_state") or {}
-    for key, mtype in (("state_resets_total", "counter"), ("cache_positions", "gauge"), ("carry_bytes", "gauge")):
+    for key, mtype in (
+        ("state_resets_total", "counter"), ("cache_positions", "gauge"), ("carry_bytes", "gauge"), ("view_bytes", "gauge")
+    ):
         if key in policy_state:
             emit("policy_" + key, mtype, policy_state[key])
 

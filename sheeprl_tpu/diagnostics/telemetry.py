@@ -761,14 +761,15 @@ class Telemetry:
             self._env_steps_interval += int(n)
             self._env_steps_total += int(n)
 
-    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int) -> None:
+    def note_policy_state(self, resets: int, cache_positions: int, carry_bytes: int, view_bytes: int = 0) -> None:
         """A sequence policy's carried state (``ppo_recurrent`` with a
-        language-model backbone): the resets counted, the other two as they stand."""
+        language-model backbone): the resets counted, the others as they stand."""
         with self._lock:
             self._policy_state = {
                 "state_resets_total": self._policy_state.get("state_resets_total", 0) + int(resets),
                 "cache_positions": int(cache_positions),
                 "carry_bytes": int(carry_bytes),
+                "view_bytes": int(view_bytes),
             }
 
     def note_loop_order(self, order: str) -> None:
