@@ -19,8 +19,12 @@ re-expressed functionally:
   and the observation's rows of those products (`RSSM.scan_projections`), the
   learned initial state, the draws' Gumbel noise (`RSSM.scan_noise`) and the
   whole prior head (`RSSM.prior_logits`) — runs once on all `T x B` rows,
-  before or after the loop.  `RSSM.dynamic` is the same mathematics one step
-  at a time (the init path and the tests' reference);
+  before or after the loop.  The transposed loop likewise holds the four
+  products' input gradients and no kernel's: each of those is one product
+  over all `T x B` rows after it, from the inputs and the output cotangents
+  the loops stack (`tap_product` marks the four products for
+  `utils.py::scan_kernel_gradients_after`).  `RSSM.dynamic` is the same
+  mathematics one step at a time (the init path and the tests' reference);
 - stochastic states are kept flattened [..., stochastic*discrete] and
   reshaped at the categorical boundaries.
 """
@@ -36,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from sheeprl_tpu.models.blocks import LayerNormGRUCell, get_activation
+from sheeprl_tpu.models.blocks import LayerNormGRUCell, get_activation, tap_product
 from sheeprl_tpu.ops.numerics import symlog
 
 # Hafner initializers (reference algos/dreamer_v3/utils.py:143-188)
@@ -65,13 +69,14 @@ class DenseStack(nn.Module):
     def __call__(self, x: jax.Array, tail: Optional[jax.Array] = None) -> jax.Array:
         """With ``tail``, ``x`` is only the leading columns of the stack's
         input ``concat(x, u)`` and ``tail`` is ``u``'s product with the first
-        kernel's remaining rows, computed elsewhere (`tail_product`)."""
+        kernel's remaining rows, computed elsewhere (`tail_product`); what is
+        left here is a product a loop carries, and is marked as one."""
         fn = get_activation(self.act)
         for i in range(self.layers):
             dense = nn.Dense(self.units, use_bias=not self.layer_norm, kernel_init=trunc_normal_init)
             if i == 0 and tail is not None:
                 p = dense.variables["params"]
-                x = x @ p["kernel"][: x.shape[-1]] + tail
+                x = tap_product(self, dense.name, x, x @ p["kernel"][: x.shape[-1]]) + tail
                 x = x + p["bias"] if dense.use_bias else x
             else:
                 x = dense(x)
@@ -379,7 +384,10 @@ class RSSM(nn.Module):
 
     def scan_step(self, posterior, recurrent_state, action_rows, obs_rows, is_first, noise, initial_states):
         """Inside the loop: ``action_rows`` are already masked by
-        ``1 - is_first``; ``initial_states`` is `get_initial_states(())`."""
+        ``1 - is_first``; ``initial_states`` is `get_initial_states(())`.
+        Its four products are marked (`tap_product` in `DenseStack`,
+        `LayerNormGRUCell` and `_StochHead`): their kernels' gradients are
+        taken after the loop."""
         initial_recurrent, initial_posterior = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * initial_recurrent
         posterior = (1 - is_first) * posterior + is_first * initial_posterior
@@ -417,7 +425,8 @@ class _StochHead(nn.Module):
     def __call__(self, x: jax.Array, tail: Optional[jax.Array] = None) -> jax.Array:
         x = DenseStack(self.hidden_size, 1, self.eps, self.act, self.layer_norm)(x, tail)
         init = uniform_init(self.head_scale) if self.head_scale != -1 else trunc_normal_init
-        return nn.Dense(self.out_size, kernel_init=init)(x)
+        head = nn.Dense(self.out_size, kernel_init=init)
+        return tap_product(self, head.name, x, head(x))
 
 
 class PredictionHead(nn.Module):

@@ -11,7 +11,8 @@ H=15, :235-241).  Here each gradient step is ONE jitted XLA graph:
   the representation head.  The action's and the embedded observation's
   halves of those products, the learned initial state, the draws' noise
   (before the loop) and the whole prior head (after it, on the stacked
-  recurrent states) run once on all T x B rows
+  recurrent states) run once on all T x B rows, and so do, after the
+  transposed loop, the gradients of the four kernels the body multiplies by
   (`utils.py::dynamic_learning_scan`, shared with the JEPA and P2E steps);
 - imagination = `lax.scan` over the horizon **inside the actor loss**, so
   gradients flow through the dynamics for continuous control exactly as the

@@ -10,11 +10,15 @@ explicit ``lax.all_gather`` over the data axis before ``jnp.quantile``
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax.traverse_util import flatten_dict, unflatten_dict
+
+from sheeprl_tpu.models.blocks import PRODUCT_INPUTS, PRODUCT_PROBES, PRODUCT_SUFFIX
 
 #: Replay keys carrying the player's post-step RSSM state when
 #: ``algo.rssm_chunks > 1`` (SEED-RL/R2D2-style stored-state chunking):
@@ -41,7 +45,7 @@ def rssm_scan_spec(cfg) -> Tuple[int, int]:
 
 
 def chunked_dynamic_scan(
-    scan_body,
+    scan,
     batch_actions: jax.Array,
     embedded: jax.Array,
     is_first: jax.Array,
@@ -55,7 +59,6 @@ def chunked_dynamic_scan(
     stored_recurrent: jax.Array | None = None,
     stored_posterior: jax.Array | None = None,
     stored_valid: jax.Array | None = None,
-    unroll: int = 1,
     step_inputs=lambda *xs: xs,
 ):
     """Run the T-step dynamic-learning scan, optionally split into ``chunks``
@@ -66,15 +69,20 @@ def chunked_dynamic_scan(
     across chunk boundaries for stored — possibly stale — states, the
     SEED-RL/R2D2 playbook).
 
-    ``scan_body`` is the per-step body: ``((posterior, recurrent), x_t) ->
-    ((posterior, recurrent), ys)``.  ``step_inputs`` maps one loop's
-    ``(actions, embedded, is_first, keys)``, each with that loop's leading
-    axis and folded rows, to the ``xs`` the body reads a step of: whatever of
-    a step depends on neither carry is computed there, once for all the
-    loop's rows (`dynamic_learning_scan` below; the default hands the body
-    the four as they are).  ``batch_actions`` and ``embedded`` are any two
-    ``[T, B, ...]`` leaves.  Returns the stacked ``ys`` pytree in the original
-    ``[T, B, ...]`` layout.
+    ``scan`` runs one loop, ``(init, xs) -> (carry, ys)`` over ``xs``' leading
+    axis with the carry ``(posterior, recurrent)``: for a per-step body
+    ``(carry, x_t) -> (carry, ys_t)`` it is ``functools.partial(jax.lax.scan,
+    body)``.  Whoever builds it chooses its unrolling and what its backward
+    keeps in the loop (`dynamic_learning_scan`'s takes the kernels' gradients
+    out of it); it is called for the burn-in rows, whose carry's gradient is
+    stopped here so that their backward never runs, and for the chunks.
+    ``step_inputs`` maps one loop's ``(actions, embedded, is_first, keys)``,
+    each with that loop's leading axis and folded rows, to the ``xs`` the body
+    reads a step of: whatever of a step depends on neither carry is computed
+    there, once for all the loop's rows (`dynamic_learning_scan` below; the
+    default hands the body the four as they are).  ``batch_actions`` and
+    ``embedded`` are any two ``[T, B, ...]`` leaves.  Returns the stacked
+    ``ys`` pytree in the original ``[T, B, ...]`` layout.
 
     * ``chunks == 1`` reproduces today's sequential scan **bit-identically**
       (same zero init, same ``jax.random.split(key, T)`` per-step keys, same
@@ -93,9 +101,7 @@ def chunked_dynamic_scan(
     if chunks <= 1:
         keys_t = jax.random.split(key, T)
         init = (jnp.zeros((B, stoch_flat), cdt), jnp.zeros((B, recurrent_size), cdt))
-        _, ys = jax.lax.scan(
-            scan_body, init, step_inputs(batch_actions, embedded, is_first, keys_t), unroll=unroll
-        )
+        _, ys = scan(init, step_inputs(batch_actions, embedded, is_first, keys_t))
         return ys
 
     K = int(chunks)
@@ -157,7 +163,7 @@ def chunked_dynamic_scan(
             bf,
             jax.random.split(k_burn, burn_in),
         )
-        (z_fresh, h_fresh), _ = jax.lax.scan(scan_body, (z0, h0), xs_burn, unroll=unroll)
+        (z_fresh, h_fresh), _ = scan((z0, h0), xs_burn)
         z_rest = jax.lax.stop_gradient(z_fresh).reshape((K - 1, B, stoch_flat))
         h_rest = jax.lax.stop_gradient(h_fresh).reshape((K - 1, B, recurrent_size))
         is_first_adj = is_first
@@ -176,11 +182,83 @@ def chunked_dynamic_scan(
     h_init = jnp.concatenate([jnp.zeros((1, B, recurrent_size), cdt), h_rest], axis=0)
     init = (z_init.reshape((K * B, stoch_flat)), h_init.reshape((K * B, recurrent_size)))
     xs = step_inputs(fold(batch_actions), fold(embedded), fold(is_first_adj), jax.random.split(k_main, C))
-    _, ys = jax.lax.scan(scan_body, init, xs, unroll=unroll)
+    _, ys = scan(init, xs)
     return jax.tree_util.tree_map(unfold, ys)
 
 
-def dynamic_learning_scan(world_model_def, wm_params, batch_actions, embedded, is_first, key, *, cdt, **scan_spec):
+def scan_kernel_gradients_after(step, unroll: int = 1):
+    """``loop(variables, consts, init, xs) -> (carry, ys)``: `lax.scan` of the
+    flax apply ``step(variables, consts, carry, x_t, mutable) -> ((carry,
+    ys_t), mutated)`` over ``xs``' leading axis, with a backward of its own.
+
+    `lax.scan`'s transpose accumulates the cotangent of everything its body
+    closes over inside the loop: for a kernel that is an outer product of one
+    step's few rows, read-modified-written at the kernel's size every step,
+    though nothing the chain of the backward needs reads it.  Here the kernels
+    of the products that ``step`` marks (`models/blocks.py::tap_product`) are
+    held constant through the loop, whose backward keeps what the carry's
+    cotangent needs (the products' input gradients, the vectors of LayerNorm
+    scales and biases) and stacks, as the cotangent of a zero probe added to
+    each product, the cotangent of its output; the product's inputs are
+    stacked going forward, and each kernel's gradient is then one product over
+    all the loop's rows, ``sum_t x_t^T dy_t = X^T dY``, with the operand types
+    and the precision of the step's own: the result differs from
+    `lax.scan`'s by summation order only.  A product that is not marked (the
+    fused GRU's) keeps its kernel's gradient in the loop.  The forward loop
+    runs once: its linearisation is made in the forward rule and kept."""
+
+    def run(variables, consts, init, xs, probes):
+        params = flatten_dict(variables["params"])
+        for tap in flatten_dict(probes):
+            params[_tapped_kernel(tap)] = jax.lax.stop_gradient(params[_tapped_kernel(tap)])
+        held = {**variables, "params": unflatten_dict(params)}
+
+        def body(carry, x):
+            x, probe = x
+            (carry, ys), tapped = step({**held, PRODUCT_PROBES: probe}, consts, carry, x, [PRODUCT_INPUTS])
+            return carry, (ys, tapped.get(PRODUCT_INPUTS, {}))
+
+        carry, (ys, inputs) = jax.lax.scan(body, init, (xs, probes), unroll=unroll)
+        return (carry, ys), inputs
+
+    @jax.custom_vjp
+    def loop(variables, consts, init, xs):
+        return jax.lax.scan(lambda carry, x: step(variables, consts, carry, x, [])[0], init, xs, unroll=unroll)
+
+    def forward(variables, consts, init, xs):
+        x0 = jax.tree_util.tree_map(lambda x: x[0], xs)
+        length = jax.tree_util.tree_leaves(xs)[0].shape[0]
+        # one abstract trace of a step names its marked products and gives their shapes
+        marked = jax.eval_shape(lambda: step(variables, consts, init, x0, [PRODUCT_PROBES])[1])
+        probes = jax.tree_util.tree_map(
+            lambda y: jnp.zeros((length, *y.shape), y.dtype), marked.get(PRODUCT_PROBES, {})
+        )
+        out, transpose, inputs = jax.vjp(run, variables, consts, init, xs, probes, has_aux=True)
+        return out, (transpose, inputs)
+
+    def backward(residuals, cotangents):
+        transpose, inputs = residuals
+        d_variables, d_consts, d_init, d_xs, d_outputs = transpose(cotangents)
+        d_params, d_outputs = flatten_dict(d_variables["params"]), flatten_dict(d_outputs)
+        for tap, x in flatten_dict(inputs).items():
+            held = d_params[_tapped_kernel(tap)]  # zeros: the loop held the kernel constant
+            product = jnp.einsum("...i,...o->io", x, d_outputs[tap]).astype(held.dtype)
+            # the step's product reads the kernel's leading rows only
+            d_params[_tapped_kernel(tap)] = jnp.pad(product, ((0, held.shape[0] - product.shape[0]), (0, 0)))
+        return {**d_variables, "params": unflatten_dict(d_params)}, d_consts, d_init, d_xs
+
+    loop.defvjp(forward, backward)
+    return loop
+
+
+def _tapped_kernel(tap: Tuple[str, ...]) -> Tuple[str, ...]:
+    """A marked product's path among the probes -> its kernel's among the parameters."""
+    return (*tap[:-1], tap[-1].removesuffix(PRODUCT_SUFFIX), "kernel")
+
+
+def dynamic_learning_scan(
+    world_model_def, wm_params, batch_actions, embedded, is_first, key, *, cdt, unroll: int = 1, **scan_spec
+):
     """The dynamic-learning pass of the DV3 family's train steps
     (DV3/JEPA/P2E): ``(recurrents, posteriors, post_logits, prior_logits)``,
     each ``[T, B, ...]``, by `RSSM.dynamic`'s mathematics and draws.
@@ -193,10 +271,21 @@ def dynamic_learning_scan(world_model_def, wm_params, batch_actions, embedded, i
     folds them like any ``[T, B, ...]`` leaf), the learned initial state, each
     loop's Gumbel noise, and — after it, on the stacked recurrent states — the
     prior head, which nothing carries and the burn-in loop never needs.
-    ``scan_spec`` is `chunked_dynamic_scan`'s keyword arguments."""
 
-    def rssm(method, *args):
-        return world_model_def.apply(wm_params, *args, method=lambda wm, *a: getattr(wm.rssm, method)(*a))
+    The backward loop likewise keeps what the carry's cotangent depends on:
+    the input gradients of those four products, the transposes of the
+    LayerNorms, the gates and the reset, and the vector-sized gradients of the
+    LayerNorm scales and biases.  Outside it, once on all the loop's rows
+    after it: the gradients of the four products' kernels, from the products'
+    stacked inputs and the stacked cotangents of their outputs
+    (`scan_kernel_gradients_after`); nothing the loop carries has a kernel's
+    shape.  ``unroll`` is the loops' `lax.scan` unrolling; ``scan_spec`` is
+    `chunked_dynamic_scan`'s keyword arguments."""
+
+    def rssm(method, *args, variables=wm_params, **apply_kwargs):
+        return world_model_def.apply(
+            variables, *args, method=lambda wm, *a: getattr(wm.rssm, method)(*a), **apply_kwargs
+        )
 
     action_rows, obs_rows = rssm("scan_projections", batch_actions, embedded)
     initial_states = rssm("get_initial_states", ())
@@ -205,12 +294,17 @@ def dynamic_learning_scan(world_model_def, wm_params, batch_actions, embedded, i
         noise = rssm("scan_noise", keys, is_first.shape[1], cdt)
         return (1 - is_first) * action_rows, obs_rows, is_first, noise
 
-    def scan_body(carry, x):
-        recurrent, posterior, post_logits = rssm("scan_step", *carry, *x, initial_states)
-        return (posterior, recurrent), (recurrent, posterior, post_logits)
+    def step(variables, initial_states, carry, x, mutable):
+        (recurrent, posterior, post_logits), mutated = rssm(
+            "scan_step", *carry, *x, initial_states, variables=variables, mutable=mutable
+        )
+        return ((posterior, recurrent), (recurrent, posterior, post_logits)), mutated
 
+    loop = scan_kernel_gradients_after(step, unroll)
+    # the loop is differentiated with respect to what it is handed: of the parameters, the RSSM's
+    scan = functools.partial(loop, {"params": {"rssm": wm_params["params"]["rssm"]}}, initial_states)
     recurrents, posteriors, post_logits = chunked_dynamic_scan(
-        scan_body, action_rows, obs_rows, is_first, key, cdt=cdt, step_inputs=step_inputs, **scan_spec
+        scan, action_rows, obs_rows, is_first, key, cdt=cdt, step_inputs=step_inputs, **scan_spec
     )
     return recurrents, posteriors, post_logits, rssm("prior_logits", recurrents)
 

@@ -24,6 +24,25 @@ from flax import linen as nn
 
 Dtype = Any
 
+#: the collections `tap_product` writes to and reads from, and its names' suffix
+PRODUCT_INPUTS, PRODUCT_PROBES, PRODUCT_SUFFIX = "intermediates", "perturbations", "_product"
+
+
+def tap_product(module: nn.Module, dense_name: str, x: jax.Array, y: jax.Array) -> jax.Array:
+    """Mark ``y`` as the product of ``x`` with the leading ``x.shape[-1]`` rows
+    of the kernel of ``module``'s Dense ``dense_name`` (plus whatever does not
+    depend on that kernel).  Returns ``y``, and does nothing else unless the
+    caller of ``apply`` asks: with ``PRODUCT_INPUTS`` mutable ``x`` is stored
+    there, and with ``PRODUCT_PROBES`` given its entry (zeros of ``y``'s
+    shape) is added to ``y``, so that its cotangent is ``y``'s.  From the two,
+    stacked over a loop's steps, the kernel's gradient is one product after
+    the loop (`algos/dreamer_v3/utils.py::scan_kernel_gradients_after`)."""
+    if module.is_initializing():  # `init` makes every collection mutable: the tree stays the parameters alone
+        return y
+    name = dense_name + PRODUCT_SUFFIX
+    module.sow(PRODUCT_INPUTS, name, x, reduce_fn=lambda _, new: new, init_fn=lambda: None)
+    return module.perturb(name, y, PRODUCT_PROBES)
+
 
 def get_activation(name: str | Callable | None) -> Callable:
     """Map reference activation names (e.g. ``torch.nn.SiLU``) to jax fns."""
@@ -254,7 +273,7 @@ class LayerNormGRUCell(nn.Module):
                     self.fused_interpret,
                 )
 
-        z = dense(joint)
+        z = tap_product(self, dense.name, joint, dense(joint))
         if ln is not None:
             z = ln(z)
         reset, cand, update = jnp.split(z, 3, axis=-1)

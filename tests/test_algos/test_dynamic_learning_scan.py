@@ -3,17 +3,25 @@
 
 * against the one-step `RSSM.dynamic` driven a step at a time (the form the
   three train-step builders held before): identical posterior draws, and
-  logits, recurrent states and world-model gradients equal to float32
-  re-association — at ``chunks=1``, ``chunks>1`` with and without burn-in,
-  ``decoupled_rssm=True``, a stack with biases in place of LayerNorm, and
-  once each through the JEPA and P2E train-step builders;
+  logits and recurrent states equal to float32 re-association — at
+  ``chunks=1``, ``chunks>1`` with and without burn-in, ``decoupled_rssm=True``,
+  a stack with biases in place of LayerNorm, and once each through the JEPA
+  and P2E train-step builders;
+* gradients: the scan's own backward (the kernels' gradients taken once after
+  the loop) against plain autodiff through `RSSM.dynamic` a step, for every
+  leaf of the world model's parameters, the embedded observations and the
+  actions, in each of those forms and through both builders' train steps; the
+  burn-in loop passes no gradient;
 * structure: in `make_train_step`'s jaxpr the T-step loops hold no product
   with the embedded observation, none of the transition head's and no random
-  bits; and the parameter tree is the one checked in beside this file.
+  bits, the transposed loop neither computes nor carries anything of a
+  kernel's shape, and the four kernels' gradients are products over all T x B
+  rows outside it; and the parameter tree is the one checked in beside this file.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 from pathlib import Path
 
@@ -21,6 +29,7 @@ import gymnasium as gym
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from sheeprl_tpu.algos.dreamer_v3.utils import chunked_dynamic_scan, dynamic_learning_scan, init_moments_state
@@ -88,7 +97,7 @@ def one_step_dynamic_scan(world_model_def, wm_params, batch_actions, embedded, i
     if chunks == 1:  # hand-inlined: no helper between the test and lax.scan
         init = (jnp.zeros((B, STOCH * DISCRETE)), jnp.zeros((B, REC)))
         return jax.lax.scan(scan_body, init, (batch_actions, embedded, is_first, jax.random.split(key, T)))[1]
-    return chunked_dynamic_scan(scan_body, batch_actions, embedded, is_first, key, chunks=chunks, **scan_spec)
+    return chunked_dynamic_scan(functools.partial(jax.lax.scan, scan_body), batch_actions, embedded, is_first, key, chunks=chunks, **scan_spec)
 
 
 def _world_model(*overrides, **fields):
@@ -117,11 +126,12 @@ SCAN_CASES = {
     "decoupled": (("algo.world_model.decoupled_rssm=True",), {}, {}),
     "decoupled_chunks2_burn_in1": (("algo.world_model.decoupled_rssm=True",), {}, {"chunks": 2, "burn_in": 1}),
     "biases_for_layer_norm": ((), {"layer_norm": False}, {}),
+    "unroll4": ((), {}, {"unroll": 4}),  # `algo.scan_unroll`: the reference's loop takes no notice of it
 }
 
 
-@pytest.mark.parametrize("case", list(SCAN_CASES))
-def test_scan_equals_one_step_dynamic(case):
+def _scan_case(case):
+    """``run(scan, params, embedded, actions) -> the four outputs`` of a form of the scan."""
     overrides, fields, spec = SCAN_CASES[case]
     wm_def, wm_params = _world_model(*overrides, **fields)
     batch = _batch()
@@ -134,22 +144,29 @@ def test_scan_equals_one_step_dynamic(case):
             stored_valid=batch["rssm_valid"],
         )
 
-    def run(scan, params):
-        embedded = wm_def.apply(params, {"state": batch["state"]}, method="encode")
-        return scan(wm_def, params, batch["actions"], embedded, batch["is_first"], key, **scan_spec)
+    def run(scan, params, embedded, actions, **stored):
+        return scan(wm_def, params, actions, embedded, batch["is_first"], key, **{**scan_spec, **stored})
 
-    def loss(scan, params):
-        recurrents, posteriors, post_logits, prior_logits = run(scan, params)
-        weights = jnp.arange(1.0, STOCH * DISCRETE + 1.0)
-        return (
-            jnp.sum(recurrents**2)
-            + jnp.sum(posteriors * weights)
-            + jnp.sum(jnp.sin(post_logits))
-            + jnp.sum(jnp.cos(prior_logits) * weights)
-        )
+    embedded = wm_def.apply(wm_params, {"state": batch["state"]}, method="encode")
+    return run, wm_params, embedded, batch["actions"]
 
-    want = jax.jit(lambda p: run(one_step_dynamic_scan, p))(wm_params)
-    got = jax.jit(lambda p: run(dynamic_learning_scan, p))(wm_params)
+
+def _scalar(outputs):
+    recurrents, posteriors, post_logits, prior_logits = outputs
+    weights = jnp.arange(1.0, STOCH * DISCRETE + 1.0)
+    return (
+        jnp.sum(recurrents**2)
+        + jnp.sum(posteriors * weights)
+        + jnp.sum(jnp.sin(post_logits))
+        + jnp.sum(jnp.cos(prior_logits) * weights)
+    )
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_scan_equals_one_step_dynamic(case):
+    run, wm_params, embedded, actions = _scan_case(case)
+    want = jax.jit(lambda p: run(one_step_dynamic_scan, p, embedded, actions))(wm_params)
+    got = jax.jit(lambda p: run(dynamic_learning_scan, p, embedded, actions))(wm_params)
     names = ("recurrents", "posteriors", "post_logits", "prior_logits")
     for name, w, g in zip(names, want, got):
         assert w.shape == g.shape, name
@@ -159,16 +176,6 @@ def test_scan_equals_one_step_dynamic(case):
     np.testing.assert_array_equal(draws, np.rint(np.asarray(want[1])).reshape(draws.shape))
     assert ((draws == 0) | (draws == 1)).all() and (draws.sum(-1) == 1).all()
     assert len({tuple(d.ravel()) for d in draws.reshape(T * B, -1)}) > 1, "every draw the same: nothing was sampled"
-
-    want_grads = jax.jit(jax.grad(lambda p: loss(one_step_dynamic_scan, p)))(wm_params)
-    got_grads = jax.jit(jax.grad(lambda p: loss(dynamic_learning_scan, p)))(wm_params)
-    moved = 0
-    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want_grads), jax.tree_util.tree_leaves(got_grads)):
-        w, g = np.asarray(w), np.asarray(g)
-        scale = np.abs(w).max()
-        np.testing.assert_allclose(w, g, rtol=1e-5, atol=1e-5 * scale + 1e-9, err_msg=jax.tree_util.keystr(path))
-        moved += scale > 0
-    assert moved > 10, "the loss reached almost no parameter"
 
 
 def _dv3(cfg):
@@ -197,11 +204,13 @@ def _p2e(cfg):
     return mod, (wm, actor, critic), params, optimizers, opt_states, mod._init_moments(cfg, None)
 
 
+# builder, overrides, and what of the module sets the world-model optimizer's state up after `init`
 BUILDERS = {
-    "dreamer_v3_jepa": (_jepa, ["exp=dreamer_v3_jepa", "algo.jepa_proj_dim=8", "algo.jepa_hidden=8"]),
+    "dreamer_v3_jepa": (_jepa, ["exp=dreamer_v3_jepa", "algo.jepa_proj_dim=8", "algo.jepa_hidden=8"], "_extra_opt_setup"),
     "p2e_dv3_exploration": (
         _p2e,
         ["exp=p2e_dv3_exploration", "algo.ensembles.n=2", "algo.ensembles.dense_units=8", "algo.ensembles.mlp_layers=1"],
+        None,
     ),
 }
 
@@ -211,7 +220,7 @@ def test_builders_train_step_equals_one_step_dynamic(algo, monkeypatch):
     """One gradient step of the JEPA and the P2E builders, with the shared
     scan and with `RSSM.dynamic` a step in its place: the same losses and
     gradient norms."""
-    build, overrides = BUILDERS[algo]
+    build, overrides, _ = BUILDERS[algo]
     cfg = compose([*overrides, *TINY])
     batch = {k: v for k, v in _batch().items() if not k.startswith("rssm_")}
     metrics = {}
@@ -225,6 +234,88 @@ def test_builders_train_step_equals_one_step_dynamic(algo, monkeypatch):
     assert np.isfinite(metrics["shared"]).all()
     assert metrics["shared"][0] != 0 and metrics["shared"][8] != 0  # a world-model loss and its gradient's norm
     np.testing.assert_allclose(metrics["shared"], metrics["one_step"], rtol=2e-5, atol=1e-6)
+
+
+def _assert_gradients_equal(want, got, at_least):
+    """Leaf by leaf to 1e-5 of the leaf's largest magnitude: the two differ by summation order only."""
+    moved = 0
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want), jax.tree_util.tree_leaves(got), strict=True):
+        w, g = np.asarray(w), np.asarray(g)
+        scale = np.abs(w).max()
+        np.testing.assert_allclose(w, g, rtol=0, atol=1e-5 * scale + 1e-9, err_msg=jax.tree_util.keystr(path))
+        moved += scale > 0
+    assert moved > at_least, "the loss reached almost no leaf"
+
+
+# an optimizer that moves nothing and keeps the gradient it was given as its state
+RECORDER = optax.GradientTransformation(
+    init=lambda params: jax.tree_util.tree_map(jnp.zeros_like, params),
+    update=lambda grads, state, params=None: (jax.tree_util.tree_map(jnp.zeros_like, grads), grads),
+)
+
+
+def _builder_gradients(algo, monkeypatch):
+    """The world-model gradient of one train step of a builder, with the
+    shared scan and with `RSSM.dynamic` a step in its place."""
+    build, overrides, extra_opt_setup = BUILDERS[algo]
+    cfg = compose([*overrides, *TINY])
+    batch = {k: v for k, v in _batch().items() if not k.startswith("rssm_")}
+    grads = {}
+    for form in ("shared", "one_step"):
+        mod, defs, params, optimizers, opt_states, moments = build(cfg)
+        optimizers["world_model"] = RECORDER
+        opt_states["world_model"] = RECORDER.init(params["world_model"])
+        if extra_opt_setup is not None:
+            opt_states = getattr(mod, extra_opt_setup)(optimizers, opt_states, params)
+        if form == "one_step":
+            monkeypatch.setattr(mod, "dynamic_learning_scan", one_step_dynamic_scan)
+        step = mod.make_train_step(*defs, optimizers, cfg, ACTIONS_DIM, False)
+        out = step(params, opt_states, moments, batch, jax.random.PRNGKey(5), jnp.float32(0.02))
+        grads[form] = out[1]["world_model"]
+    return grads["one_step"], grads["shared"]
+
+
+def _burn_in_passes_no_gradient():
+    """``chunks=2, burn_in=2``: nothing reaches the stored states the burn-in
+    loop starts from, and of the loops of its length none runs backward."""
+    run, wm_params, embedded, actions = _scan_case("chunks2_burn_in2")
+    batch = _batch()
+
+    def loss(params, stored_recurrent, stored_posterior):
+        outputs = run(
+            dynamic_learning_scan,
+            params,
+            embedded,
+            actions,
+            stored_recurrent=stored_recurrent,
+            stored_posterior=stored_posterior,
+        )
+        return _scalar(outputs)
+
+    leaves = (wm_params, batch["rssm_recurrent"], batch["rssm_posterior"])
+    for grad in jax.grad(loss, argnums=(1, 2))(*leaves):
+        assert not np.asarray(grad).any()
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(*leaves).jaxpr
+    loops = {length: [loop.params["reverse"] for loop in _loops(jaxpr, length)] for length in (2, T // 2)}
+    assert loops == {2: [False], T // 2: [False, True]}, loops
+
+
+@pytest.mark.parametrize("case", [*SCAN_CASES, *BUILDERS, "burn_in_passes_no_gradient"])
+def test_gradients_equal_plain_autodiff(case, monkeypatch):
+    """The scan's backward takes the four kernels' gradients once after the
+    loop; plain autodiff through `RSSM.dynamic` a step accumulates them in it."""
+    if case == "burn_in_passes_no_gradient":
+        return _burn_in_passes_no_gradient()
+    if case in BUILDERS:
+        return _assert_gradients_equal(*_builder_gradients(case, monkeypatch), at_least=30)
+    run, wm_params, embedded, actions = _scan_case(case)
+
+    def gradients(scan):
+        return jax.jit(jax.grad(lambda *leaves: _scalar(run(scan, *leaves)), argnums=(0, 1, 2)))(
+            wm_params, embedded, actions
+        )
+
+    _assert_gradients_equal(gradients(one_step_dynamic_scan), gradients(dynamic_learning_scan), at_least=10)
 
 
 # ---------------------------------------------------------------------------
@@ -249,9 +340,14 @@ def _eqns(jaxpr):
 
 
 def _loops(jaxpr, length):
+    """The `lax.scan` equations of ``length`` steps, in order."""
     for eqn in _eqns(jaxpr):
         if eqn.primitive.name == "scan" and eqn.params["length"] == length:
-            yield eqn.params["jaxpr"].jaxpr
+            yield eqn
+
+
+def _body(loop):
+    return loop.params["jaxpr"].jaxpr
 
 
 def _dots(jaxpr):
@@ -280,26 +376,51 @@ ACTION_PRODUCT = ((T, B, ACTIONS_DIM[0]), (ACTIONS_DIM[0], DENSE))
 PRIOR_PRODUCTS = [((T, B, REC), (REC, HIDDEN)), ((T, B, HIDDEN), (HIDDEN, STOCH_FLAT))]
 
 
+# the four kernels' gradients, once after the loop: (the products' stacked inputs, the stacked cotangents of their outputs)
+KERNEL_GRADIENTS = [((T, B, x[-1]), (T, B, kernel[-1])) for x, kernel in CARRIED_PRODUCTS]
+
+
+def _kernel_shaped_in_backward(loop, kernel_shapes):
+    """What the transposed loop computes or carries at a kernel's size: the
+    shapes of every product's output (either way round: a kernel's gradient is
+    taken as its transpose) and of every carried value that are a kernel's
+    (for the kernel read by its leading rows, the whole one's too)."""
+    body = _body(loop)
+    consts, carried = loop.params["num_consts"], loop.params["num_carry"]
+    shapes = [tuple(v.aval.shape) for v in body.invars[consts : consts + carried]]
+    shapes += [tuple(eqn.outvars[0].aval.shape) for eqn in _eqns(body) if eqn.primitive.name == "dot_general"]
+    return [shape for shape in shapes if {shape, shape[::-1]} & kernel_shapes]
+
+
+def _kernel_shapes(wm_params):
+    rssm = wm_params["params"]["rssm"]
+    return {tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(rssm) if leaf.ndim == 2} | {
+        kernel for _, kernel in CARRIED_PRODUCTS
+    }
+
+
 def test_train_step_dynamic_loops_hold_only_what_their_carry_needs():
     cfg = compose(["exp=dreamer_v3", *TINY])
     mod, defs, params, optimizers, opt_states, moments = _dv3(cfg)
     step = mod.make_train_step(*defs, optimizers, cfg, ACTIONS_DIM, False)
     batch = {k: v for k, v in _batch().items() if not k.startswith("rssm_")}
     jaxpr = jax.make_jaxpr(step)(params, opt_states, moments, batch, jax.random.PRNGKey(0), jnp.float32(0.02)).jaxpr
-    forward, backward = _loops(jaxpr, T)  # horizon=3 is the imagination's length
+    forward, backward = _loops(jaxpr, T)  # horizon=3 is the imagination's length: the forward loop runs once
+    assert not forward.params["reverse"] and backward.params["reverse"]
     # forward: the four carried products and no other; so no embed product
     # and no second (REC, HIDDEN) or (HIDDEN, STOCH_FLAT): no transition head
-    assert sorted(_dots(forward)) == CARRIED_PRODUCTS
-    # backward: an input's and a kernel's gradient for each of the four
-    backward_dots = list(_dots(backward))
-    assert len(backward_dots) == 2 * len(CARRIED_PRODUCTS), backward_dots
-    for body in (forward, backward):
+    assert sorted(_dots(_body(forward))) == CARRIED_PRODUCTS
+    # backward: an input's gradient for each of the four, (the output's cotangent, the kernel) ...
+    assert sorted(_dots(_body(backward))) == sorted(((B, kernel[-1]), kernel) for _, kernel in CARRIED_PRODUCTS)
+    # ... and nothing of a kernel's shape computed or carried
+    assert not _kernel_shaped_in_backward(backward, _kernel_shapes(params["world_model"]))
+    for body in (_body(forward), _body(backward)):
         assert not _random_primitives(body)
         widths = {d for shapes in _dots(body) for shape in shapes for d in shape}
         assert not widths & {EMBED, REC + EMBED, ACTIONS_DIM[0], STOCH_FLAT + ACTIONS_DIM[0]}, widths
     # what left the loops runs outside them, once on all T x B rows
     outside = list(_dots(jaxpr))
-    for product in (EMBED_PRODUCT, ACTION_PRODUCT, *PRIOR_PRODUCTS):
+    for product in (EMBED_PRODUCT, ACTION_PRODUCT, *PRIOR_PRODUCTS, *KERNEL_GRADIENTS):
         assert product in outside, product
     assert _random_primitives(jaxpr), "the draws' noise is drawn nowhere"
 
@@ -307,7 +428,9 @@ def test_train_step_dynamic_loops_hold_only_what_their_carry_needs():
 def test_one_step_form_fails_the_structural_check():
     """The check above sees what it looks for: with `RSSM.dynamic` a step the
     loop holds the embed's and the action's rows, the transition head (for
-    the prior and again for the initial state) and the random bits."""
+    the prior and again for the initial state) and the random bits; and plain
+    autodiff through it computes and carries the kernels' gradients in the
+    transposed loop."""
     wm_def, wm_params = _world_model()
     batch = _batch()
 
@@ -317,12 +440,19 @@ def test_one_step_form_fails_the_structural_check():
             wm_def, params, batch["actions"], embedded, batch["is_first"], jax.random.PRNGKey(0)
         )
 
-    (body,) = _loops(jax.make_jaxpr(run)(wm_params).jaxpr, T)
-    dots = list(_dots(body))
+    (forward,) = _loops(jax.make_jaxpr(run)(wm_params).jaxpr, T)
+    dots = list(_dots(_body(forward)))
     assert ((B, REC + EMBED), (REC + EMBED, HIDDEN)) in dots
     assert ((B, STOCH_FLAT + ACTIONS_DIM[0]), (STOCH_FLAT + ACTIONS_DIM[0], DENSE)) in dots
     assert dots.count(((B, REC), (REC, HIDDEN))) == 2 and dots.count(((B, HIDDEN), (HIDDEN, STOCH_FLAT))) == 3
-    assert _random_primitives(body)
+    assert _random_primitives(_body(forward))
+
+    _, backward = _loops(jax.make_jaxpr(jax.grad(lambda p: _scalar(run(p))))(wm_params).jaxpr, T)
+    in_loop = _kernel_shaped_in_backward(backward, _kernel_shapes(wm_params))
+    for kernel in ((REC + DENSE, 3 * REC), (HIDDEN, STOCH_FLAT)):  # the GRU's and the representation head's
+        assert kernel in in_loop and kernel[::-1] in in_loop, (kernel, in_loop)  # carried, and computed
+    outside = list(_dots(jax.make_jaxpr(jax.grad(lambda p: _scalar(run(p))))(wm_params).jaxpr))
+    assert not set(KERNEL_GRADIENTS) & set(outside)
 
 
 PARAM_TREE = Path(__file__).with_name("dv3_s_param_tree.json")

@@ -24,6 +24,7 @@ The contract under test, layer by layer:
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 from pathlib import Path
@@ -178,7 +179,7 @@ def test_chunks1_ignores_stored_state_and_matches_same_unroll():
             body, init, (actions, embedded, is_first, keys_t), unroll=unroll
         )
         got = chunked_dynamic_scan(
-            body,
+            functools.partial(jax.lax.scan, body, unroll=unroll),
             actions,
             embedded,
             is_first,
@@ -190,7 +191,6 @@ def test_chunks1_ignores_stored_state_and_matches_same_unroll():
             stored_recurrent=jnp.full((T, B, H), 777.0),  # must be ignored at K=1
             stored_posterior=jnp.full((T, B, Z), 777.0),
             stored_valid=jnp.ones((T, B, 1)),
-            unroll=unroll,
         )
         for r, g in zip(ref, got):
             assert (np.asarray(r) == np.asarray(g)).all()
@@ -208,7 +208,7 @@ def test_exact_stored_states_reproduce_sequential_trajectory(chunks):
     _, ref = _sequential(body, actions, embedded, is_first, key)
     zs, hs = _sequential_carries(body, actions, embedded, is_first, key)
     got = chunked_dynamic_scan(
-        body,
+        functools.partial(jax.lax.scan, body),
         actions,
         embedded,
         is_first,
@@ -237,7 +237,7 @@ def test_chunked_output_layout_unfolds_to_time_major():
     zs = jnp.zeros((T, B, Z))
     hs = jnp.zeros((T, B, H))
     got = chunked_dynamic_scan(
-        echo,
+        functools.partial(jax.lax.scan, echo),
         actions,
         embedded,
         is_first,
@@ -259,7 +259,7 @@ def test_missing_stored_state_raises_with_key_names():
     actions, embedded, is_first = _inputs()
     with pytest.raises(ValueError, match="rssm_recurrent"):
         chunked_dynamic_scan(
-            body,
+            functools.partial(jax.lax.scan, body),
             actions,
             embedded,
             is_first,
@@ -286,11 +286,11 @@ def test_chunks_must_divide_sequence_and_burn_in_must_fit():
     )
     with pytest.raises(ValueError, match="must divide"):
         chunked_dynamic_scan(
-            body, actions, embedded, is_first, jax.random.PRNGKey(0), chunks=3, **common
+            functools.partial(jax.lax.scan, body), actions, embedded, is_first, jax.random.PRNGKey(0), chunks=3, **common
         )
     with pytest.raises(ValueError, match="rssm_chunk_burn_in"):
         chunked_dynamic_scan(
-            body, actions, embedded, is_first, jax.random.PRNGKey(0), chunks=2, burn_in=4, **common
+            functools.partial(jax.lax.scan, body), actions, embedded, is_first, jax.random.PRNGKey(0), chunks=2, burn_in=4, **common
         )
 
 
@@ -309,7 +309,7 @@ def test_episode_start_on_chunk_boundary_resets():
     hs = jnp.full((T, B, H), 123.0)
     _, ref = _sequential(body, actions, embedded, is_first, jax.random.PRNGKey(0))
     got = chunked_dynamic_scan(
-        body,
+        functools.partial(jax.lax.scan, body),
         actions,
         embedded,
         is_first,
@@ -340,7 +340,7 @@ def test_invalid_stored_state_falls_back_to_reset():
     hs = hs.at[C - 1].set(1e9)
     valid = jnp.ones((T, B, 1)).at[C - 1].set(0.0)  # ... and mark it invalid
     got = chunked_dynamic_scan(
-        body,
+        functools.partial(jax.lax.scan, body),
         actions,
         embedded,
         is_first,
@@ -377,7 +377,7 @@ def test_burn_in_refresh_equals_manual_stop_gradient_rollout():
     burn = 2
     C = T // 2
     got = chunked_dynamic_scan(
-        body,
+        functools.partial(jax.lax.scan, body),
         actions,
         embedded,
         is_first,
@@ -398,7 +398,7 @@ def test_burn_in_refresh_equals_manual_stop_gradient_rollout():
         t = C - burn + j
         (z, h), _ = body((z, h), (actions[t], embedded[t], is_first[t], keys_burn[j]))
     manual = chunked_dynamic_scan(
-        body,
+        functools.partial(jax.lax.scan, body),
         actions,
         embedded,
         is_first,
@@ -426,7 +426,7 @@ def test_no_gradient_through_burn_in_or_stored_states():
 
     def loss(stored_h, stored_z, burn_in):
         ys = chunked_dynamic_scan(
-            body,
+            functools.partial(jax.lax.scan, body),
             actions,
             embedded,
             is_first,
