@@ -205,32 +205,47 @@ def token_key(cfg) -> str:
     return list(cfg.algo.mlp_keys.encoder)[0]
 
 
-def build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state=None):
-    """The hybrid language model as the policy: the observation is a token id,
-    the action the next one, both over the ids this chip holds."""
+def token_backbone(cfg):
+    """``(model class, its configuration)`` of a token backbone (``algo.backbone``), each under the config group of its name."""
+    backbone = str(cfg.algo.backbone)
+    if backbone == "sparse_moe":
+        from sheeprl_tpu.models.sparse_moe_lm import SparseMoEConfig, SparseMoELM
+
+        return SparseMoELM, SparseMoEConfig.from_cfg(cfg.algo.sparse_moe)
     from sheeprl_tpu.models.hybrid_lm import HybridConfig, HybridLM
+
+    return HybridLM, HybridConfig.from_cfg(cfg.algo.olmo_hybrid)
+
+
+def build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state=None):
+    """A language model as the policy: the observation is a token id, the
+    action the next one, both over the ids this chip holds."""
     from sheeprl_tpu.parallel.precision import compute_dtype_of
 
-    config = HybridConfig.from_cfg(cfg.algo.olmo_hybrid)
+    model, config = token_backbone(cfg)
+    backbone = str(cfg.algo.backbone)
     key = token_key(cfg)
     space = obs_space[key]
     if is_continuous or len(actions_dim) != 1 or not isinstance(space, gymnasium.spaces.Discrete):
         raise ValueError(
-            f"algo.backbone=olmo_hybrid needs one Discrete observation ({key!r}) and one Discrete action, "
+            f"algo.backbone={backbone} needs one Discrete observation ({key!r}) and one Discrete action, "
             f"got observation {space} and actions {tuple(actions_dim)}"
         )
     if int(space.n) != config.vocab_held or int(actions_dim[0]) != config.vocab_held:
         raise ValueError(
             f"the env speaks {int(space.n)} ids and takes {int(actions_dim[0])}; "
-            f"algo.olmo_hybrid.vocab_held is {config.vocab_held}"
+            f"algo.{backbone}.vocab_held is {config.vocab_held}"
         )
-    agent = HybridLM(config, dtype=compute_dtype_of(cfg))
+    agent = model(config, dtype=compute_dtype_of(cfg))
     sample = jnp.zeros((1, 1), jnp.int32)
     if agent_state is not None:
         params = jax.tree_util.tree_map(jnp.asarray, agent_state)
     else:
         params = jax.jit(lambda k: agent.init(k, sample, sample, agent.init_state(1)))(jax.random.PRNGKey(int(cfg.seed or 0)))
     return agent, params, {key: sample}
+
+
+TOKEN_BACKBONES = ("olmo_hybrid", "sparse_moe")
 
 
 def build_agent(
@@ -243,9 +258,9 @@ def build_agent(
 ):
     cnn_keys = list(cfg.algo.cnn_keys.encoder)
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
-    # ``algo.backbone``: ``lstm`` (the preset's ``algo.rnn``) or ``olmo_hybrid`` (``algo.olmo_hybrid``); the one place
+    # ``algo.backbone``: ``lstm`` (the preset's ``algo.rnn``) or a token backbone (``algo.<its name>``); the one place
     # that compares the name: the loop takes its player from the kind of agent built here (``players.make_player``)
-    if str(cfg.algo.get("backbone", "lstm") or "lstm") == "olmo_hybrid":
+    if str(cfg.algo.get("backbone", "lstm") or "lstm") in TOKEN_BACKBONES:
         return build_token_agent(cfg, actions_dim, is_continuous, obs_space, agent_state)
     agent = RecurrentPPOAgent(
         actions_dim=tuple(int(a) for a in actions_dim),

@@ -11,11 +11,13 @@ rollout, and where.  Two classes of one surface; the loop names neither:
 - a rollout's end: ``end_rollout(params, obs, prev_dones)`` (the bootstrap value, and what
   the player kept of the rollout beside the row), ``initial_state(local)`` (what each
   training sequence starts from, ``[1, S, ...]``, sequence ``s = chunk * N + env``);
+- an update's end: ``after_update(losses)`` (what the update reported beside the three PPO losses, for the page);
 - ``test(params, log_dir)``: the greedy test episode's return, ``None`` where there is none.
 
 :class:`LSTMPlayer` is the reference's: ``hx``, ``cx`` a row per env, stored with every
 step.  :class:`TokenPlayer` drives any model of ``models/hybrid_lm.py``'s contract
-(``apply(params, tokens, resets, state, decode=, write=)``, ``init_state(n)``): the carried
+(``apply(params, tokens, resets, state, decode=, write=)``, ``init_state(n)``; :class:`SparseTokenPlayer`
+one of ``models/sparse_moe_lm.py``'s, whose update has a loss of its own): the carried
 state is a pytree that stays on the device through the rollout, is donated to
 ``policy_step``, is copied once where a training sequence starts (the learner's constant,
 as ``hx0``/``cx0`` are) and never reaches the host; the rollout's log-probabilities and
@@ -37,6 +39,7 @@ from sheeprl_tpu.algos.ppo_recurrent.agent import token_key
 from sheeprl_tpu.algos.ppo_recurrent.utils import prepare_obs, test
 from sheeprl_tpu.envs.env import make_env
 from sheeprl_tpu.models.hybrid_lm import carry_bytes
+from sheeprl_tpu.models.sparse_moe_lm import AUX, SparseMoELM
 from sheeprl_tpu.parallel.precision import cast_floating, compute_dtype_of, resolve_precision
 
 
@@ -97,7 +100,11 @@ def policy_view_dtypes(agent, cfg, num_envs: int):
       kernels of the ``nn.Dense`` modules a decoded token goes through, found
       by what they are and not by their name (a convolution's taps and the
       embedding's rows are leaves named ``kernel`` too: multiplied
-      elementwise and gathered, rounding them would change the numbers).
+      elementwise and gathered, rounding them would change the numbers), and
+      the leaves a module of another kind lists as such (``mxu_operands``: a
+      stack of experts).  A ``nn.Dense`` that asks for a precision of its own
+      is none of them (an indexer's and a router's, float32 at ``highest``: a
+      rounded score or logit picks other positions and experts).
       A product with one row or one column is none of them: XLA:TPU rewrites
       it as a multiply and a reduction in float32, which reads all of its
       operands (the value head's one column; every product of a single env).
@@ -107,9 +114,12 @@ def policy_view_dtypes(agent, cfg, num_envs: int):
 
     def note(next_fun, args, kwargs, context):
         module = context.module
-        if (isinstance(module, nn.Dense) and context.method_name == "__call__" and module.precision is None
-                and module.features > 1 and math.prod(args[0].shape[:-1]) > 1):
-            through_the_mxu.add(("params",) + tuple(module.path) + ("kernel",))
+        if context.method_name == "__call__" and args and math.prod(args[0].shape[:-1]) > 1:
+            if isinstance(module, nn.Dense):
+                operands = ("kernel",) if module.precision is None and module.features > 1 else ()
+            else:
+                operands = getattr(module, "mxu_operands", ())
+            through_the_mxu.update(("params",) + tuple(module.path) + (name,) for name in operands)
         return next_fun(*args, **kwargs)
 
     with nn.intercept_methods(note):  # abstract all through: nothing is put on the device to find this out
@@ -160,22 +170,30 @@ def _view(player, params):
     return player.view
 
 
+def _sequences_of(batch, key):
+    """A token policy's training sequences: leaves are time-major ``[L, S, 1]``, the model's batch-major."""
+    tokens = batch[key][..., 0].T.astype(jnp.int32)
+    resets = batch["resets"][..., 0].T.astype(jnp.int32)
+    return tokens, resets, jax.tree_util.tree_map(lambda x: x[0], batch["state0"])
+
+
+def _token_terms(logits, values, batch):
+    """What the loss reads of a token policy's forward pass: the stored actions' log-probabilities, the entropy, the values."""
+    with jax.named_scope("ppo_loss"):
+        logp_all = jax.nn.log_softmax(logits, axis=-1)
+        actions = batch["actions"][..., 0].T.astype(jnp.int32)
+        logprobs = jnp.take_along_axis(logp_all, actions[..., None], axis=-1)
+        entropy = -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1, keepdims=True)
+    return logprobs.swapaxes(0, 1), entropy.swapaxes(0, 1), values.T[..., None]
+
+
 class TokenPlayer:
     def __init__(self, agent, cfg):
         self.agent, self.cfg, self.key = agent, cfg, token_key(cfg)
 
     def evaluate(self, params, batch):
-        """Leaves are time-major ``[L, S, 1]``, the model's batch-major."""
-        tokens = batch[self.key][..., 0].T.astype(jnp.int32)
-        resets = batch["resets"][..., 0].T.astype(jnp.int32)
-        state0 = jax.tree_util.tree_map(lambda x: x[0], batch["state0"])
-        logits, values, _ = self.agent.apply(cast_floating(params, compute_dtype_of(self.cfg)), tokens, resets, state0)
-        with jax.named_scope("ppo_loss"):
-            logp_all = jax.nn.log_softmax(logits, axis=-1)
-            actions = batch["actions"][..., 0].T.astype(jnp.int32)
-            logprobs = jnp.take_along_axis(logp_all, actions[..., None], axis=-1)
-            entropy = -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1, keepdims=True)
-        return logprobs.swapaxes(0, 1), entropy.swapaxes(0, 1), values.T[..., None]
+        logits, values, _ = self.agent.apply(cast_floating(params, compute_dtype_of(self.cfg)), *_sequences_of(batch, self.key))
+        return _token_terms(logits, values, batch)
 
     def start(self, diag, keys, num_envs, rollout_steps, seq_len):
         # through the loop's module, now: the benchmark's families replace the name there, and a planted fault of
@@ -233,8 +251,38 @@ class TokenPlayer:
         snapshots, self.snapshots = self.snapshots, []
         return {"state0": jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0)[None], *snapshots)}
 
+    def after_update(self, losses):
+        pass
+
     def test(self, params, log_dir):
         return None  # sampling is this policy's decoding: it has no greedy episode
+
+
+class SparseTokenPlayer(TokenPlayer):
+    """A policy of ``models/sparse_moe_lm.py``: the update differentiates the
+    indexers' loss beside PPO's and reports it with the shares of positions
+    attended and of picks held; the page says how many positions the next
+    decode step attends and what each kind of cache holds."""
+
+    def evaluate(self, params, batch):
+        logits, values, _, report = self.agent.apply(
+            cast_floating(params, compute_dtype_of(self.cfg)), *_sequences_of(batch, self.key), aux=True)
+        own = (self.agent.config.index_loss_coef * report["index_loss"], tuple(report[name] for name in AUX))
+        return _token_terms(logits, values, batch) + (own,)
+
+    def start(self, diag, keys, num_envs, rollout_steps, seq_len):
+        super().start(diag, keys, num_envs, rollout_steps, seq_len)
+        layers = self.carry["state"]["layers"]
+        diag.note_policy_gauges(carry_bytes_by_kind={
+            "kv": carry_bytes([(layer["k"], layer["v"]) for layer in layers]), "index": carry_bytes([layer["ki"] for layer in layers])})
+
+    def begin_step(self, prev_dones):
+        super().begin_step(prev_dones)
+        # what the decode step about to run attends, from the host's mirror: no fetch
+        self.diag.note_policy_selection(int(self.positions.sum()), int(np.minimum(self.positions, self.agent.config.topk).sum()))
+
+    def after_update(self, losses):
+        self.diag.note_policy_update(**{name: float(value) for name, value in zip(AUX, losses[3:])})
 
 
 class LSTMPlayer:
@@ -310,6 +358,9 @@ class LSTMPlayer:
         # the stored state at each sequence's first step; the rest of the two columns is nobody's
         return {k + "0": local.pop(k)[:: self.seq_len].reshape(1, -1, self.hx.shape[-1]) for k in ("hx", "cx")}
 
+    def after_update(self, losses):
+        pass
+
     def test(self, params, log_dir):
         env = make_env(self.cfg, self.cfg.seed, 0, log_dir, "test", vector_env_idx=0)()
         return test(LSTMPlayer(self.agent, self.cfg, greedy=True), params, env, self.cfg)
@@ -317,4 +368,6 @@ class LSTMPlayer:
 
 def make_player(agent, cfg, greedy: bool = False):
     """The player of the agent's kind: a model that makes its own carried state (``init_state``) is a token policy."""
+    if isinstance(agent, SparseMoELM):
+        return SparseTokenPlayer(agent, cfg)
     return TokenPlayer(agent, cfg) if hasattr(agent, "init_state") else LSTMPlayer(agent, cfg, greedy)

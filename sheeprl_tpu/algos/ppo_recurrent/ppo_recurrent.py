@@ -10,9 +10,10 @@ requires at :226), each sequence starts from its stored LSTM state, and the
 `reset_recurrent_state_on_done` semantics are preserved by in-graph masked
 state resets at done steps.  No padding, no masks, one `lax.scan` per BPTT.
 
-Two backbones (``algo.backbone``), each a player (``players.py``): ``lstm`` is
+Three backbones (``algo.backbone``), each a player (``players.py``): ``lstm`` is
 the reference's, ``olmo_hybrid`` a hybrid language model as a token-action
-policy (``models/hybrid_lm.py``).  What a policy carries through a rollout,
+policy (``models/hybrid_lm.py``), ``sparse_moe`` a sparse-attention, routed-expert
+one (``models/sparse_moe_lm.py``).  What a policy carries through a rollout,
 where it lives and what a training sequence starts from is the player's; the
 loop below names no backbone.
 """
@@ -53,9 +54,12 @@ def make_train_step(player, optimizer, cfg, mesh, num_minibatches: int, seq_batc
     distributed = world > 1
 
     def loss_fn(params, batch, clip_coef, ent_coef, vf_coef):
-        new_logprobs, entropy, new_values = player.evaluate(params, batch)
+        new_logprobs, entropy, new_values, *own = player.evaluate(params, batch)
         with jax.named_scope("ppo_loss"):
-            return ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef)
+            loss, reported = ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef)
+        for term, reports in own:  # a policy's own term of the loss, and what it reports beside the three
+            loss, reported = loss + term, reported + tuple(reports)
+        return loss, reported
 
     def ppo_loss(new_logprobs, entropy, new_values, batch, clip_coef, ent_coef, vf_coef):
         new_values = new_values.astype(jnp.float32)
@@ -100,7 +104,7 @@ def make_train_step(player, optimizer, cfg, mesh, num_minibatches: int, seq_batc
         (params, opt_state), (losses, grad_norms) = jax.lax.scan(epoch_body, (params, opt_state), keys)
         # the mean losses as ever; then every gradient step's own, and the norm of
         # every leaf's gradient as the optimizer got it, in the order of the steps
-        by_step = {"losses": losses.reshape(-1, 3), "grad_norms": grad_norms.reshape(-1, grad_norms.shape[-1])}
+        by_step = {"losses": losses.reshape(-1, losses.shape[-1]), "grad_norms": grad_norms.reshape(-1, grad_norms.shape[-1])}
         return params, opt_state, jnp.mean(by_step["losses"], axis=0), by_step
 
     if distributed:
@@ -349,6 +353,7 @@ def main(runtime, cfg):
             aggregator.update("Loss/policy_loss", float(losses[0]))
             aggregator.update("Loss/value_loss", float(losses[1]))
             aggregator.update("Loss/entropy_loss", float(losses[2]))
+            player.after_update(losses)
 
             if policy_step_count - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run:
                 metrics = aggregator.compute()

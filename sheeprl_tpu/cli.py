@@ -276,25 +276,27 @@ def check_configs(cfg: dotdict) -> None:
                     f"algo.rssm_chunk_burn_in ({burn_in}) must be < the chunk length "
                     f"({seq_len // rssm_chunks} = per_rank_sequence_length / rssm_chunks)"
                 )
-    # the recurrent on-policy loop's backbone (howto/olmo_hybrid_policy.md): what
+    # the recurrent on-policy loop's backbone (howto/olmo_hybrid_policy.md, howto/sparse_moe_policy.md): what
     # cannot work is refused here, not at the first trace of a 7B layer
     backbone = str(cfg.algo.get("backbone", "lstm") or "lstm")
-    if backbone not in ("lstm", "olmo_hybrid"):
-        raise ValueError(f"algo.backbone must be 'lstm' or 'olmo_hybrid', got {backbone!r}")
-    if backbone == "olmo_hybrid":
+    if backbone not in ("lstm", "olmo_hybrid", "sparse_moe"):
+        raise ValueError(f"algo.backbone must be 'lstm', 'olmo_hybrid' or 'sparse_moe', got {backbone!r}")
+    if backbone != "lstm":
         if algo_name != "ppo_recurrent":
-            raise ValueError(f"algo.backbone=olmo_hybrid is a backbone of ppo_recurrent, got algo.name={algo_name!r}")
+            raise ValueError(f"algo.backbone={backbone} is a backbone of ppo_recurrent, got algo.name={algo_name!r}")
+        from sheeprl_tpu.algos.ppo_recurrent.agent import token_backbone
         from sheeprl_tpu.envs.token import longest_episode
-        from sheeprl_tpu.models.hybrid_lm import HybridConfig
 
-        hybrid = HybridConfig.from_cfg(cfg.algo.olmo_hybrid)
-        problems = hybrid.problems()
+        _, model_cfg = token_backbone(cfg)
+        problems = model_cfg.problems()
         if problems:
-            raise ValueError(f"algo.olmo_hybrid: {problems[0]}")
+            raise ValueError(f"algo.{backbone}: {problems[0]}")
         seq_len = int(cfg.algo.per_rank_sequence_length)
-        if seq_len > hybrid.chunk_size and seq_len % hybrid.chunk_size:
+        # what a training sequence is worked in pieces of: the delta rule's chunks, the sparse attention's blocks of queries
+        piece = "chunk_size" if backbone == "olmo_hybrid" else "query_block"
+        if seq_len > getattr(model_cfg, piece) and seq_len % getattr(model_cfg, piece):
             raise ValueError(
-                f"algo.olmo_hybrid.chunk_size ({hybrid.chunk_size}) must divide "
+                f"algo.{backbone}.{piece} ({getattr(model_cfg, piece)}) must divide "
                 f"algo.per_rank_sequence_length ({seq_len})"
             )
         wrapper = cfg.env.get("wrapper") or {}
@@ -302,10 +304,10 @@ def check_configs(cfg: dotdict) -> None:
             longest = longest_episode(
                 wrapper["episode_max"], wrapper.get("first_episodes") or (), wrapper.get("stagger", 0) or 0, cfg.env.num_envs
             )
-            if longest > hybrid.cache_len:
+            if longest > model_cfg.cache_len:
                 raise ValueError(
-                    f"algo.olmo_hybrid.cache_len ({hybrid.cache_len}) is shorter than the env's longest "
-                    f"episode ({longest} tokens): a full-attention layer keeps every key of the running episode"
+                    f"algo.{backbone}.cache_len ({model_cfg.cache_len}) is shorter than the env's longest "
+                    f"episode ({longest} tokens): an attention layer keeps every key of the running episode"
                 )
     # FSDP knobs (howto/sharding.md): fail at compose time — a bad axis size
     # would otherwise surface as an opaque mesh-reshape error inside Runtime

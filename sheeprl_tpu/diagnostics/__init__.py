@@ -508,6 +508,26 @@ class Diagnostics:
         if self.telemetry is not None:
             self.telemetry.note_policy_state(resets, cache_positions, carry_bytes, view_bytes)
 
+    def note_policy_gauges(self, **gauges: Any) -> None:
+        """More of a sequence policy's state as it stands
+        (``sheeprl_policy_carry_bytes{kind}``).  No-op when telemetry is disabled."""
+        if self.telemetry is not None:
+            self.telemetry.note_policy_gauges(**gauges)
+
+    def note_policy_selection(self, visible: int, attended: int) -> None:
+        """A sparse-attention policy's vector step: positions seen and attended
+        (``sheeprl_policy_attended_positions`` and the two ``*_positions_total``).
+        No-op when telemetry is disabled."""
+        if self.telemetry is not None:
+            self.telemetry.note_policy_selection(visible, attended)
+
+    def note_policy_update(self, **reports: float) -> None:
+        """What one update of a sequence policy reported beside its losses
+        (``sheeprl_policy_updates_total``, ``sheeprl_policy_<name>_sum``).
+        No-op when telemetry is disabled."""
+        if self.telemetry is not None:
+            self.telemetry.note_policy_update(**reports)
+
     def note_loop_order(self, order: str) -> None:
         """Count one iteration under the order it ran in
         (``sheeprl_loop_order_iterations_total{order}`` on ``/metrics``).

@@ -150,10 +150,17 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
     # a sequence policy's carried state (Diagnostics.note_policy_state): absent where no loop reports one
     policy_state = snapshot.get("policy_state") or {}
     for key, mtype in (
-        ("state_resets_total", "counter"), ("cache_positions", "gauge"), ("carry_bytes", "gauge"), ("view_bytes", "gauge")
+        ("state_resets_total", "counter"), ("cache_positions", "gauge"), ("attended_positions", "gauge"), ("carry_bytes", "gauge"),
+        ("view_bytes", "gauge"), ("visible_positions_total", "counter"), ("attended_positions_total", "counter"),
+        ("updates_total", "counter"),
     ):
         if key in policy_state:
             emit("policy_" + key, mtype, policy_state[key])
+            if key == "carry_bytes":  # and what each kind of cache holds of it, where a policy has more than one kind
+                for kind, nbytes in sorted((policy_state.get("carry_bytes_by_kind") or {}).items()):
+                    lines.append(f'{METRIC_PREFIX}policy_carry_bytes{{kind="{_escape_label(kind)}"}} {float(nbytes):g}')
+    for key in sorted(k for k in policy_state if k.endswith("_sum")):  # what the updates reported, summed over them
+        emit("policy_" + key, "counter", policy_state[key])
 
     lag = snapshot.get("journal_lag_seconds")
     if lag is not None:

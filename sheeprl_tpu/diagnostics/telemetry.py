@@ -663,6 +663,7 @@ class Telemetry:
         self._env_steps_interval = 0
         self._env_steps_total = 0
         self._policy_state: Dict[str, int] = {}
+        self._policy_more: Dict[str, Any] = {}  # what a sparse-attention policy adds to it (note_policy_gauges / _selection / _update)
         self._loop_order_iterations: Dict[str, int] = {}
         self._rollout_calls_interval = 0
         # offline dataset feed: rows streamed from the loader (the env-free
@@ -771,6 +772,31 @@ class Telemetry:
                 "carry_bytes": int(carry_bytes),
                 "view_bytes": int(view_bytes),
             }
+
+    def note_policy_gauges(self, **gauges: Any) -> None:
+        """More of a sequence policy's state as it stands (``carry_bytes_by_kind``);
+        kept beside what :meth:`note_policy_state` replaces every vector step."""
+        with self._lock:
+            self._policy_more.update(gauges)
+
+    def note_policy_selection(self, visible: int, attended: int) -> None:
+        """A sparse-attention policy's vector step: the positions its queries
+        see and those they attend, summed over the envs; as they stand and
+        summed over the steps."""
+        with self._lock:
+            state = self._policy_more
+            state["attended_positions"] = int(attended)
+            state["visible_positions_total"] = state.get("visible_positions_total", 0) + int(visible)
+            state["attended_positions_total"] = state.get("attended_positions_total", 0) + int(attended)
+
+    def note_policy_update(self, **reports: float) -> None:
+        """What one update of a sequence policy reported beside its losses,
+        summed over the updates (``<name>_sum`` over ``updates_total`` is the mean)."""
+        with self._lock:
+            state = self._policy_more
+            state["updates_total"] = state.get("updates_total", 0) + 1
+            for name, value in reports.items():
+                state[name + "_sum"] = state.get(name + "_sum", 0.0) + float(value)
 
     def note_loop_order(self, order: str) -> None:
         """One iteration of a loop that has two orders, under the order it ran
@@ -964,7 +990,7 @@ class Telemetry:
                 "phase_seconds_total": dict(self._phase_total),
                 "phase_calls_total": dict(self._phase_calls_total),
                 "calls_total": dict(self._calls_total),
-                "policy_state": dict(self._policy_state),
+                "policy_state": {**self._policy_state, **self._policy_more},
                 "loop_order_iterations_total": dict(self._loop_order_iterations),
                 "flops_per_call": {
                     name: inst.flops_per_call

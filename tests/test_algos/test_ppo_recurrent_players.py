@@ -67,7 +67,7 @@ def test_train_step_gets_the_pinned_data(overrides, backbone):
 def test_the_two_players_answer_one_surface_and_nothing_beside_it():
     from sheeprl_tpu.algos.ppo_recurrent.players import LSTMPlayer, TokenPlayer
 
-    surface = {"evaluate", "start", "begin_step", "stage", "act", "fetch", "end_rollout", "initial_state", "test"}
+    surface = {"evaluate", "start", "begin_step", "stage", "act", "fetch", "end_rollout", "initial_state", "after_update", "test"}
     for player in (LSTMPlayer, TokenPlayer):
         assert {name for name, member in vars(player).items() if callable(member) and name != "__init__"} == surface
     for name in surface:  # and with the same arguments: nothing is asked of one only
