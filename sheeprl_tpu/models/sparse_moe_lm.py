@@ -24,9 +24,10 @@ precision: the score is), rotated when written.  ``decode=True`` decodes one
 token an env: score against the index cache, select, gather the selected
 rows, attend, write the token's own three rows.  Otherwise a training
 sequence is computed from a snapshot of that state taken as a constant, a
-sequence and a block of ``query_block`` queries at a time, as a dense product
-over cache and sequence masked to the selection (the result is over the
-selection only), each block recomputed in the backward pass.  Episode ends
+sequence and a block of ``query_block`` queries at a time, through one Pallas
+kernel over the key tiles of cache and sequence that the block selected from
+(``ops/sparse_attention.py``: the scores stay on chip and a tile no query
+selected is skipped), each block recomputed in the backward pass.  Episode ends
 (``resets``) restart the cache and start a new block of the mask, as in
 ``hybrid_lm.py``, whose protocol this keeps (``TokenPlayer`` drives both).
 
@@ -48,6 +49,7 @@ from flax import linen as nn
 
 from sheeprl_tpu.models.hybrid_lm import F32, RMSNorm, _dense, _Kernel, _segments, _write_rows
 from sheeprl_tpu.ops.moe import held_experts, route
+from sheeprl_tpu.ops.sparse_attention import selected_attention
 from sheeprl_tpu.ops.sparse_index import index_scores, select_indices, select_mask
 
 _HI = jax.lax.Precision.HIGHEST
@@ -55,7 +57,7 @@ _HI = jax.lax.Precision.HIGHEST
 SCOPES = ("embed", "attn_proj", "index_score", "select", "sparse_attention", "index_loss", "moe_route", "moe_experts",
           "vocab_head", "ppo_loss", "optim")
 # what the update reports beside the three PPO losses, in this order
-AUX = ("index_loss", "attended_share", "picks_held_share")
+AUX = ("index_loss", "attended_share", "picks_held_share", "attention_tiles_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,13 +173,12 @@ def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):
     time.  ``q`` ``[T, Hq, dh]``, ``k``/``v`` ``[T, Hkv, dh]``, ``qi`` ``[T, Hi, di]``,
     ``ki`` ``[T, di]``, ``w`` ``[T, Hi]``; ``cache`` leaves ``[1, L, .]``, ``pos`` how
     many of its positions are the first episode's, ``seg`` ``[T]``.  Returns the
-    heads' outputs ``[T, Hq dh]``, the indexer's KL summed over the queries, and the
-    attended and the visible positions counted over them."""
+    heads' outputs ``[T, Hq dh]``, the indexer's KL summed over the queries, the
+    attended and the visible positions counted over them, and the key tiles
+    attention computed and that exist, counted over the blocks."""
     T, L = q.shape[0], cache["ki"].shape[1]
-    G, R, dh = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
     block = min(c.query_block, T)
-    keys = jnp.concatenate([cache["k"][0].reshape(L, G, dh).astype(k.dtype), k], axis=0)
-    values = jnp.concatenate([cache["v"][0].reshape(L, G, dh).astype(v.dtype), v], axis=0)
+    cache_k, cache_v = cache["k"][0].astype(k.dtype), cache["v"][0].astype(v.dtype)
     index_keys = jnp.concatenate([cache["ki"][0], ki], axis=0)
 
     @jax.checkpoint
@@ -191,18 +192,16 @@ def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):
         with jax.named_scope("select"):
             selected = select_mask(scores, visible, c.topk)
         with jax.named_scope("sparse_attention"):
-            s = jnp.einsum("tgrd,sgd->grts", qb.reshape(-1, G, R, dh), keys).astype(F32) * dh ** -0.5
-            weights = jax.nn.softmax(jnp.where(selected, s, -jnp.inf), axis=-1)
-            o = jnp.einsum("grts,sgd->tgrd", weights.astype(qb.dtype), values).reshape(-1, c.num_heads * dh)
+            o, p, live = selected_attention(qb, cache_k, cache_v, k, v, selected)  # p: the weights' mean over the heads, a constant
         with jax.named_scope("index_loss"):
-            p = jax.lax.stop_gradient(jnp.sum(weights, axis=(0, 1)) / c.num_heads)
             log_q = jax.nn.log_softmax(jnp.where(selected, scores, -jnp.inf), axis=-1)
             kl = jnp.where(selected, p * (jnp.log(jnp.where(p > 0, p, 1.0)) - jnp.where(selected, log_q, 0.0)), 0.0)
-        return o, jnp.sum(kl), jnp.sum(selected.astype(jnp.int32)), jnp.sum(visible.astype(jnp.int32))
+        counts = (jnp.sum(selected.astype(jnp.int32)), jnp.sum(visible.astype(jnp.int32)), jnp.sum(live), jnp.int32(live.size))
+        return (o.reshape(o.shape[0], -1), jnp.sum(kl)) + counts
 
     cut = lambda x: x.reshape((T // block, block) + x.shape[1:])  # noqa: E731
-    o, kl, attended, seen = jax.lax.map(queries, (cut(q), cut(qi), cut(w), cut(jnp.arange(T)), cut(seg)))
-    return o.reshape(T, -1), jnp.sum(kl), jnp.sum(attended), jnp.sum(seen)
+    o, kl, *counts = jax.lax.map(queries, (cut(q), cut(qi), cut(w), cut(jnp.arange(T)), cut(seg)))
+    return (o.reshape(T, -1), jnp.sum(kl)) + tuple(jnp.sum(n) for n in counts)
 
 
 # -- the modules -----------------------------------------------------------------------
@@ -246,8 +245,8 @@ class SparseAttention(nn.Module):
     @nn.compact
     def __call__(self, x, resets, state, pos, positions, decode: bool, write: bool):
         """``state`` ``{"k", "v", "ki"}``; returns ``(y, state, aux)``, ``aux`` the
-        indexer's KL summed over the queries and the attended and visible
-        positions counted (nothing when decoding)."""
+        indexer's KL summed over the queries, the attended and visible
+        positions counted and the key tiles computed and in all (nothing when decoding)."""
         c = self.config
         B, T = x.shape[:2]
         with jax.named_scope("attn_proj"):
@@ -269,9 +268,10 @@ class SparseAttention(nn.Module):
             aux = {}
         else:
             per_sequence = lambda a: sequence_attention(c, *a)  # noqa: E731
-            o, kl, attended, seen = jax.lax.map(
+            o, kl, attended, seen, tiles, tiles_total = jax.lax.map(
                 per_sequence, (q, k, v, qi, ki, w, {n: state[n] for n in ("k", "v", "ki")}, pos, _segments(resets)))
-            aux = {"index_kl": jnp.sum(kl), "attended": jnp.sum(attended), "visible": jnp.sum(seen)}
+            aux = {"index_kl": jnp.sum(kl), "attended": jnp.sum(attended), "visible": jnp.sum(seen), "tiles": jnp.sum(tiles),
+                   "tiles_total": jnp.sum(tiles_total)}
         with jax.named_scope("attn_proj"):
             y = _dense(c.hidden_size, "o_proj", self.dtype)(o.astype(self.dtype))
         return y, state, aux
@@ -376,6 +376,7 @@ class SparseMoELM(nn.Module):
                 "index_loss": total["index_kl"] / queries,
                 "attended_share": total["attended"] / jnp.maximum(total["visible"], 1),
                 "picks_held_share": total["picks_held"] / (queries * c.num_layers * c.experts_per_token),
+                "attention_tiles_share": total["tiles"] / jnp.maximum(total["tiles_total"], 1),
             }
         return out + (report,)
 

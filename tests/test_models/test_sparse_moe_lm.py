@@ -14,7 +14,9 @@ import numpy as np
 import pytest
 
 from benchmarks.chip import sparse_moe_reference as ref
+from sheeprl_tpu.models import sparse_moe_lm
 from sheeprl_tpu.models.sparse_moe_lm import SparseMoEConfig, SparseMoELM, take_share
+from sheeprl_tpu.ops.sparse_attention import key_tile
 from sheeprl_tpu.ops.sparse_index import select_indices, select_mask
 
 TINY = dict(hidden_size=32, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8, rope_theta=1e4, mrope_section=(1, 1, 2),
@@ -49,6 +51,7 @@ def decoded():
 
 
 def test_decoding_through_the_caches_is_the_sequence_form_and_the_references(decoded):
+    """The sequence form (attention through ``ops/sparse_attention.py``'s kernel) token by token against the decode form and the reference."""
     model, params, tokens, resets, snapshot, step = decoded
     with jax.default_matmul_precision("highest"):
         assert list(np.asarray(snapshot["pos"])) == [3, 16, 16]  # env 0 restarted at 13; topk is 6: every query selects
@@ -66,6 +69,28 @@ def test_decoding_through_the_caches_is_the_sequence_form_and_the_references(dec
     assert float(report["index_loss"]) == pytest.approx(float(jnp.sum(kl)) / (B * T), rel=1e-5)
     assert float(report["attended_share"]) == pytest.approx(float(shares["attended_share"]), abs=1e-7) and float(report["attended_share"]) < 0.8
     assert float(report["picks_held_share"]) == pytest.approx(float(shares["picks_held_share"]), abs=1e-7)
+
+
+def test_the_update_counts_the_key_tiles_its_blocks_computed(decoded, monkeypatch):
+    """``attention_tiles_share`` is the tiles any query of a block selected from
+    over the tiles there are, counted over layers, sequences and blocks: here
+    from the selections the kernel was handed, counted with NumPy."""
+    model, params, tokens, resets, snapshot, _ = decoded
+    handed, kernel = [], sparse_moe_lm.selected_attention
+
+    def recording(q, cache_k, cache_v, k, v, selected):
+        jax.debug.callback(lambda s: handed.append(np.asarray(s)), selected)
+        return kernel(q, cache_k, cache_v, k, v, selected)
+
+    monkeypatch.setattr(sparse_moe_lm, "selected_attention", recording)
+    with jax.default_matmul_precision("highest"):
+        report = model.apply(params, tokens[:, PREFIX:], resets[:, PREFIX:], snapshot, aux=True)[3]
+    jax.effects_barrier()
+    assert len(handed) == TINY["num_layers"] * B * T // TINY["query_block"]
+    tile = key_tile(TINY["cache_len"], T)
+    live = [s.reshape(s.shape[0], -1, tile).any(axis=(0, 2)) for s in handed]
+    assert float(report["attention_tiles_share"]) == pytest.approx(float(np.mean(live)))
+    assert 0 < float(report["attention_tiles_share"]) < 1  # env 0 restarted 3 positions before the sequence: its cache tiles are skipped
 
 
 def test_three_unequal_position_streams_turn_their_own_pairs(decoded):
