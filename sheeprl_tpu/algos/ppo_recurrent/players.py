@@ -40,6 +40,7 @@ from sheeprl_tpu.algos.ppo_recurrent.utils import prepare_obs, test
 from sheeprl_tpu.envs.env import make_env
 from sheeprl_tpu.models.hybrid_lm import carry_bytes
 from sheeprl_tpu.models.sparse_moe_lm import AUX, SparseMoELM
+from sheeprl_tpu.ops.sparse_attention import decode_cache_rows
 from sheeprl_tpu.parallel.precision import cast_floating, compute_dtype_of, resolve_precision
 
 
@@ -262,7 +263,8 @@ class SparseTokenPlayer(TokenPlayer):
     """A policy of ``models/sparse_moe_lm.py``: the update differentiates the
     indexers' loss beside PPO's and reports it with the shares of positions
     attended and of picks held; the page says how many positions the next
-    decode step attends and what each kind of cache holds."""
+    decode step attends, how many its kernel may stream, and what each kind of
+    cache holds."""
 
     def evaluate(self, params, batch):
         logits, values, _, report = self.agent.apply(
@@ -278,8 +280,11 @@ class SparseTokenPlayer(TokenPlayer):
 
     def begin_step(self, prev_dones):
         super().begin_step(prev_dones)
-        # what the decode step about to run attends, from the host's mirror: no fetch
-        self.diag.note_policy_selection(int(self.positions.sum()), int(np.minimum(self.positions, self.agent.config.topk).sum()))
+        # what the decode step about to run attends and may stream of its caches (the positions before its token), from the
+        # host's mirror: no fetch
+        c = self.agent.config
+        self.diag.note_policy_selection(int(self.positions.sum()), int(np.minimum(self.positions, c.topk).sum()),
+                                        decode_cache_rows(self.positions - 1, c.cache_len))
 
     def after_update(self, losses):
         self.diag.note_policy_update(**{name: float(value) for name, value in zip(AUX, losses[3:])})

@@ -514,12 +514,13 @@ class Diagnostics:
         if self.telemetry is not None:
             self.telemetry.note_policy_gauges(**gauges)
 
-    def note_policy_selection(self, visible: int, attended: int) -> None:
+    def note_policy_selection(self, visible: int, attended: int, cache_rows: int) -> None:
         """A sparse-attention policy's vector step: positions seen and attended
-        (``sheeprl_policy_attended_positions`` and the two ``*_positions_total``).
-        No-op when telemetry is disabled."""
+        (``sheeprl_policy_attended_positions`` and the two ``*_positions_total``)
+        and the cache positions its decode kernel's tile tables allow
+        (``sheeprl_policy_decode_cache_rows_total``).  No-op when telemetry is disabled."""
         if self.telemetry is not None:
-            self.telemetry.note_policy_selection(visible, attended)
+            self.telemetry.note_policy_selection(visible, attended, cache_rows)
 
     def note_policy_update(self, **reports: float) -> None:
         """What one update of a sequence policy reported beside its losses

@@ -152,7 +152,7 @@ def render_prometheus(snapshot: Mapping[str, Any]) -> str:
     for key, mtype in (
         ("state_resets_total", "counter"), ("cache_positions", "gauge"), ("attended_positions", "gauge"), ("carry_bytes", "gauge"),
         ("view_bytes", "gauge"), ("visible_positions_total", "counter"), ("attended_positions_total", "counter"),
-        ("updates_total", "counter"),
+        ("decode_cache_rows_total", "counter"), ("updates_total", "counter"),
     ):
         if key in policy_state:
             emit("policy_" + key, mtype, policy_state[key])

@@ -779,15 +779,17 @@ class Telemetry:
         with self._lock:
             self._policy_more.update(gauges)
 
-    def note_policy_selection(self, visible: int, attended: int) -> None:
+    def note_policy_selection(self, visible: int, attended: int, cache_rows: int) -> None:
         """A sparse-attention policy's vector step: the positions its queries
         see and those they attend, summed over the envs; as they stand and
-        summed over the steps."""
+        summed over the steps; and the cache positions its decode kernel may
+        stream, summed over the steps."""
         with self._lock:
             state = self._policy_more
             state["attended_positions"] = int(attended)
             state["visible_positions_total"] = state.get("visible_positions_total", 0) + int(visible)
             state["attended_positions_total"] = state.get("attended_positions_total", 0) + int(attended)
+            state["decode_cache_rows_total"] = state.get("decode_cache_rows_total", 0) + int(cache_rows)
 
     def note_policy_update(self, **reports: float) -> None:
         """What one update of a sequence policy reported beside its losses,

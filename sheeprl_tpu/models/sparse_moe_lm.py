@@ -21,8 +21,10 @@ Pre-norm residual blocks ``x + Attn(RMSNorm(x))``, ``x + MoE(RMSNorm(x))``:
 episode ``[B, 1, cache_len, num_kv_heads * head_dim]`` and its index keys
 ``[B, 1, cache_len, indexer_head_dim]`` (float32 whatever the model's
 precision: the score is), rotated when written.  ``decode=True`` decodes one
-token an env: score against the index cache, select, gather the selected
-rows, attend, write the token's own three rows.  Otherwise a training
+token an env: score against the index cache, select, attend over the cache in
+place under the selection as a mask (``ops/sparse_attention.py``'s decode
+kernel: a key tile past the env's positions, or holding none it selected, is
+skipped), merge the token's own row, write its three rows.  Otherwise a training
 sequence is computed from a snapshot of that state taken as a constant, a
 sequence and a block of ``query_block`` queries at a time, through one Pallas
 kernel over the key tiles of cache and sequence that the block selected from
@@ -49,8 +51,8 @@ from flax import linen as nn
 
 from sheeprl_tpu.models.hybrid_lm import F32, RMSNorm, _dense, _Kernel, _segments, _write_rows
 from sheeprl_tpu.ops.moe import held_experts, route
-from sheeprl_tpu.ops.sparse_attention import selected_attention
-from sheeprl_tpu.ops.sparse_index import index_scores, select_indices, select_mask
+from sheeprl_tpu.ops.sparse_attention import decode_selected_attention, selected_attention
+from sheeprl_tpu.ops.sparse_index import index_scores, select_mask
 
 _HI = jax.lax.Precision.HIGHEST
 # the jax.named_scopes a profile of the update is split by
@@ -149,23 +151,19 @@ def decode_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, held):
     """One token an env.  ``q`` ``[B, Hq, dh]``, ``k``/``v`` ``[B, Hkv dh]``, ``qi``
     ``[B, Hi, di]``, ``ki`` ``[B, di]``, ``w`` ``[B, Hi]``; ``cache`` the layer's
     state, of which the first ``held`` ``[B]`` positions are the episode's.  The
-    token's own rows are one more candidate, not yet in the cache."""
+    token's own rows are one more candidate, not yet in the cache: selected
+    beside the cache's, attended outside the kernel and merged by its
+    log-sum-exp."""
     B, L = q.shape[0], cache["ki"].shape[2]
-    G, R = c.num_kv_heads, c.num_heads // c.num_kv_heads
     with jax.named_scope("index_score"):
         scores = jnp.concatenate([
             index_scores(qi[:, None], cache["ki"][:, 0], w[:, None])[:, 0], index_scores(qi[:, None], ki[:, None], w[:, None])[:, 0],
         ], axis=-1)
         visible = jnp.concatenate([jnp.arange(L)[None] < held[:, None], jnp.ones((B, 1), bool)], axis=-1)
     with jax.named_scope("select"):
-        idx, valid = select_indices(scores, visible, min(c.topk, L + 1))
+        selected = select_mask(scores, visible, min(c.topk, L + 1))
     with jax.named_scope("sparse_attention"):
-        own, at = (idx == L)[..., None], jnp.minimum(idx, L - 1)[..., None]
-        rows_k = jnp.where(own, k[:, None], jnp.take_along_axis(cache["k"][:, 0], at, axis=1)).reshape(B, -1, G, c.head_dim)
-        rows_v = jnp.where(own, v[:, None], jnp.take_along_axis(cache["v"][:, 0], at, axis=1)).reshape(B, -1, G, c.head_dim)
-        s = jnp.einsum("bgrd,bkgd->bgrk", q.reshape(B, G, R, c.head_dim), rows_k.astype(q.dtype)).astype(F32) * c.head_dim ** -0.5
-        weights = jax.nn.softmax(jnp.where(valid[:, None, None], s, -jnp.inf), axis=-1).astype(q.dtype)
-        return jnp.einsum("bgrk,bkgd->bgrd", weights, rows_v.astype(q.dtype)).reshape(B, c.num_heads * c.head_dim)
+        return decode_selected_attention(q, k, v, cache["k"], cache["v"], selected, held).reshape(B, c.num_heads * c.head_dim)
 
 
 def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):

@@ -1,5 +1,7 @@
-"""Attention over the lightning indexer's selection, for a training sequence:
-one Pallas TPU kernel that visits only the key tiles a block of queries selected from.
+"""Attention over the lightning indexer's selection: for a training sequence, one
+Pallas TPU kernel that visits only the key tiles a block of queries selected
+from; for a decoded token, one that reads each env's cache in place under the
+selection as a mask (:func:`decode_attention_cached`, at the end).
 
 A block of ``bq`` queries of one sequence attends, each query its own selected
 positions, over the layer's cached keys and values ``[L, Hkv dh]`` (a constant
@@ -106,6 +108,17 @@ def _masked_scores(q, k, mask, heads: int, scale: float, dtype):
     return jnp.where(selected, s.reshape(heads, bq, tile), -jnp.inf).reshape(heads * bq, tile)
 
 
+def _accumulate(s, v, m_sc, l_sc, acc_sc, rows, dtype) -> None:
+    """One tile of an online softmax: scores ``s`` against values ``v`` into the running maximum, sum and output of ``rows``."""
+    m_prev = m_sc[rows]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_sc[rows] = alpha * l_sc[rows] + jnp.sum(p, axis=1, keepdims=True)
+    acc_sc[rows] = alpha * acc_sc[rows] + _dot(p, v, (((1,), (0,)), ((), ())), dtype)
+    m_sc[rows] = m_new
+
+
 def _on_live_tile(live_ref, j, n_cache: int, cached, own) -> None:
     """Run ``cached()`` on a live tile of the cache, ``own()`` on a live tile of the sequence."""
     live = live_ref[j] != 0
@@ -125,13 +138,7 @@ def _forward_kernel(live_ref, _m, _c, _s, q_ref, kc_ref, vc_ref, ks_ref, vs_ref,
 
     def visit(k_ref, v_ref):
         s = _masked_scores(q_ref[0], k_ref[...], mask_ref[...], heads, scale, dtype)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[...] = alpha * acc_sc[...] + _dot(p, v_ref[...], (((1,), (0,)), ((), ())), dtype)
-        m_sc[...] = m_new
+        _accumulate(s, v_ref[...], m_sc, l_sc, acc_sc, ..., dtype)
 
     _on_live_tile(live_ref, j, n_cache, lambda: visit(kc_ref, vc_ref), lambda: visit(ks_ref, vs_ref))
 
@@ -354,3 +361,114 @@ def selected_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, k: 
         p = _weights_call(sg(qg), sg(cache_k), sg(ks), mask, order, sg(lse), dtype)
         o = o.reshape(groups, heads, bq, head_dim).transpose(2, 0, 1, 3).reshape(bq, heads_q, head_dim)
     return o.astype(q.dtype), p, live
+
+
+# -- one decoded token an env, over its cache in place --------------------------------------
+def decode_tile(cache_len: int) -> int:
+    """Positions a key tile of the decode kernel holds: the most, up to 512, that divides the cache."""
+    return math.gcd(cache_len, _TILE)
+
+
+def decode_cache_rows(held: np.ndarray, cache_len: int) -> int:
+    """The cache positions the decode kernel's tile tables allow for envs holding ``held`` positions:
+    every tile up to the last held position, whole.  An upper bound on what it fetches (a tile
+    holding no selected position is skipped too)."""
+    tile = decode_tile(cache_len)
+    return int(np.sum(-(-np.asarray(held, np.int64) // tile)) * tile)
+
+
+def decode_order(mask: jax.Array, tile: int) -> Tuple[jax.Array, jax.Array]:
+    """``mask`` ``[B, L]`` -> the scalar-prefetched tables, flat ``[B x L / tile]`` int32:
+    which of an env's tiles hold a selected position, and at each step the tile
+    to hold.  A dead step holds what the step before it held, so its copies are
+    never issued; an env with no live tile holds its first."""
+    live = jnp.any(mask.reshape(mask.shape[0], -1, tile), axis=2)
+    return live.astype(jnp.int32).reshape(-1), jax.vmap(_held)(live).reshape(-1)
+
+
+def _decode_kernel(live_ref, _blocks, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, groups, heads, head_dim,
+                   scale, dtype):
+    b, j, n = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _FLOOR, F32)
+        l_sc[...] = jnp.zeros(l_sc.shape, F32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
+
+    @pl.when(live_ref[b * n + j] != 0)
+    def _():
+        off = jnp.where(mask_ref[...] != 0, 0.0, -jnp.inf).astype(F32)  # [1, tile]: one selection for every head of the env
+        for g in range(groups):  # the query heads of a key head against its lanes of the tile
+            rows, lanes = pl.ds(g * heads, heads), pl.ds(g * head_dim, head_dim)
+            s = _dot(q_ref[rows, :], k_ref[:, lanes], _NT, dtype) * scale + off
+            _accumulate(s, v_ref[:, lanes], m_sc, l_sc, acc_sc, (rows, slice(None)), dtype)
+
+    @pl.when(j == n - 1)
+    def _():
+        l = l_sc[...]
+        some = l > 0  # an env that selected nothing of its cache reads 0 and the floor
+        o_ref[...] = acc_sc[...] / jnp.where(some, l, 1.0)
+        lse = jnp.where(some, m_sc[...] + jnp.log(jnp.where(some, l, 1.0)), _FLOOR)
+        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
+
+
+def decode_attention_cached(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, mask: jax.Array,
+                            held: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One query token an env over the selected positions of its cache, read in place.
+
+    ``q`` ``[B, Hq, dh]``; ``cache_k``/``cache_v`` ``[B, 1, L, Hkv dh]``, of which
+    the first ``held`` ``[B]`` positions are the env's; ``mask`` ``[B, L]`` bool,
+    the positions the query attends.  Returns the heads' outputs ``[B, Hq, dh]``
+    and their log-sum-exp ``[B, Hq]``, float32 (the floor where nothing is
+    selected), for a caller that merges more candidates.  The grid runs over
+    envs and key tiles of :func:`decode_tile`; a tile at or past ``held``, or
+    holding no selected position, is neither fetched nor computed, and every
+    key-value group of a live tile is computed in its one step."""
+    batch, heads_q, head_dim = q.shape
+    length, width = cache_k.shape[2], cache_k.shape[3]
+    groups = width // head_dim
+    tile = decode_tile(length)
+    n_tiles = length // tile
+    dtype = dot_dtype(q.dtype)
+    with jax.named_scope(SCOPE):
+        mask = mask & (jnp.arange(length)[None] < held[:, None])
+        live, blocks = decode_order(mask, tile)
+        kernel = functools.partial(_decode_kernel, groups=groups, heads=heads_q // groups, head_dim=head_dim, scale=head_dim ** -0.5,
+                                   dtype=dtype)
+        rows = lambda lanes: pl.BlockSpec((None, heads_q, lanes), lambda b, j, *_: (b, 0, 0))  # noqa: E731
+        cached = pl.BlockSpec((None, None, tile, width), lambda b, j, live, blocks: (b, 0, blocks[b * n_tiles + j], 0))
+        o, lse = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(batch, n_tiles),
+                in_specs=[rows(head_dim), cached, cached,
+                          pl.BlockSpec((None, 1, tile), lambda b, j, live, blocks: (b, 0, blocks[b * n_tiles + j]))],
+                out_specs=[rows(head_dim), rows(_LANES)],
+                scratch_shapes=[pltpu.VMEM((heads_q, 1), F32), pltpu.VMEM((heads_q, 1), F32), pltpu.VMEM((heads_q, head_dim), F32)]),
+            out_shape=[jax.ShapeDtypeStruct((batch, heads_q, head_dim), F32), jax.ShapeDtypeStruct((batch, heads_q, _LANES), F32)],
+            compiler_params=_params("parallel", "arbitrary"), interpret=_interpret(), name="sparse_attention_decode",
+        )(live, blocks, q.astype(F32), cache_k, cache_v, mask.astype(jnp.int32)[:, None])
+    return o, lse[..., 0]
+
+
+def decode_selected_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache_k: jax.Array, cache_v: jax.Array, selected: jax.Array,
+                              held: jax.Array) -> jax.Array:
+    """A decoded token an env over its selection.  ``q`` ``[B, Hq, dh]``; ``k``/``v``
+    ``[B, Hkv dh]``, the token's own row, not yet in the cache; ``cache_k``/``cache_v``
+    as :func:`decode_attention_cached` takes them; ``selected`` ``[B, L + 1]`` bool,
+    the cache's positions and then the own row.  The cache goes through the
+    kernel; the own row is one more candidate, merged by the two log-sum-exps.
+    Returns the heads' outputs ``[B, Hq, dh]`` in ``q``'s type."""
+    batch, heads_q, head_dim = q.shape
+    length, groups = cache_k.shape[2], k.shape[-1] // head_dim
+    o, lse = decode_attention_cached(q, cache_k, cache_v, selected[:, :length], held)
+    with jax.named_scope(SCOPE):
+        rounded = lambda x: x.astype(dot_dtype(q.dtype)).astype(F32)  # noqa: E731  (as the kernel's products take their operands)
+        by_group = lambda x: x.reshape(batch, groups, 1, head_dim)  # noqa: E731
+        own = jnp.sum(rounded(q).reshape(batch, groups, -1, head_dim) * by_group(rounded(k)), axis=-1).reshape(batch, heads_q)
+        own = jnp.where(selected[:, length:], own * head_dim ** -0.5, -jnp.inf)
+        total = jnp.logaddexp(lse, own)
+        own_v = jnp.broadcast_to(by_group(v.astype(F32)), (batch, groups, heads_q // groups, head_dim)).reshape(batch, heads_q, head_dim)
+        o = o * jnp.exp(lse - total)[..., None] + own_v * jnp.exp(own - total)[..., None]
+    return o.astype(q.dtype)

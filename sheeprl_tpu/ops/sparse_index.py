@@ -6,19 +6,14 @@
 positions of largest ``I[t, .]``, ties to the lower position.  A selection is
 discrete: a rounded score picks other positions, so the score is float32 at
 ``highest`` matmul precision wherever it is computed, and the selection is
-exact in both of its forms:
-
-- :func:`select_indices` (a decoded token: the rows to gather) is
-  ``jax.lax.top_k``, whose sort is stable;
-- :func:`select_mask` (a training sequence: a mask over a blocked dense
-  product) finds the k-th largest score of a row by a search over the bits of
-  its order-preserving integer image (32 counts a row, no sort), then takes
-  the tied positions in order until ``k`` are taken.
+exact: :func:`select_mask` (a decoded token's and a training sequence's, a mask
+over the positions) finds the k-th largest score of a row by a search over the
+bits of its order-preserving integer image (32 counts a row, no sort), then
+takes the tied positions in order until ``k`` are taken: ``jax.lax.top_k``'s
+choice, whose sort is stable.
 """
 
 from __future__ import annotations
-
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,10 +56,3 @@ def select_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
     above, tied = keys > threshold, keys == threshold
     left = k - jnp.sum(above.astype(jnp.int32), axis=-1, keepdims=True)
     return visible & (above | (tied & (jnp.cumsum(tied.astype(jnp.int32), axis=-1) <= left)))
-
-
-def select_indices(scores: jax.Array, visible: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """The same selection as the positions themselves: ``[..., k]`` indices
-    and which of them are selected (fewer than ``k`` where fewer are visible)."""
-    top, idx = jax.lax.top_k(jnp.where(visible, jax.lax.stop_gradient(scores), -jnp.inf), k)
-    return idx, top > -jnp.inf  # a score is finite: what the sort left at the bottom is what was not visible

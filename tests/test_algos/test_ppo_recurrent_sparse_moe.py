@@ -116,7 +116,7 @@ def test_the_selection_and_the_updates_reports_are_on_the_metrics_page():
     telemetry.note_policy_gauges(carry_bytes_by_kind={"kv": 800, "index": 200})
     for visible, attended in ((40, 12), (42, 12)):
         telemetry.note_policy_state(0, visible, 1008, 500)
-        telemetry.note_policy_selection(visible, attended)
+        telemetry.note_policy_selection(visible, attended, 64)
     telemetry.note_policy_update(index_loss=0.5, attended_share=0.75, picks_held_share=0.125, attention_tiles_share=0.375)
     # the update's losses row as the loop hands it over: the three PPO terms, then the report's columns in AUX's order
     player = players.SparseTokenPlayer.__new__(players.SparseTokenPlayer)
@@ -124,9 +124,49 @@ def test_the_selection_and_the_updates_reports_are_on_the_metrics_page():
     player.after_update([0.1, 0.2, 0.3, 0.3, 0.25, 0.125, 0.25])
     page = render_prometheus({"policy_state": {**telemetry._policy_state, **telemetry._policy_more}})
     for line in ("sheeprl_policy_cache_positions 42", "sheeprl_policy_attended_positions 12", "sheeprl_policy_visible_positions_total 82",
-                 "sheeprl_policy_attended_positions_total 24", "sheeprl_policy_carry_bytes 1008", 'sheeprl_policy_carry_bytes{kind="kv"} 800',
+                 "sheeprl_policy_attended_positions_total 24", "sheeprl_policy_decode_cache_rows_total 128", "sheeprl_policy_carry_bytes 1008", 'sheeprl_policy_carry_bytes{kind="kv"} 800',
                  'sheeprl_policy_carry_bytes{kind="index"} 200', "sheeprl_policy_updates_total 2", "sheeprl_policy_attended_share_sum 1",
                  "sheeprl_policy_picks_held_share_sum 0.25", "sheeprl_policy_index_loss_sum 0.8",
                  "sheeprl_policy_attention_tiles_share_sum 0.625"):
         assert line in page.splitlines(), line
     assert page.count("# TYPE sheeprl_policy_carry_bytes ") == 1  # one family, the kinds under it
+
+
+def test_the_decode_kernels_cache_rows_are_counted_from_the_hosts_mirror_without_a_fetch():
+    """Two vector steps of a player whose caches hold 1,536 positions (tiles of
+    512): ``sheeprl_policy_decode_cache_rows_total`` grows by every tile up to
+    each env's positions before the token it decodes, whole, from the host's
+    mirror; and the decode program that this counts for holds no host callback."""
+    from unittest.mock import MagicMock
+
+    import numpy as np
+
+    from sheeprl_tpu.diagnostics.metrics_server import render_prometheus
+    from sheeprl_tpu.diagnostics.telemetry import Telemetry
+
+    config = dict(TINY_MODEL, mrope_section=(1, 1, 2), rope_theta=1e4, expert_share=0, norm_topk_prob=True, rms_norm_eps=1e-6, vocab_share=0)
+    telemetry = Telemetry.__new__(Telemetry)
+    telemetry._lock, telemetry._policy_state, telemetry._policy_more = MagicMock(), {}, {}
+    player = players.SparseTokenPlayer.__new__(players.SparseTokenPlayer)
+    player.agent = SparseMoELM(SparseMoEConfig(**dict(config, cache_len=1536)))
+    player.diag = mock.Mock(note_policy_state=telemetry.note_policy_state, note_policy_selection=telemetry.note_policy_selection)
+    player.positions, player.steps, player.seq_len, player.carry_nbytes, player.view_nbytes = np.asarray([0, 510, 1100]), 1, 8, 0, 0
+    held = []
+    for dones in ([0, 0, 1], [0, 0, 0]):
+        player.begin_step(np.asarray(dones)[:, None])
+        held.append(player.positions - 1)
+    assert [list(h) for h in held] == [[0, 510, 0], [1, 511, 1]]
+    want = sum(int(np.ceil(h / 512)) * 512 for h in np.concatenate(held))
+    assert want == 512 + 3 * 512
+    page = render_prometheus({"policy_state": {**telemetry._policy_state, **telemetry._policy_more}})
+    assert f"sheeprl_policy_decode_cache_rows_total {want}" in page.splitlines()
+    assert "# TYPE sheeprl_policy_decode_cache_rows_total counter" in page.splitlines()
+
+    agent = SparseMoELM(SparseMoEConfig(**config))
+    n, steps = 2, 4
+    carry = {"state": agent.init_state(n), "key": jax.random.PRNGKey(0), "t": jnp.zeros((), jnp.int32),
+             "logprobs": jnp.zeros((steps, n), jnp.float32), "values": jnp.zeros((steps, n), jnp.float32)}
+    token = jnp.zeros((n, 1), jnp.int32)
+    params = agent.init(jax.random.PRNGKey(1), token, token, agent.init_state(n))
+    text = players.make_token_player(agent, None, steps)[0].lower(params, carry, jnp.zeros((2, n), jnp.int32)).as_text()
+    assert "callback" not in text and "outfeed" not in text

@@ -4,9 +4,10 @@
 Small widths, a ``topk`` smaller than the episodes so that the selection
 bites, an episode end inside a sequence, caches carried from a decoded
 prefix: log-probabilities, values, the PPO losses, the indexers' loss and the
-gradient of every leaf; the two forms of the selection against
-``jax.lax.top_k`` with planted ties; the expert shares' partial sums; the two
-disjoint gradient paths."""
+gradient of every leaf; the selection in the learner's form and in a decoded
+token's (the cache's positions, then its own row) against ``jax.lax.top_k``
+with planted ties; the expert shares' partial sums; the two disjoint gradient
+paths."""
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,7 @@ from benchmarks.chip import sparse_moe_reference as ref
 from sheeprl_tpu.models import sparse_moe_lm
 from sheeprl_tpu.models.sparse_moe_lm import SparseMoEConfig, SparseMoELM, take_share
 from sheeprl_tpu.ops.sparse_attention import key_tile
-from sheeprl_tpu.ops.sparse_index import select_indices, select_mask
+from sheeprl_tpu.ops.sparse_index import select_mask
 
 TINY = dict(hidden_size=32, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8, rope_theta=1e4, mrope_section=(1, 1, 2),
             indexer_heads=2, indexer_head_dim=4, topk=6, experts_total=8, experts_held=4, expert_share=1, experts_per_token=2,
@@ -165,11 +166,16 @@ def test_both_forms_of_the_selection_are_top_k_of_the_scores_with_planted_ties(k
     got = np.asarray(jax.jit(lambda s, v: select_mask(s, v, k))(jnp.asarray(scores), visible))
     np.testing.assert_array_equal(got, want_mask)
     assert list(got.sum(1)) == [min(k, n) for n in (40, 40, 33, 12, 4, 1, 27)]
-    idx, valid = select_indices(jnp.asarray(scores), visible, k)
-    from_indices = np.zeros((7, 40), bool)
+    # a decoded token's form: the cache's first ``held`` of 39 positions, then its own row, always visible
+    held = np.asarray([39, 0, 33, 12, 4, 1, 27])
+    own = jnp.asarray(np.concatenate([np.arange(39)[None] < held[:, None], np.ones((7, 1), bool)], axis=1))
+    _, want = jax.lax.top_k(jnp.where(own, jnp.asarray(scores), -jnp.inf), k)
+    want_mask = np.zeros((7, 40), bool)
     for row in range(7):
-        from_indices[row, np.asarray(idx[row])[np.asarray(valid[row])]] = True
-    np.testing.assert_array_equal(from_indices, want_mask)
+        want_mask[row, np.asarray(want[row])] = True
+    got = np.asarray(select_mask(jnp.asarray(scores), own, k))
+    np.testing.assert_array_equal(got, want_mask & np.asarray(own))
+    assert bool(np.all(got[:, -1] | (got.sum(1) == k)))  # the own row is taken unless k others outscore it
 
 
 def test_the_expert_shares_partial_sums_add_up_to_the_uncut_layer():
