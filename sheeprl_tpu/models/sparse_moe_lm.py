@@ -26,12 +26,14 @@ place under the selection as a mask (``ops/sparse_attention.py``'s decode
 kernel: a key tile past the env's positions, or holding none it selected, is
 skipped), merge the token's own row, write its three rows.  Otherwise a training
 sequence is computed from a snapshot of that state taken as a constant, a
-sequence and a block of ``query_block`` queries at a time, through one Pallas
-kernel over the key tiles of cache and sequence that the block selected from
-(``ops/sparse_attention.py``: the scores stay on chip and a tile no query
-selected is skipped), each block recomputed in the backward pass.  Episode ends
-(``resets``) restart the cache and start a new block of the mask, as in
-``hybrid_lm.py``, whose protocol this keeps (``TokenPlayer`` drives both).
+sequence and a block of ``query_block`` queries at a time: the index score
+through one Pallas kernel over the key tiles of cache and sequence that some
+query of the block can see (``ops/sparse_index.py``: the per-head scores stay
+on chip and a tile no query sees is skipped), attention through one over the
+tiles the block selected from (``ops/sparse_attention.py``, alike), each block
+recomputed in the backward pass.  Episode ends (``resets``) restart the cache
+and start a new block of the mask, as in ``hybrid_lm.py``, whose protocol this
+keeps (``TokenPlayer`` drives both).
 
 **A chip's share.**  ``experts_held`` of ``experts_total`` (share
 ``expert_share``) and ``vocab_held`` of ``vocab_total`` ids; attention and the
@@ -52,14 +54,14 @@ from flax import linen as nn
 from sheeprl_tpu.models.hybrid_lm import F32, RMSNorm, _dense, _Kernel, _segments, _write_rows
 from sheeprl_tpu.ops.moe import held_experts, route
 from sheeprl_tpu.ops.sparse_attention import decode_selected_attention, selected_attention
-from sheeprl_tpu.ops.sparse_index import index_scores, select_mask
+from sheeprl_tpu.ops.sparse_index import block_index_scores, index_scores, select_mask
 
 _HI = jax.lax.Precision.HIGHEST
 # the jax.named_scopes a profile of the update is split by
 SCOPES = ("embed", "attn_proj", "index_score", "select", "sparse_attention", "index_loss", "moe_route", "moe_experts",
           "vocab_head", "ppo_loss", "optim")
 # what the update reports beside the three PPO losses, in this order
-AUX = ("index_loss", "attended_share", "picks_held_share", "attention_tiles_share")
+AUX = ("index_loss", "attended_share", "picks_held_share", "attention_tiles_share", "index_tiles_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,11 +175,11 @@ def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):
     many of its positions are the first episode's, ``seg`` ``[T]``.  Returns the
     heads' outputs ``[T, Hq dh]``, the indexer's KL summed over the queries, the
     attended and the visible positions counted over them, and the key tiles
-    attention computed and that exist, counted over the blocks."""
+    attention computed, that exist and that the index score computed, counted
+    over the blocks."""
     T, L = q.shape[0], cache["ki"].shape[1]
     block = min(c.query_block, T)
     cache_k, cache_v = cache["k"][0].astype(k.dtype), cache["v"][0].astype(v.dtype)
-    index_keys = jnp.concatenate([cache["ki"][0], ki], axis=0)
 
     @jax.checkpoint
     def queries(args):
@@ -186,7 +188,7 @@ def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):
         carried = (jnp.arange(L)[None] < pos) & (segb[:, None] == 0)
         visible = jnp.concatenate([carried, (jnp.arange(T)[None] <= tb[:, None]) & (seg[None] == segb[:, None])], axis=1)
         with jax.named_scope("index_score"):
-            scores = index_scores(qib, index_keys, wb)
+            scores, scored = block_index_scores(qib, wb, cache["ki"][0], ki, visible)  # 0 on a key tile no query of the block sees
         with jax.named_scope("select"):
             selected = select_mask(scores, visible, c.topk)
         with jax.named_scope("sparse_attention"):
@@ -194,7 +196,8 @@ def sequence_attention(c: SparseMoEConfig, q, k, v, qi, ki, w, cache, pos, seg):
         with jax.named_scope("index_loss"):
             log_q = jax.nn.log_softmax(jnp.where(selected, scores, -jnp.inf), axis=-1)
             kl = jnp.where(selected, p * (jnp.log(jnp.where(p > 0, p, 1.0)) - jnp.where(selected, log_q, 0.0)), 0.0)
-        counts = (jnp.sum(selected.astype(jnp.int32)), jnp.sum(visible.astype(jnp.int32)), jnp.sum(live), jnp.int32(live.size))
+        counts = (jnp.sum(selected.astype(jnp.int32)), jnp.sum(visible.astype(jnp.int32)), jnp.sum(live), jnp.int32(live.size),
+                  jnp.sum(scored))
         return (o.reshape(o.shape[0], -1), jnp.sum(kl)) + counts
 
     cut = lambda x: x.reshape((T // block, block) + x.shape[1:])  # noqa: E731
@@ -244,7 +247,8 @@ class SparseAttention(nn.Module):
     def __call__(self, x, resets, state, pos, positions, decode: bool, write: bool):
         """``state`` ``{"k", "v", "ki"}``; returns ``(y, state, aux)``, ``aux`` the
         indexer's KL summed over the queries, the attended and visible
-        positions counted and the key tiles computed and in all (nothing when decoding)."""
+        positions counted, the key tiles attention computed, in all and that
+        the index score computed (nothing when decoding)."""
         c = self.config
         B, T = x.shape[:2]
         with jax.named_scope("attn_proj"):
@@ -266,10 +270,10 @@ class SparseAttention(nn.Module):
             aux = {}
         else:
             per_sequence = lambda a: sequence_attention(c, *a)  # noqa: E731
-            o, kl, attended, seen, tiles, tiles_total = jax.lax.map(
+            o, kl, attended, seen, tiles, tiles_total, index_tiles = jax.lax.map(
                 per_sequence, (q, k, v, qi, ki, w, {n: state[n] for n in ("k", "v", "ki")}, pos, _segments(resets)))
             aux = {"index_kl": jnp.sum(kl), "attended": jnp.sum(attended), "visible": jnp.sum(seen), "tiles": jnp.sum(tiles),
-                   "tiles_total": jnp.sum(tiles_total)}
+                   "tiles_total": jnp.sum(tiles_total), "index_tiles": jnp.sum(index_tiles)}
         with jax.named_scope("attn_proj"):
             y = _dense(c.hidden_size, "o_proj", self.dtype)(o.astype(self.dtype))
         return y, state, aux
@@ -375,6 +379,7 @@ class SparseMoELM(nn.Module):
                 "attended_share": total["attended"] / jnp.maximum(total["visible"], 1),
                 "picks_held_share": total["picks_held"] / (queries * c.num_layers * c.experts_per_token),
                 "attention_tiles_share": total["tiles"] / jnp.maximum(total["tiles_total"], 1),
+                "index_tiles_share": total["index_tiles"] / jnp.maximum(total["tiles_total"], 1),
             }
         return out + (report,)
 

@@ -117,17 +117,18 @@ def test_the_selection_and_the_updates_reports_are_on_the_metrics_page():
     for visible, attended in ((40, 12), (42, 12)):
         telemetry.note_policy_state(0, visible, 1008, 500)
         telemetry.note_policy_selection(visible, attended, 64)
-    telemetry.note_policy_update(index_loss=0.5, attended_share=0.75, picks_held_share=0.125, attention_tiles_share=0.375)
+    telemetry.note_policy_update(index_loss=0.5, attended_share=0.75, picks_held_share=0.125, attention_tiles_share=0.375,
+                                 index_tiles_share=0.5)
     # the update's losses row as the loop hands it over: the three PPO terms, then the report's columns in AUX's order
     player = players.SparseTokenPlayer.__new__(players.SparseTokenPlayer)
     player.diag = mock.Mock(note_policy_update=telemetry.note_policy_update)
-    player.after_update([0.1, 0.2, 0.3, 0.3, 0.25, 0.125, 0.25])
+    player.after_update([0.1, 0.2, 0.3, 0.3, 0.25, 0.125, 0.25, 0.375])
     page = render_prometheus({"policy_state": {**telemetry._policy_state, **telemetry._policy_more}})
     for line in ("sheeprl_policy_cache_positions 42", "sheeprl_policy_attended_positions 12", "sheeprl_policy_visible_positions_total 82",
                  "sheeprl_policy_attended_positions_total 24", "sheeprl_policy_decode_cache_rows_total 128", "sheeprl_policy_carry_bytes 1008", 'sheeprl_policy_carry_bytes{kind="kv"} 800',
                  'sheeprl_policy_carry_bytes{kind="index"} 200', "sheeprl_policy_updates_total 2", "sheeprl_policy_attended_share_sum 1",
                  "sheeprl_policy_picks_held_share_sum 0.25", "sheeprl_policy_index_loss_sum 0.8",
-                 "sheeprl_policy_attention_tiles_share_sum 0.625"):
+                 "sheeprl_policy_attention_tiles_share_sum 0.625", "sheeprl_policy_index_tiles_share_sum 0.875"):
         assert line in page.splitlines(), line
     assert page.count("# TYPE sheeprl_policy_carry_bytes ") == 1  # one family, the kinds under it
 

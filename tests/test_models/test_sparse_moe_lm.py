@@ -94,6 +94,29 @@ def test_the_update_counts_the_key_tiles_its_blocks_computed(decoded, monkeypatc
     assert 0 < float(report["attention_tiles_share"]) < 1  # env 0 restarted 3 positions before the sequence: its cache tiles are skipped
 
 
+def test_the_update_counts_the_key_tiles_its_index_score_computed(decoded, monkeypatch):
+    """``index_tiles_share`` is the tiles any query of a block can see over the
+    tiles there are, counted over layers, sequences and blocks: here from the
+    visibilities the index kernel was handed, counted with NumPy.  What a block
+    selects it can see, so attention computes no tile the index score skipped."""
+    model, params, tokens, resets, snapshot, _ = decoded
+    handed, kernel = [], sparse_moe_lm.block_index_scores
+
+    def recording(q, w, cache_k, k, visible):
+        jax.debug.callback(lambda v: handed.append(np.asarray(v)), visible)
+        return kernel(q, w, cache_k, k, visible)
+
+    monkeypatch.setattr(sparse_moe_lm, "block_index_scores", recording)
+    with jax.default_matmul_precision("highest"):
+        report = model.apply(params, tokens[:, PREFIX:], resets[:, PREFIX:], snapshot, aux=True)[3]
+    jax.effects_barrier()
+    assert len(handed) == TINY["num_layers"] * B * T // TINY["query_block"]
+    tile = key_tile(TINY["cache_len"], T)
+    live = [v.reshape(v.shape[0], -1, tile).any(axis=(0, 2)) for v in handed]
+    assert float(report["index_tiles_share"]) == pytest.approx(float(np.mean(live)))
+    assert float(report["attention_tiles_share"]) <= float(report["index_tiles_share"]) < 1
+
+
 def test_three_unequal_position_streams_turn_their_own_pairs(decoded):
     model, params, tokens, resets, snapshot, _ = decoded
     positions = jnp.stack([jnp.arange(T)[None] + snapshot["pos"][:, None], 3 * jnp.arange(T)[None] + jnp.arange(B)[:, None],

@@ -377,6 +377,27 @@ def test_compiled_for_a_v5e_the_decode_step_holds_no_gathered_rows(v5e_chip, mon
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 32768 * 512 * 2
 
 
+def test_compiled_for_a_v5e_the_learners_index_score_holds_no_per_head_block(v5e_chip, monkeypatch):
+    """The learner's index score for a block of 128 queries of the keye cell (16
+    heads of 64, a cache of 8,192 positions and a sequence of 1,024), its value
+    and gradient compiled for a described v5e: three kernels through Mosaic
+    (forward, dQ and dW, dK), and no ``[128, 16, 9216]`` float32 block of
+    per-head scores in the program."""
+    from sheeprl_tpu.ops import sparse_index as si
+
+    monkeypatch.setattr(si, "_interpret", lambda: False)
+    on_chip = lambda *shape, dtype=F32: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)  # noqa: E731
+
+    def loss(q, w, k, cache_k, visible, g):
+        return jnp.sum(si.block_index_scores(q, w, cache_k, k, visible)[0] * g)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip(128, 16, 64), on_chip(128, 16), on_chip(1024, 64), on_chip(8192, 64), on_chip(128, 9216, dtype=jnp.bool_),
+        on_chip(128, 9216)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "f32[128,16,9216]" not in text
+
+
 # -- on the chip: the decode kernel at the keye cell's shapes ---------------------------------------
 DECODE_CELL = dict(envs=16, length=8192, heads_q=32, groups=4, dh=128, topk=2048)
 
